@@ -167,8 +167,10 @@ class ServeConfig:
     max_wait_us: float = 2000.0
     # --- compile-time control (docs/serving.md "Warm starts") ----------
     # persistent XLA compilation cache (hyperspace_tpu/compile_cache.py):
-    # default ON at <repo>/.cache/jax_compile (HYPERSPACE_COMPILE_CACHE
-    # env overrides); a path points it elsewhere, 0 disables.  A serve
+    # default ON — where JAX_COMPILATION_CACHE_DIR says if that is set
+    # (no other directory is then accepted), else at
+    # <repo>/.cache/jax_compile unless this flag or
+    # HYPERSPACE_COMPILE_CACHE names a path; 0 disables.  A serve
     # restart then deserializes its executables instead of re-compiling
     # the whole bucket ladder.
     compile_cache_dir: str | None = None

@@ -20,6 +20,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import math
 import os
 import sys
 from typing import Any
@@ -84,11 +85,12 @@ class RunConfig:
     # default synchronous gather keeps the bitwise contract)
     host_gather_ahead: bool = False
     # persistent XLA compilation cache (hyperspace_tpu/compile_cache.py,
-    # docs/observability.md "Compilation cache"): default ON at
-    # <repo>/.cache/jax_compile (HYPERSPACE_COMPILE_CACHE env overrides);
-    # a path points it elsewhere, 0 disables.  Run #2 of the same
-    # program shapes deserializes executables instead of re-invoking XLA
-    # (`jax/compile_cache_hit` counts them).
+    # docs/observability.md "Compilation cache"): default ON — where
+    # JAX_COMPILATION_CACHE_DIR says if that is set (no other directory
+    # is then accepted), else at <repo>/.cache/jax_compile unless this
+    # flag or HYPERSPACE_COMPILE_CACHE names a path; 0 disables.  Run #2
+    # of the same program shapes deserializes executables instead of
+    # re-invoking XLA (`jax/compile_cache_hit` counts them).
     compile_cache_dir: str | None = None
     # --- telemetry (docs/observability.md) -----------------------------
     # telemetry=1: run manifest as the FIRST JSONL record, span/* host
@@ -259,13 +261,9 @@ def _precision_default(run: RunConfig, overrides: dict) -> dict:
 
 def run_poincare(run: RunConfig, overrides: dict):
     _reject_accum(run, "poincare")
-    from hyperspace_tpu.data import wordnet
     from hyperspace_tpu.models import poincare_embed as pe
 
-    if run.data_root:
-        ds = wordnet.load_closure_tsv(run.data_root)
-    else:
-        ds = wordnet.synthetic_tree(depth=5, branching=4)
+    ds, data = _closure_dataset(run, branching=4)
     cfg = apply_overrides(
         pe.PoincareEmbedConfig(num_nodes=ds.num_nodes),
         _precision_default(run, overrides))
@@ -312,12 +310,13 @@ def run_poincare(run: RunConfig, overrides: dict):
             # beyond-HBM design at exactly the scale it exists for —
             # the sharded master (+ the serve lanes) is the product
             return {"workload": "poincare", "steps": int(trainer.step),
-                    "host_table": True, "eval_skipped": "beyond-hbm"}
+                    **data, "host_table": True,
+                    "eval_skipped": "beyond-hbm"}
         state = project(trainer.to_state())
         with _eval_span():
             res = pe.evaluate(state.table, ds.pairs, cfg.c)
         return {"workload": "poincare", "steps": int(state.step),
-                "host_table": True, **res}
+                **data, "host_table": True, **res}
     if run.scan_chunk > 1 and cfg.sparse:
         raise SystemExit(
             "scan_chunk>1 scans the dense step body only — drop "
@@ -329,13 +328,15 @@ def run_poincare(run: RunConfig, overrides: dict):
     stepper, spc = _chunked(run, lambda st: step_fn(cfg, opt, st, pairs))
     health_fn = _maybe_health(run, lambda: _make_health(
         ball, params_of=lambda st: st.table))
-    state, _ = _train_loop(run, state, stepper, project=project,
-                           steps_per_call=spc, health_fn=health_fn)
+    state, loss = _train_loop(run, state, stepper, project=project,
+                              steps_per_call=spc, health_fn=health_fn,
+                              data=data)
     with _eval_span():
         res = pe.evaluate(state.table, ds.pairs, cfg.c)
     # state.step is the authoritative count (survives resume/chunk
     # rounding — a resumed chunked run can legitimately exceed run.steps)
-    return {"workload": "poincare", "steps": int(state.step), **res}
+    return {"workload": "poincare", "steps": int(state.step), **data,
+            "loss": float(loss), **res}
 
 
 def _resume_chunk(run: RunConfig, chunk_steps: int) -> int:
@@ -443,6 +444,11 @@ def run_hgcn(run: RunConfig, overrides: dict):
     # caps the [S, B, f1, f2] id pyramid's device footprint on long runs
     plan_steps = int(overrides.pop("plan_steps", "64"))
     edges, x, labels, ncls, source = G.load_graph(dataset, run.data_root)
+    # which data ran, at what size: into the manifest AND the result — a
+    # synthetic stand-in for a named dataset must be visible in both
+    data = {"dataset": dataset, "source": source,
+            "num_nodes": int(x.shape[0]), "num_edges": int(len(edges)),
+            "feat_dim": int(x.shape[1])}
     if reorder not in ("0", "false", "no", "1", "true", "yes", "bfs",
                        "community"):
         raise SystemExit(
@@ -493,15 +499,15 @@ def run_hgcn(run: RunConfig, overrides: dict):
                                           steps_per_call=spc)
                 state, loss = _train_loop(
                     run, state, stepper, steps_per_call=spc,
-                    health_fn=_maybe_health(run, _make_health))
+                    health_fn=_maybe_health(run, _make_health), data=data)
             full = hgcn.HGCNLinkPred(cfg)
             with _eval_span():
                 res = {"loss": float(loss), **hgcn.evaluate_lp(
                     full, state.params, split, "test")}
-            return {"workload": "hgcn", "task": "lp", "dataset": dataset,
-                    "source": source, "sampled": True, **res}
+            return {"workload": "hgcn", "task": "lp", **data,
+                    "steps": int(state.step), "sampled": True, **res}
         model, opt, state = hgcn.init_lp(cfg, split.graph, seed=run.seed)
-        ga = hgcn._device_graph(split.graph)
+        ga = None  # mesh runs place the whole graph only for the eval
         if mesh is not None:
             from hyperspace_tpu.parallel import multihost as mh
 
@@ -521,15 +527,18 @@ def run_hgcn(run: RunConfig, overrides: dict):
                 model, opt, num_nodes, mesh, state, split)
             stepper, spc = _chunked(run, lambda st: step(st, ga_s, train_pos))
         else:
+            ga = hgcn._device_graph(split.graph)
             train_pos = jnp.asarray(split.train_pos)
             stepper, spc = _chunked(
                 run, lambda st: hgcn.train_step_lp(model, opt, num_nodes,
                                                    st, ga, train_pos))
         state, loss = _train_loop(run, state, stepper, steps_per_call=spc,
-                                  health_fn=_maybe_health(run, _make_health))
+                                  health_fn=_maybe_health(run, _make_health),
+                                  data=data)
         with _eval_span():
             res = {"loss": float(loss), **hgcn.evaluate_lp(
-                model, state.params, split, "test", ga=ga)}
+                model, _eval_params(state.params, mesh), split, "test",
+                ga=ga)}
     else:
         tr, va, te = G.node_split_masks(num_nodes, seed=run.seed)
         g = G.prepare(edges, num_nodes, x, labels=labels, num_classes=ncls,
@@ -565,13 +574,13 @@ def run_hgcn(run: RunConfig, overrides: dict):
                                           steps_per_call=spc)
                 state, loss = _train_loop(
                     run, state, stepper, steps_per_call=spc,
-                    health_fn=_maybe_health(run, _make_health))
+                    health_fn=_maybe_health(run, _make_health), data=data)
             full = hgcn.HGCNNodeClf(cfg)
             with _eval_span():
                 res = {"loss": float(loss),
                        **hgcn.evaluate_nc(full, state.params, g)}
-            return {"workload": "hgcn", "task": "nc", "dataset": dataset,
-                    "source": source, "sampled": True, **res}
+            return {"workload": "hgcn", "task": "nc", **data,
+                    "steps": int(state.step), "sampled": True, **res}
         model, opt, state = hgcn.init_nc(cfg, g, seed=run.seed)
         ga = hgcn._device_graph(g)
         lab = jnp.asarray(g.labels)
@@ -586,12 +595,24 @@ def run_hgcn(run: RunConfig, overrides: dict):
                 run, lambda st: hgcn.train_step_nc(model, opt, st, ga, lab,
                                                    mask))
         state, loss = _train_loop(run, state, stepper, steps_per_call=spc,
-                                  health_fn=_maybe_health(run, _make_health))
+                                  health_fn=_maybe_health(run, _make_health),
+                                  data=data)
         with _eval_span():
-            res = {"loss": float(loss),
-                   **hgcn.evaluate_nc(model, state.params, g, ga=ga)}
-    return {"workload": "hgcn", "task": task, "dataset": dataset,
-            "source": source, **res}
+            res = {"loss": float(loss), **hgcn.evaluate_nc(
+                model, _eval_params(state.params, mesh), g, ga=ga)}
+    return {"workload": "hgcn", "task": task, **data,
+            "steps": int(state.step), **res}
+
+
+def _eval_params(params, mesh):
+    """Parameters for the full-graph evaluation.  That program runs on
+    ONE device — its Pallas kernels sit outside any ``shard_map``, and
+    the partitioner refuses to place a Mosaic kernel by itself — so
+    parameters a mesh run left sharded over its devices are gathered
+    onto the device the evaluation graph goes to."""
+    if mesh is None:
+        return params
+    return jax.device_put(params, jax.local_devices()[0])
 
 
 def run_hybonet(run: RunConfig, overrides: dict):
@@ -600,6 +621,8 @@ def run_hybonet(run: RunConfig, overrides: dict):
 
     dataset = overrides.pop("dataset", "text")
     ds, source = T.load_text(dataset, run.data_root)
+    data = {"dataset": dataset, "source": source,
+            "num_examples": int(ds.tokens.shape[0])}
     tr, te = ds.split(0.8, seed=run.seed)
     cfg = apply_overrides(
         hybonet.HyboNetConfig(vocab_size=ds.vocab_size,
@@ -624,10 +647,12 @@ def run_hybonet(run: RunConfig, overrides: dict):
                                                      mask, labels)
     stepper, spc = _chunked(run, base)
     state, loss = _train_loop(run, state, stepper, steps_per_call=spc,
-                              health_fn=_maybe_health(run, _make_health))
+                              health_fn=_maybe_health(run, _make_health),
+                              data=data)
     with _eval_span():
         res = hybonet.evaluate(model, state.params, te)
-    return {"workload": "hybonet", "source": source, "loss": float(loss), **res}
+    return {"workload": "hybonet", **data, "steps": int(state.step),
+            "loss": float(loss), **res}
 
 
 def run_hvae(run: RunConfig, overrides: dict):
@@ -635,6 +660,7 @@ def run_hvae(run: RunConfig, overrides: dict):
     from hyperspace_tpu.models import hvae
 
     ds, source = M.load_mnist(run.data_root)
+    data = {"source": source, "num_examples": int(ds.images.shape[0])}
     cfg = apply_overrides(hvae.HVAEConfig(image_size=ds.images.shape[1]),
                           _precision_default(run, overrides))
     model, opt, state = hvae.init_model(cfg, seed=run.seed)
@@ -665,27 +691,24 @@ def run_hvae(run: RunConfig, overrides: dict):
         return st, loss
 
     state, loss = _train_loop(run, state, stepper, steps_per_call=spc,
-                              health_fn=_maybe_health(run, _make_health))
+                              health_fn=_maybe_health(run, _make_health),
+                              data=data)
     recon, kl = (float(v) for v in metrics.get("rk", (jnp.nan,) * 2))
     loss = float(loss)
     x = jnp.asarray(ds.images[:256], cfg.dtype)
     with _eval_span():
         iwae = float(hvae.iwae_bound(model, state.params, x,
                                      jax.random.PRNGKey(1), k=16))
-    return {"workload": "hvae", "source": source, "loss": loss, "recon": recon,
-            "kl": kl, "iwae": iwae}
+    return {"workload": "hvae", **data, "steps": int(state.step),
+            "loss": loss, "recon": recon, "kl": kl, "iwae": iwae}
 
 
 def run_product(run: RunConfig, overrides: dict):
     _reject_accum(run, "product")
-    from hyperspace_tpu.data import wordnet
     from hyperspace_tpu.models import product_embed as pme
     from hyperspace_tpu.parallel.mesh import auto_mesh
 
-    if run.data_root:
-        ds = wordnet.load_closure_tsv(run.data_root)
-    else:
-        ds = wordnet.synthetic_tree(depth=5, branching=3)
+    ds, data = _closure_dataset(run, branching=3)
     cfg = apply_overrides(
         pme.ProductEmbedConfig(num_nodes=ds.num_nodes),
         _precision_default(run, overrides))
@@ -716,12 +739,14 @@ def run_product(run: RunConfig, overrides: dict):
 
         return jax.jit(fn)
 
-    state, _ = _train_loop(run, state, stepper, project=project,
-                           steps_per_call=spc,
-                           health_fn=_maybe_health(run, product_health))
+    state, loss = _train_loop(run, state, stepper, project=project,
+                              steps_per_call=spc,
+                              health_fn=_maybe_health(run, product_health),
+                              data=data)
     with _eval_span():
         res = pme.evaluate(cfg, state.params, ds.pairs)
-    return {"workload": "product", **res,
+    return {"workload": "product", "steps": int(state.step), **data,
+            "loss": float(loss), **res,
             "curvatures": pme.curvatures(cfg, state.params)}
 
 
@@ -737,8 +762,24 @@ WORKLOADS = {
 # --- helpers ------------------------------------------------------------------
 
 
+def _closure_dataset(run: RunConfig, branching: int):
+    """(dataset, data record) for the closure-embedding workloads: the
+    ``data_root`` TSV, else a small synthetic tree that stands in for
+    tests — the record says which one ran and at what size (manifest
+    and result)."""
+    from hyperspace_tpu.data import wordnet
+
+    if run.data_root:
+        ds, source = wordnet.load_closure_tsv(run.data_root), "disk"
+    else:
+        ds, source = wordnet.synthetic_tree(depth=5,
+                                            branching=branching), "synthetic"
+    return ds, {"source": source, "num_nodes": int(ds.num_nodes),
+                "num_pairs": int(ds.num_pairs)}
+
+
 def _train_loop(run: RunConfig, state, stepper, project=None,
-                steps_per_call=1, health_fn=None):
+                steps_per_call=1, health_fn=None, data=None):
     """The ONE step loop every workload runner goes through — moved to
     :func:`hyperspace_tpu.train.loop.run_loop` (checkpoint/resume, JSONL
     logging with boundary-crossing cadence, per-chunk loss accumulation,
@@ -750,7 +791,8 @@ def _train_loop(run: RunConfig, state, stepper, project=None,
 
     return run_loop(run, state, stepper, project=project,
                     steps_per_call=steps_per_call, health_fn=health_fn,
-                    on_rollback=getattr(stepper, "on_rollback", None))
+                    on_rollback=getattr(stepper, "on_rollback", None),
+                    data=data)
 
 
 def _maybe_health(run: RunConfig, build):
@@ -830,6 +872,14 @@ def main(argv: list[str] | None = None) -> int:
         mh.initialize(run.coordinator, run.num_processes, run.process_id)
     from hyperspace_tpu.telemetry import cli_session
 
+    # where a resumed run starts: with the result's final step count it
+    # tells a run that stepped from one with nothing left to do
+    start_step = 0
+    if run.resume and run.ckpt_dir:
+        from hyperspace_tpu.train.checkpoint import peek_latest_step
+
+        start_step = peek_latest_step(run.ckpt_dir)
+
     # enabled BEFORE the workload runs (not inside run_loop) so host
     # graph prep / cache misses land in the spans and trace too; the
     # trace dumps in cli_session's finally — a crash (incl. health_abort)
@@ -846,18 +896,26 @@ def main(argv: list[str] | None = None) -> int:
             # the registry is process-global: an in-process caller
             # (tests, benches) must never inherit this run's faults
             _faults.clear()
+    loss = result.get("loss")
+    diverged = False
+    if isinstance(loss, float) and not math.isfinite(loss):
+        if result.get("steps", 0) > start_step:
+            # the run stepped and ended on a non-finite loss: that is a
+            # failed run, and its exit code says so
+            diverged = result["diverged"] = True
+        else:
+            result["no_steps_run"] = True  # resumed past its step budget
     print(json.dumps(_json_safe(result)))
-    return 0
+    return 1 if diverged else 0
 
 
 def _json_safe(x):
     """Non-finite floats → null and numpy scalars → Python, so every
     emitted line is strict JSON (loss is nan when a resumed run had
-    nothing left to do or a run diverged; a NaN table row reaches the
-    serve CLI's response stream the same way — all must print parseably).
+    nothing left to do — ``no_steps_run``, exit 0 — or when a run
+    diverged — ``diverged``, exit 1; a NaN table row reaches the serve
+    CLI's response stream the same way — all must print parseably).
     Shared by the train and serve CLIs."""
-    import math
-
     if isinstance(x, np.generic):
         x = x.item()
     if isinstance(x, float) and not math.isfinite(x):

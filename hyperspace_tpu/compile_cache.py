@@ -11,14 +11,21 @@ pure waste.  This module points JAX's on-disk compilation cache
 (``jax_compilation_cache_dir``) at a persistent directory so run #2 of
 anything deserializes executables instead of invoking XLA.
 
-**Resolution order** (:func:`resolve_dir`): an explicit
-``compile_cache_dir=`` flag wins; else the ``HYPERSPACE_COMPILE_CACHE``
-env var; else the default ``<repo>/.cache/jax_compile`` beside the
-graph-prep cache.  The cache is **on by default**; the value ``0`` (or
-``false``/``no``/``off``) at either level disables it.  A directory
-that cannot be created or written is a loud :class:`ValueError` (the
-CLIs turn it into a clean usage exit) — a silently-dead cache would
-re-create exactly the cold-start cliff this exists to kill.
+**Where the cache lives** (:func:`resolve_dir`): where
+``JAX_COMPILATION_CACHE_DIR`` says, if it is set — jax reads that
+variable itself, and this module then sets no other directory (an
+explicit path that disagrees with it is a :class:`ValueError`, never a
+silent second cache).  With the variable unset: an explicit
+``compile_cache_dir=`` flag, else the ``HYPERSPACE_COMPILE_CACHE`` env
+var, else the fixed default ``<repo>/.cache/jax_compile`` beside the
+graph-prep cache — fixed, because the path is part of what a caller
+must repeat to hit the cache again.  The cache is **on by default**;
+the value ``0`` (or ``false``/``no``/``off``) in the flag or in
+``HYPERSPACE_COMPILE_CACHE`` disables it wherever it would have lived.
+A directory that cannot be created or written is a loud
+:class:`ValueError` (the CLIs turn it into a clean usage exit) — a
+silently-dead cache would re-create exactly the cold-start cliff this
+exists to kill.
 
 **Cache-everything policy**: ``jax_persistent_cache_min_compile_time_
 secs`` is set to 0 and the min-entry-size check is disabled, so even
@@ -46,13 +53,29 @@ import os
 from typing import Optional
 
 ENV_VAR = "HYPERSPACE_COMPILE_CACHE"
+JAX_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 _OFF_VALUES = ("0", "false", "no", "off")
 
 # activation state: the directory the cache was pointed at (None = not
-# activated / disabled) plus the jax config value activation replaced
-# (tests/conftest.py points the suite at its own cache — deactivate
-# must restore it, not blank it).
-_state: dict = {"dir": None, "prev": None}
+# activated / disabled) plus, once this module has written jax's cache
+# config, the value it replaced (tests/conftest.py points the suite at
+# its own cache — deactivate must restore it, not blank it).
+_state: dict = {"dir": None, "prev": None, "changed": False}
+
+
+def _set_jax_dir(jax, d: Optional[str]) -> None:
+    """Write jax's cache-dir config, remembering the first value
+    replaced, and drop jax's in-process file-cache singleton when one
+    may exist: it is initialized once for the FIRST directory used, so
+    re-pointing the config alone would keep writing to the old dir."""
+    prev = jax.config.jax_compilation_cache_dir
+    if not _state["changed"]:
+        _state["prev"], _state["changed"] = prev, True
+    jax.config.update("jax_compilation_cache_dir", d)
+    if prev is not None:
+        from jax.experimental.compilation_cache import compilation_cache
+
+        compilation_cache.reset_cache()
 
 
 def default_dir() -> str:
@@ -63,29 +86,42 @@ def default_dir() -> str:
 
 
 def resolve_dir(flag: Optional[str] = None) -> Optional[str]:
-    """The cache directory to use, or None when disabled.
-
-    ``flag`` is the CLI's ``compile_cache_dir=`` value (None = not
-    given); the env var covers flag-less entry points; the default is
-    ON — persistent caching must not depend on every caller
-    remembering a flag."""
+    """The cache directory to use, or None when disabled (module
+    docstring, "Where the cache lives").  ``flag`` is the CLI's
+    ``compile_cache_dir=`` value (None = not given).  Raises
+    :class:`ValueError` for an explicit path that disagrees with
+    ``JAX_COMPILATION_CACHE_DIR``."""
     v = flag if flag not in (None, "") else os.environ.get(ENV_VAR, "")
-    if v:
-        return None if v.strip().lower() in _OFF_VALUES else v
-    return default_dir()
+    if v and v.strip().lower() in _OFF_VALUES:
+        return None
+    jax_dir = os.environ.get(JAX_ENV_VAR, "")
+    if jax_dir:
+        if v and os.path.abspath(v) != os.path.abspath(jax_dir):
+            raise ValueError(
+                f"compile_cache_dir={v!r} disagrees with "
+                f"{JAX_ENV_VAR}={jax_dir!r} — the variable decides where "
+                "the cache lives; drop one of the two")
+        return jax_dir
+    return v or default_dir()
 
 
 def activate(flag: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at the resolved dir.
+    """Turn JAX's persistent compilation cache on at the resolved dir.
 
-    Returns the directory in use, or None when disabled.  Raises
-    :class:`ValueError` for a directory that cannot be created or
-    written (callers map it to a clean usage error).  Idempotent —
-    re-activating with the same resolution is a no-op; a different
-    explicit dir re-points the cache (jax re-reads the config value
-    per compile)."""
+    Returns the directory in use, or None when disabled (the cache is
+    then switched off even where ``JAX_COMPILATION_CACHE_DIR`` names a
+    directory).  Raises :class:`ValueError` for a directory that cannot
+    be created or written (callers map it to a clean usage error).
+    Idempotent — re-activating with the same resolution is a no-op; a
+    different explicit dir re-points the cache (jax re-reads the config
+    value per compile)."""
+    import jax
+
     d = resolve_dir(flag)
     if d is None:
+        if jax.config.jax_compilation_cache_dir is not None:
+            _set_jax_dir(jax, None)
+        _state["dir"] = None
         return None
     d = os.path.abspath(d)
     try:
@@ -99,24 +135,14 @@ def activate(flag: Optional[str] = None) -> Optional[str]:
         raise ValueError(
             f"compile_cache_dir={d!r}: directory is not writable — "
             "fix permissions or disable with compile_cache_dir=0")
-    import jax
-
-    prev_cfg = jax.config.jax_compilation_cache_dir
-    if _state["dir"] is None:
-        _state["prev"] = prev_cfg
-    jax.config.update("jax_compilation_cache_dir", d)
-    if prev_cfg is not None and prev_cfg != d:
-        # a cache was already configured (and possibly initialized) at
-        # another dir in this process: drop the singleton so entries
-        # actually land where the new config points
-        _reset_jax_cache_object()
+    if jax.config.jax_compilation_cache_dir != d:
+        # (with JAX_COMPILATION_CACHE_DIR set to an absolute path, jax
+        # already holds this very directory and nothing is written)
+        _set_jax_dir(jax, d)
     # cache-everything policy (module docstring): the serve ladder is
     # made of sub-second executables, and those ARE the cold-start cost
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:
-        pass  # older jax without the size gate: nothing to disable
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _state["dir"] = d
     # hit/miss counters ride the shared monitoring hook (idempotent)
     from hyperspace_tpu.telemetry import registry as telem
@@ -139,25 +165,8 @@ def deactivate() -> None:
     """Restore the pre-activation cache config (tests: jax config is
     process-global — a test that activated must not leak its dir into
     the next, nor blank a cache the harness had already pointed)."""
-    if _state["dir"] is None:
-        return
-    import jax
+    if _state["changed"]:
+        import jax
 
-    jax.config.update("jax_compilation_cache_dir", _state["prev"])
-    _reset_jax_cache_object()
-    _state["dir"] = None
-    _state["prev"] = None
-
-
-def _reset_jax_cache_object() -> None:
-    """Drop jax's in-process file-cache singleton: it is initialized
-    once for the FIRST directory used, so re-pointing the config alone
-    would silently keep writing to the old dir.  Private API —
-    best-effort (a jax without it just keeps the first dir, which only
-    in-process re-activation ever hits)."""
-    try:
-        from jax._src import compilation_cache as _jcc
-
-        _jcc.reset_cache()
-    except Exception:  # noqa: BLE001  # hyperlint: disable=swallow-base-exception — private-API drift: the first-activated dir keeps working, only an in-process re-point degrades
-        pass
+        _set_jax_dir(jax, _state["prev"])
+    _state.update(dir=None, prev=None, changed=False)

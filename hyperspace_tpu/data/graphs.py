@@ -274,7 +274,7 @@ def _build_edge_layout(edges, num_nodes, *, symmetrize, self_loops,
             self_loops=self_loops, pad_multiple=pad_multiple)
         if not symmetrize:
             rev_perm = None
-    except (ImportError, OSError):
+    except ImportError:  # no C++ compiler installed (data/native.py)
         pass
     if senders is None:
         senders, receivers, mask, rev_perm, deg = _prepare_edges_numpy(
@@ -366,7 +366,7 @@ def split_edges(
                     canon, num_nodes, k, seed=int(rng.integers(2**31)))
                 if len(neg) == k:
                     return neg.astype(np.int64)
-            except (ImportError, OSError):
+            except ImportError:  # no C++ compiler (data/native.py)
                 pass
             edge_set = {(int(u), int(v)) for u, v in canon}
             out = []
@@ -584,7 +584,8 @@ def community_power_law_graph(
       neighbors of a shared node, lifting the clustering coefficient
       from the SBM's near-zero toward citation-graph levels.
 
-    Returns (edges [E, 2] directed, x [N, F], labels [N], num_classes).
+    Returns (edges [E, 2] directed with E == ``num_edges`` exactly,
+    x [N, F], labels [N], num_classes).
     """
     rng = np.random.default_rng(seed)
     # truncated power-law degree propensities (inverse-transform Pareto)
@@ -637,26 +638,65 @@ def community_power_law_graph(
     edges = np.stack([senders, receivers], axis=1)
     edges = edges[edges[:, 0] != edges[:, 1]]
 
-    # triadic closure: connect two neighbors of a shared pivot
+    # triadic closure: connect two neighbors of a shared pivot — pair
+    # the receivers of two edges that share a sender (adjacent in sender
+    # order).  Pivots are drawn among the pairs that do close a triangle,
+    # so the published edge count is met exactly
     n_tri = num_edges - len(edges)
     if n_tri > 0:
-        # close triangles by pairing receivers of edges sharing a sender:
-        # sort by sender, draw pivot edges, connect each pivot's receiver
-        # to its sender-sorted neighbor's receiver
-        pivots = rng.choice(len(edges), size=n_tri)
-        bysend = np.argsort(edges[:, 0], kind="stable")
-        a = edges[bysend[pivots], :]
-        b = edges[bysend[np.minimum(pivots + 1, len(edges) - 1)], :]
-        share = a[:, 0] == b[:, 0]
-        tri = np.stack([a[share, 1], b[share, 1]], axis=1)
-        tri = tri[tri[:, 0] != tri[:, 1]]
-        edges = np.concatenate([edges, tri], axis=0)[:num_edges]
+        by_sender = edges[np.argsort(edges[:, 0], kind="stable")]
+        a, b = by_sender[:-1], by_sender[1:]
+        closes = np.flatnonzero((a[:, 0] == b[:, 0]) & (a[:, 1] != b[:, 1]))
+        if not len(closes):
+            raise ValueError(
+                "community_power_law_graph: no two edges share a sender, "
+                "so no triangle can be closed — raise num_edges or set "
+                "triadic_frac=0")
+        pivots = rng.choice(closes, size=n_tri)
+        tri = np.stack([a[pivots, 1], b[pivots, 1]], axis=1)
+        edges = np.concatenate([edges, tri], axis=0)
 
     protos = rng.normal(size=(num_classes, feat_dim)).astype(np.float32)
     labels = comm.astype(np.int32)
     x = protos[labels] + 0.4 * rng.normal(
         size=(num_nodes, feat_dim)).astype(np.float32)
     return edges.astype(np.int64), x, labels, num_classes
+
+
+def ensure_arxiv_scale_dataset(root: str | None = None, seed: int = 0,
+                               **graph_kw) -> str:
+    """Materialize :func:`community_power_law_graph` at its default, the
+    published ogbn-arxiv shape (169,343 nodes, 1,166,243 directed edges,
+    128 features, 40 classes), on disk in the OGB extracted-csv layout
+    that ``load_graph("ogbn-arxiv", root)`` reads (~200 MB; generated
+    once per ``root``, default ``<repo>/.cache/arxiv-synth``).  Returns
+    ``root``.  ``graph_kw`` go to the generator: tests shrink the graph
+    with them, everything else runs the default shape.
+
+    The stand-in for the real download wherever there is no network:
+    the trainer then runs at the real size through the real disk →
+    ``load_ogbn_arxiv`` → ``prepare`` pipeline (``source: "disk"``)
+    instead of on :func:`load_graph`'s small synthetic hierarchy.
+    """
+    import shutil
+
+    if root is None:
+        root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
+                            ".cache", "arxiv-synth")
+    root = os.path.abspath(root)
+    if not os.path.exists(os.path.join(root, "raw", "edge.csv")):
+        # write into a temp sibling and rename whole: an interrupted
+        # generation must not leave a half-written tree that the
+        # edge.csv existence sentinel would treat as complete
+        tmp = root + ".tmp"
+        shutil.rmtree(tmp, ignore_errors=True)
+        edges, x, labels, _ = community_power_law_graph(seed=seed,
+                                                        **graph_kw)
+        write_ogb_csv_layout(tmp, edges, x, labels)
+        os.makedirs(os.path.dirname(root), exist_ok=True)
+        shutil.rmtree(root, ignore_errors=True)
+        os.replace(tmp, root)
+    return root
 
 
 def node_split_masks(num_nodes: int, train_frac=0.6, val_frac=0.2, seed: int = 0):
@@ -674,10 +714,16 @@ def node_split_masks(num_nodes: int, train_frac=0.6, val_frac=0.2, seed: int = 0
 
 
 def load_graph(name: str, root: str | None = None, **synth_kw):
-    """Dispatch: real dataset if its files exist under ``root``, else synthetic.
+    """Dispatch: the dataset's files under ``root`` if they exist, else
+    a SMALL synthetic hierarchy that stands in for tests and demos —
+    2,048 nodes for ``cora``, 16,384 for ``ogbn-arxiv`` — same feature
+    and class counts as the named dataset, NOT its size (for the
+    published arxiv shape without a download, write
+    :func:`ensure_arxiv_scale_dataset` and pass its root).
 
     Returns (edges, x, labels, num_classes, source) where source is
-    "disk" or "synthetic".
+    "disk" or "synthetic"; callers record it, with the node and edge
+    counts, in the run manifest and result (cli/train.py).
     """
     if root is not None:
         if name == "cora" and os.path.exists(os.path.join(root, "cora.content")):
@@ -722,7 +768,7 @@ def locality_order(edges: np.ndarray, num_nodes: int) -> np.ndarray:
         from hyperspace_tpu.data import native
 
         return native.locality_order(np.asarray(e, np.int32), num_nodes)
-    except (ImportError, OSError):
+    except ImportError:  # no C++ compiler installed (data/native.py)
         return _locality_order_python(e, num_nodes)
 
 

@@ -1,21 +1,29 @@
 """ctypes bindings for the native C++ data helpers (SURVEY.md §2 "Data").
 
-Compiles ``_native/closure.cc`` with g++ on first use into the package's
-``_native`` directory (cached by source mtime) and exposes:
+Compiles the ``_native/*.cc`` sources with g++ on first use into the
+package's ``_native`` directory and exposes:
 
 - :func:`transitive_closure` — WordNet-scale DAG closure (the hook
   :mod:`hyperspace_tpu.data.wordnet` dispatches to),
 - :func:`sample_negative_edges` — rejection-sampled LP negatives at
   arxiv scale (used by :mod:`hyperspace_tpu.data.graphs`).
 
+The built library is named by a hash of its sources
+(``libhsdata-<sha256[:16]>.so``): a library built from other sources —
+a stale one copied along with a checkout, whatever its mtime — has
+another name and is never loaded.
+
 No pybind11 in this environment: plain C ABI + ctypes (the sanctioned
-binding route).  Raises ImportError if no C++ toolchain is available, and
-callers fall back to their pure-Python/numpy paths.
+binding route).  Raises ImportError if no C++ compiler is installed, and
+callers then keep their pure-Python/numpy paths.  With a compiler
+present, a failed build or load is an error (RuntimeError / OSError),
+not a quiet switch to the slow path.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import shutil
 import subprocess
@@ -25,26 +33,36 @@ import numpy as np
 _DIR = os.path.join(os.path.dirname(__file__), "_native")
 _SRCS = [os.path.join(_DIR, "closure.cc"), os.path.join(_DIR, "graphprep.cc"),
          os.path.join(_DIR, "localorder.cc"), os.path.join(_DIR, "sampler.cc")]
-_LIB = os.path.join(_DIR, "libhsdata.so")
 
 _lib = None
 
 
+def _lib_path() -> str:
+    h = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_DIR, f"libhsdata-{h.hexdigest()[:16]}.so")
+
+
 def _build() -> str:
+    lib = _lib_path()
+    if os.path.exists(lib):
+        return lib
     cxx = shutil.which("g++") or shutil.which("c++")
     if cxx is None:
         raise ImportError("no C++ compiler for hyperspace_tpu native helpers")
-    src_mtime = max(os.path.getmtime(s) for s in _SRCS)
-    if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < src_mtime:
-        cmd = [cxx, "-O2", "-std=c++17", "-shared", "-fPIC", *_SRCS,
-               "-o", _LIB + ".tmp"]
-        try:
-            subprocess.run(cmd, check=True, capture_output=True)
-        except subprocess.CalledProcessError as e:  # callers fall back on
-            raise ImportError(                      # ImportError (module doc)
-                f"native helper build failed: {e.stderr.decode()[:500]}") from e
-        os.replace(_LIB + ".tmp", _LIB)
-    return _LIB
+    # a name of its own per builder, renamed whole: concurrent first
+    # uses (test workers) never load a half-written library
+    tmp = f"{lib[:-3]}.{os.getpid()}.so.tmp"
+    cmd = [cxx, "-O2", "-std=c++17", "-shared", "-fPIC", *_SRCS, "-o", tmp]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"native helper build failed: {e.stderr.decode()[:500]}") from e
+    os.replace(tmp, lib)
+    return lib
 
 
 def _load() -> ctypes.CDLL:

@@ -6,8 +6,8 @@ no network access and no bundled WordNet dump, so the loader accepts any
 edge list in TSV form (``child<TAB>parent`` per line, the format the
 published closure files use) and can also synthesize benchmark trees of a
 chosen size.  The transitive closure is computed by the native C++ helper
-(``hyperspace_tpu.data.native``) when its extension has been built, else by
-a pure-Python DFS fallback.
+(``hyperspace_tpu.data.native``, built from source on first use), or by
+a pure-Python DFS fallback where no C++ compiler is installed.
 
 Negative sampling is done *on device* inside the jitted train step with
 ``jax.random`` — the host never touches the per-step batch (SURVEY.md §3.1:
@@ -17,6 +17,7 @@ host→device once per batch, or none when the closure fits on device).
 from __future__ import annotations
 
 import dataclasses
+import os
 
 import numpy as np
 
@@ -60,14 +61,14 @@ def load_edges_tsv(path: str) -> tuple[np.ndarray, list[str]]:
 def transitive_closure(edges: np.ndarray, num_nodes: int) -> np.ndarray:
     """All (node, ancestor) pairs reachable through the parent relation.
 
-    Uses the native C++ closure (hyperspace_tpu.data.native) when the
-    extension is built; otherwise a pure-Python DFS fallback.
+    Uses the native C++ closure (hyperspace_tpu.data.native); the
+    pure-Python DFS runs only where no C++ compiler is installed.
     """
     try:
         from hyperspace_tpu.data import native
 
         return native.transitive_closure(edges, num_nodes)
-    except (ImportError, OSError):  # no toolchain / build failed
+    except ImportError:  # no C++ compiler installed (data/native.py)
         return _closure_numpy(edges, num_nodes)
 
 
@@ -96,6 +97,17 @@ def load_closure_tsv(path: str, already_closed: bool = True) -> ClosureDataset:
     n = len(names)
     pairs = edges if already_closed else transitive_closure(edges, n)
     return ClosureDataset(pairs=pairs, num_nodes=n, names=names)
+
+
+def write_closure_tsv(path: str, ds: ClosureDataset) -> None:
+    """Write ``ds.pairs`` as the ``child<TAB>ancestor`` lines
+    :func:`load_closure_tsv` reads (node names are the integer ids) —
+    the disk end of the disk → load → train pipeline for generated
+    hierarchies."""
+    tmp = f"{path}.tmp"
+    with open(tmp, "w") as f:
+        f.writelines(f"{u}\t{v}\n" for u, v in ds.pairs.tolist())
+    os.replace(tmp, path)
 
 
 def synthetic_tree(depth: int, branching: int, seed: int = 0) -> ClosureDataset:

@@ -38,14 +38,12 @@ ARTANH_EPS_F32 = 3e-7
 
 
 def tpu_compiler_params(**kw):
-    """``pltpu.CompilerParams`` across the jax rename: newer jax calls it
-    ``CompilerParams``, 0.4.x ``TPUCompilerParams`` — same fields either
-    way (``dimension_semantics`` etc.).  Kernels must build against
-    both, so this is the one place the name is resolved."""
+    """``pltpu.CompilerParams(**kw)`` (``dimension_semantics`` etc.) —
+    imported here so kernel modules need no pallas-tpu import of their
+    own."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
+    return pltpu.CompilerParams(**kw)
 
 
 def mode() -> str:

@@ -71,7 +71,7 @@ in VMEM) to distance-scan-top-k:
   tiles in-kernel — two VMEM tile slots, the async HBM→VMEM copy of
   tile i+1 issued BEFORE tile i's Gram/fold math, one DMA semaphore
   per slot (the slab and its scale/code companions live in
-  ``pltpu.ANY`` memory space).  The tile ORDER and math are exactly
+  ``pl.ANY`` memory space).  The tile ORDER and math are exactly
   the implicit-grid schedule's, so the twin (and results) are
   unchanged; only the copy/compute overlap is now explicit.  The
   candidate variant keeps the implicit grid pipeline (its stream is a
@@ -659,7 +659,7 @@ def _launch_slab(slab, q, q_idx, col0, *, kind, c, k, n, bm, exclude_self,
         pl.BlockSpec((bq, 128), lambda iq: (iq, 0),
                      memory_space=pltpu.VMEM),
         # the slab stays in HBM: the body's DMA pipeline streams it
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [S.c_smem(c), i32(col0), i32(n), i32(nloc), qp, qip, yp]
     scratch = [
@@ -670,7 +670,7 @@ def _launch_slab(slab, q, q_idx, col0, *, kind, c, k, n, bm, exclude_self,
         pltpu.SemaphoreType.DMA((2,)),
     ]
     if scale is not None:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         operands.append(_scale_pad(scale, bm))
         scratch += [pltpu.VMEM((2, bm, 128), jnp.float32),
                     pltpu.SemaphoreType.DMA((2,))]
@@ -878,7 +878,7 @@ def _launch_pq(codes, lut, q_idx, col0, *, kind, c, k, n, m, bm,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((bq, 128), lambda iq: (iq, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((bq, kp), lambda iq: (iq, 0),

@@ -32,13 +32,17 @@ def minkowski_dot(x: jax.Array, y: jax.Array, keepdims: bool = True) -> jax.Arra
 def _pad_last(x: jax.Array, lo: int, hi: int) -> jax.Array:
     """Zero-pad the last axis by (lo, hi) — the time-coordinate
     assembly primitive.  Every Lorentz lift/split used to be a
-    ``jnp.concatenate``; jax 0.4.37's GSPMD partitioner miscompiles
+    ``jnp.concatenate``; an earlier jax's GSPMD partitioner miscompiled
     `concatenate` whose operands are sharded over a subset of a
-    multi-axis mesh (the dp×tp trap documented in
-    tests/parallel/test_node_sharded.py), so the lifts are written as
-    pad(+add) instead — `lax.pad` partitions cleanly.  Bitwise-equal to
-    the concat form (x + 0.0 and x - 0.0 are exact), except that a
-    -0.0 operand landing on a zero-padded lane comes out +0.0."""
+    multi-axis mesh, so the lifts were rewritten as pad(+add).  The
+    installed jax (0.9.0) partitions that concatenate correctly
+    (tests/parallel/test_node_sharded.py::
+    test_gspmd_concat_under_subset_constraint passes); the pad+add form
+    stays until a benchmark cell has compared the two on the chip —
+    it is on the compiled hot path of every Lorentz model.
+    Bitwise-equal to the concat form (x + 0.0 and x - 0.0 are exact),
+    except that a -0.0 operand landing on a zero-padded lane comes out
+    +0.0."""
     cfg = [(0, 0)] * (x.ndim - 1) + [(lo, hi)]
     return jnp.pad(x, cfg)
 

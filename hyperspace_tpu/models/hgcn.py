@@ -165,12 +165,10 @@ class HGCNLinkPred(nn.Module):
     def split_pair_logits(self, g: graph_data.DeviceGraph, pos, neg, *,
                           deterministic=True):
         """``(pos_logits, neg_logits)`` with ONE encoder pass and NO
-        concatenation of the two pair batches — the dp×tp-safe form
-        of :meth:`__call__`: this image's jax 0.4.37 GSPMD miscompiles
-        ``concatenate`` when any operand or consumer carries a
-        batch-sharding constraint over a subset of a multi-axis mesh's
-        axes (see ``_lp_step_impl``), so the sharded LP step gathers
-        the two batches separately and combines scalars only."""
+        concatenation of the two pair batches — the form the sharded LP
+        step takes on a multi-axis mesh (see ``_lp_step_impl``): it
+        gathers the two batches separately and combines scalars
+        only."""
         z, m = HGCNEncoder(self.cfg, name="encoder")(
             g, deterministic=deterministic
         )
@@ -316,20 +314,20 @@ def _lp_step_impl(model, opt, num_nodes, state, g, train_pos, constrain=None,
     def loss_fn(params):
         if constrain is not None and split_pairs:
             # multi-axis-mesh form: NO concatenate anywhere near the
-            # constrained batch.  This image's jax 0.4.37 GSPMD
-            # miscompiles `concatenate` when any operand — or any
-            # downstream consumer, via backward sharding propagation —
-            # carries a with_sharding_constraint over a proper subset
-            # of a multi-axis mesh's axes (P(("data",), None) on a
-            # dp×tp mesh): the output is assembled from the model-axis
-            # sub-shard with full-width strides, garbling every row's
-            # VALUES, not just their order (root-caused in PR 9;
-            # reduced repro: tests/parallel/test_node_sharded.py::
-            # test_gspmd_concat_constraint_miscompile).  So under such
-            # a mesh the step gathers pos and neg separately (one
-            # encoder pass, no pair concat) and combines scalar sums.
-            # Single-axis (dp-only) meshes partition the concat
-            # correctly and keep the historical form below, unchanged.
+            # constrained batch.  An earlier jax's GSPMD miscompiled
+            # `concatenate` when any operand — or any downstream
+            # consumer, via backward sharding propagation — carried a
+            # with_sharding_constraint over a proper subset of a
+            # multi-axis mesh's axes (P(("data",), None) on a dp×tp
+            # mesh), so under such a mesh the step gathers pos and neg
+            # separately (one encoder pass, no pair concat) and
+            # combines scalar sums.  The installed jax (0.9.0) compiles
+            # the reduced repro correctly (tests/parallel/
+            # test_node_sharded.py::
+            # test_gspmd_concat_under_subset_constraint); this branch
+            # stays until a benchmark cell has compared the two forms
+            # on the chip.  Single-axis (dp-only) meshes keep the
+            # historical form below, unchanged.
             pos_logit, neg_logit = model.apply(
                 {"params": params}, g,
                 constrain(train_pos), constrain(neg),
@@ -498,9 +496,10 @@ def train_step_lp_planned(
 
 def _concat_hazard(mesh) -> bool:
     """True when ``mesh`` has a non-trivial axis outside the
-    batch-sharding ("host"/"data") set — the mesh shape under which
-    this image's jax 0.4.37 GSPMD miscompiles a constrained
-    ``concatenate`` (``_lp_step_impl``'s split_pairs rationale)."""
+    batch-sharding ("host"/"data") set — the mesh shape under which an
+    earlier jax's GSPMD miscompiled a constrained ``concatenate``
+    (``_lp_step_impl``'s split_pairs rationale; fixed in the installed
+    jax, the split form stays until measured)."""
     return any(int(mesh.shape[a]) > 1 for a in mesh.axis_names
                if a not in ("host", "data"))
 
@@ -548,9 +547,8 @@ def make_sharded_step_lp(
 
     # batch enters replicated and is constrained *in-program* (like
     # product_embed.make_sharded_step): a partitioned in_sharding would
-    # reject process-local arrays on a multi-host mesh (and segfaults
-    # XLA CPU on jax 0.4.37 when combined with restored+donated state on
-    # a dp×tp mesh).  The per-host data plane feeds the node-sharded
+    # reject process-local arrays on a multi-host mesh.  The per-host
+    # data plane feeds the node-sharded
     # builder below, which takes pairs batch-sharded.
     step = jax.jit(
         partial(_lp_step_impl, model, opt, num_nodes, constrain=constrain,

@@ -242,7 +242,7 @@ def _sample(indptr, indices, seeds, fanout, seed):
         from hyperspace_tpu.data import native
 
         return native.sample_neighbors(indptr, indices, seeds, fanout, seed)
-    except (ImportError, OSError):
+    except ImportError:  # no C++ compiler installed (data/native.py)
         from hyperspace_tpu.data.native import sample_neighbors_numpy
 
         return sample_neighbors_numpy(indptr, indices, seeds, fanout, seed)

@@ -24,37 +24,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across the API move — the ONE place the name is
-    resolved.  Newer jax exposes it as ``jax.shard_map`` (replication
-    checking flag ``check_vma=``); 0.4.x has
-    ``jax.experimental.shard_map.shard_map`` (same flag named
-    ``check_rep=``).  ``check_vma=None`` means "library default" on
-    either version."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def pcast_varying(x, axis_name):
-    """Mark ``x`` varying over ``axis_name`` inside a shard_map body —
-    ``jax.lax.pcast(..., to="varying")`` on newer jax, ``jax.lax.pvary``
-    where that's the spelling, and a no-op on 0.4.x, whose shard_map has
-    no varying-axes typing to satisfy."""
-    pc = getattr(jax.lax, "pcast", None)
-    if pc is not None:
-        return pc(x, axis_name, to="varying")
-    pv = getattr(jax.lax, "pvary", None)
-    if pv is not None:
-        return pv(x, axis_name)
-    return x
-
-
 def make_mesh(
     axes: dict[str, int] | None = None,
     *,

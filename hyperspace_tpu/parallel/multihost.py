@@ -184,17 +184,15 @@ def sync(name: str = "barrier") -> None:
     process group is up, so it works on every backend — including the
     CPU loopback topology, whose backend cannot execute cross-process
     device collectives (``sync_global_devices`` aborts there).  Falls
-    back to ``sync_global_devices`` if there is no coordination client,
-    and is a no-op single-process.
+    back to ``sync_global_devices`` if there is no coordination client
+    (a multi-process runtime brought up without
+    ``jax.distributed.initialize``), and is a no-op single-process.
     """
     if jax.process_count() == 1:
         return
-    try:
-        from jax._src import distributed
+    from jax._src import distributed
 
-        client = distributed.global_state.client
-    except Exception:  # pragma: no cover - jax internals moved
-        client = None
+    client = distributed.global_state.client
     if client is None:
         multihost_utils.sync_global_devices(name)
         return

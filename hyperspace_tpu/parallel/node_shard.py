@@ -42,11 +42,11 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hyperspace_tpu.data import graphs as graph_data
 from hyperspace_tpu.kernels.segment import build_csr_plan, csr_segment_sum
-from hyperspace_tpu.parallel.mesh import shard_map
 
 _BN = 128   # node-block rows (must match kernels.segment._BN tiling)
 _BK = 512   # edge-chunk size (must match kernels.segment._BK)

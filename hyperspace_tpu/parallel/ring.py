@@ -24,10 +24,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hyperspace_tpu.manifolds import Lorentz, smath
-from hyperspace_tpu.parallel.mesh import pcast_varying, shard_map
 from hyperspace_tpu.nn.attention import minkowski_gram
 
 
@@ -76,9 +76,10 @@ def ring_lorentz_attention(
 
     # constants must be marked varying over the ring axis or the fori_loop
     # carry types mismatch under shard_map's manual-axes checking
-    # (pcast_varying: version-portable spelling, no-op on 0.4.x)
-    m0 = pcast_varying(jnp.full(q.shape[:-1], -jnp.inf, q.dtype), axis_name)
-    l0 = pcast_varying(jnp.zeros(q.shape[:-1], q.dtype), axis_name)
+    m0 = jax.lax.pcast(jnp.full(q.shape[:-1], -jnp.inf, q.dtype), axis_name,
+                       to="varying")
+    l0 = jax.lax.pcast(jnp.zeros(q.shape[:-1], q.dtype), axis_name,
+                       to="varying")
     s0 = jnp.zeros_like(q)
 
     def fold(carry, kvm):

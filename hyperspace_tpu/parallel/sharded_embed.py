@@ -27,9 +27,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from hyperspace_tpu.parallel.mesh import shard_map
 
 
 def table_sharding(mesh: Mesh, axis: str = "model") -> NamedSharding:

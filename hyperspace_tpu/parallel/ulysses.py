@@ -31,11 +31,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from hyperspace_tpu.manifolds import Lorentz
 from hyperspace_tpu.kernels.attention import flash_attention
-from hyperspace_tpu.parallel.mesh import shard_map
 
 
 def ulysses_lorentz_attention(

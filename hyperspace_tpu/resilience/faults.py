@@ -102,8 +102,9 @@ class _Armed:
         self.calls = 0
         self.fired = 0
         # per-spec stream: site+kind fold into the seed so two specs on
-        # one site draw independent (but reproducible) streams
-        self._rng = random.Random((seed, spec.site, spec.kind))
+        # one site draw independent (but reproducible) streams (a str
+        # seed: random.Random hashes it with sha512, stable across runs)
+        self._rng = random.Random(f"{seed}:{spec.site}:{spec.kind}")
 
     def due(self) -> bool:
         i = self.calls

@@ -95,8 +95,10 @@ import threading
 import time
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
+from hyperspace_tpu.kernels import _support as kernel_support
 from hyperspace_tpu.resilience import faults
 from hyperspace_tpu.serve.access import new_request_id
 from hyperspace_tpu.serve.engine import QueryEngine
@@ -1163,6 +1165,11 @@ class RequestBatcher:
             "scan_strategy": self.engine.scan_strategy,
             "scan_mode": self.engine.scan_mode,
             "nprobe": self.engine.nprobe,
+            # what the programs run on and as: the backend jax resolved
+            # and the kernel implementation that follows from it — a
+            # server whose kernels run as their XLA twins says so
+            "backend": jax.default_backend(),
+            "kernel_mode": kernel_support.mode(),
             # live-index identity (serve/delta.py): the segment
             # generation and current delta occupancy — None on a
             # frozen engine, so a stats consumer can tell the worlds

@@ -132,10 +132,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from hyperspace_tpu import precision as precision_mod
-from hyperspace_tpu.parallel.mesh import shard_map
 from hyperspace_tpu.parallel.sharded_embed import local_gather, table_sharding
 from hyperspace_tpu.serve.artifact import (ServingArtifact, fingerprint_of,
                                            manifold_from_spec)

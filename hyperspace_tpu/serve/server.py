@@ -83,9 +83,11 @@ import time
 import urllib.parse
 from typing import Optional
 
+import jax
 import numpy as np
 
 import hyperspace_tpu
+from hyperspace_tpu.kernels import _support as kernel_support
 from hyperspace_tpu.serve.access import new_request_id
 from hyperspace_tpu.serve.batcher import RequestBatcher
 from hyperspace_tpu.serve.collator import DEFAULT_MAX_WAIT_US, Collator
@@ -666,7 +668,9 @@ class HttpFrontDoor:
         if self._registry is not None:
             out = {"ok": ok, "draining": not ok,
                    "uptime_s": round(time.monotonic() - self.t_start, 3),
-                   "version": hyperspace_tpu.__version__}
+                   "version": hyperspace_tpu.__version__,
+                   "backend": jax.default_backend(),
+                   "kernel_mode": kernel_support.mode()}
             if tenant_key is not None:
                 # raises UnknownTenantError → the caller's 404 path
                 out.update(self._registry.resolve(tenant_key).summary())
@@ -688,6 +692,10 @@ class HttpFrontDoor:
             "draining": not ok,
             "uptime_s": round(time.monotonic() - self.t_start, 3),
             "version": hyperspace_tpu.__version__,
+            # the backend jax resolved and the kernel implementation
+            # that follows from it (kernels/_support.mode())
+            "backend": jax.default_backend(),
+            "kernel_mode": kernel_support.mode(),
             "fingerprint": eng.fingerprint,
             "scan_signature": list(eng.scan_signature),
             "precision": eng.precision,

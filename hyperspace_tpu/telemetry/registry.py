@@ -237,23 +237,20 @@ def install_jax_monitoring_hook() -> None:
     global _hook_installed
     if _hook_installed:
         return
-    try:
-        import jax.monitoring as _mon
+    import jax.monitoring as _mon
 
-        def _on_duration(event: str, duration: float, **_kw) -> None:
-            if _BACKEND_COMPILE_SUBSTR in event:
-                reg = default_registry()
-                reg.inc("jax/recompiles")
-                reg.inc("jax/compile_s", float(duration))
+    def _on_duration(event: str, duration: float, **_kw) -> None:
+        if _BACKEND_COMPILE_SUBSTR in event:
+            reg = default_registry()
+            reg.inc("jax/recompiles")
+            reg.inc("jax/compile_s", float(duration))
 
-        def _on_event(event: str, **_kw) -> None:
-            if event == _CACHE_HIT_EVENT:
-                default_registry().inc("jax/compile_cache_hit")
-            elif event == _CACHE_MISS_EVENT:
-                default_registry().inc("jax/compile_cache_miss")
+    def _on_event(event: str, **_kw) -> None:
+        if event == _CACHE_HIT_EVENT:
+            default_registry().inc("jax/compile_cache_hit")
+        elif event == _CACHE_MISS_EVENT:
+            default_registry().inc("jax/compile_cache_miss")
 
-        _mon.register_event_duration_secs_listener(_on_duration)
-        _mon.register_event_listener(_on_event)
-        _hook_installed = True
-    except Exception:  # noqa: BLE001  # hyperlint: disable=swallow-base-exception — jax.monitoring absent/renamed: recompile counting is best-effort by contract (telemetry must never sink a run)
-        pass
+    _mon.register_event_duration_secs_listener(_on_duration)
+    _mon.register_event_listener(_on_event)
+    _hook_installed = True

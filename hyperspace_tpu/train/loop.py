@@ -148,16 +148,20 @@ def _logger(run):
                          tensorboard_dir=run.tensorboard_dir)
 
 
-def run_manifest(run) -> dict:
+def run_manifest(run, data: Optional[dict] = None) -> dict:
     """The run-identity record logged FIRST in every telemetry-enabled
     JSONL (the acceptance anchor for "which run produced this file"):
-    full run config, device/backend identity, process topology, and the
-    package version."""
+    full run config, device/backend identity, the kernel implementation
+    that identity resolved to (``kernels._support.mode()`` — a run whose
+    kernels ran as their XLA twins says so here), process topology, the
+    package version, and — from the runners — ``data``: which dataset
+    ran (``source``: disk or a synthetic stand-in) and at what size."""
     import dataclasses
 
     import jax
 
     import hyperspace_tpu
+    from hyperspace_tpu.kernels import _support
 
     try:
         config = dataclasses.asdict(run)
@@ -170,6 +174,8 @@ def run_manifest(run) -> dict:
         "backend": jax.default_backend(),
         "device_kind": dev.device_kind,
         "device_count": jax.device_count(),
+        "kernel_mode": _support.mode(),
+        "data": data,
         "process_index": jax.process_index(),
         "process_count": jax.process_count(),
         "version": hyperspace_tpu.__version__,
@@ -272,7 +278,7 @@ def _rollback_ctrl(run, ck, project, on_rollback):
 
 
 def run_loop(run, state, stepper, project=None, steps_per_call=1,
-             health_fn=None, on_rollback=None):
+             health_fn=None, on_rollback=None, data=None):
     """Shared step loop: optional checkpoint/resume + JSONL logging.
 
     ``run`` is duck-typed (``cli.train.RunConfig`` shape): ``steps``,
@@ -303,7 +309,8 @@ def run_loop(run, state, stepper, project=None, steps_per_call=1,
     device scalar}`` (``telemetry.health.make_health_fn``), sampled
     every ``run.health_every`` chunks — reading the state between
     dispatches is safe w.r.t. donation (the read is enqueued before the
-    next dispatch consumes the buffers).  Returns ``(final_state,
+    next dispatch consumes the buffers).  ``data`` (which dataset ran,
+    at what size) rides into the run manifest.  Returns ``(final_state,
     final_loss)``; loss is nan when no step ran.
     """
     from hyperspace_tpu.resilience import faults
@@ -386,7 +393,7 @@ def run_loop(run, state, stepper, project=None, steps_per_call=1,
             (ck if ck is not None else contextlib.nullcontext()), \
             _logger(run) as log:
         if reg is not None:
-            log.event("run_manifest", **run_manifest(run))
+            log.event("run_manifest", **run_manifest(run, data))
         if (ck is not None and run.resume
                 and ck.latest_committed_step() is not None):
             state, start = ck.restore(state, project=project)
@@ -423,7 +430,7 @@ def run_loop(run, state, stepper, project=None, steps_per_call=1,
                     if prof:
                         # profiled window: the dispatch time must read
                         # execution, not enqueue (block_until_ready is
-                        # not a host fetch — no value crosses the link)
+                        # not a host fetch — no value crosses to the host)
                         jax.block_until_ready(loss)
                 disp_ms = (time.perf_counter() - t_disp) * 1e3
                 telem.observe("train/dispatch_ms", disp_ms)
@@ -553,6 +560,15 @@ def run_loop(run, state, stepper, project=None, steps_per_call=1,
             summary = reg.snapshot("ctr/", baseline=counter_base)
             if tracer is not None:
                 summary.update(tracer.total_fields())
+            # each local device's allocator reading while the training
+            # state and its data are still alive (live arrays; None
+            # where the backend keeps no statistics, as the CPU's) —
+            # on a mesh it shows whether the data was divided
+            summary["device_memory"] = [
+                {"id": d.id, "bytes_in_use": st.get("bytes_in_use"),
+                 "peak_bytes_in_use": st.get("peak_bytes_in_use")}
+                for d, st in ((d, d.memory_stats() or {})
+                              for d in jax.local_devices())]
             if jax.process_count() > 1:
                 # fleet view (docs/observability.md "Multihost metric
                 # aggregation", exercised by real training since this

@@ -62,28 +62,15 @@ def trace(log_dir: str):
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to ONE flat dict.
-
-    The raw call is backend- and version-shaped: older jax returns a
-    one-element ``[dict]`` per program, some backends raise, some
-    return None.  Every consumer (``compiled_cost``, the bench's
-    ``step_cost``, the profiling scripts) goes through here so the
-    list-shape handling lives in exactly one place; returns {} whenever
-    no analysis is available.
-    """
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001 — backend without cost analysis
-        return {}
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per program
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
+    """``compiled.cost_analysis()`` as a plain dict — {} when the
+    backend reports none (the call returns None there)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def compiled_cost(fn: Callable, *args, **kwargs) -> dict:
     """flops / bytes-accessed of the XLA executable for fn(*args) —
-    the two keys every roofline consumer wants, {} when the backend
-    offers no analysis."""
+    the two keys every roofline consumer wants (absent where the
+    backend offers no analysis)."""
     compiled = jax.jit(fn).lower(*args, **kwargs).compile()
     cost = cost_analysis_dict(compiled)
     return {k: cost[k] for k in ("flops", "bytes accessed") if k in cost}

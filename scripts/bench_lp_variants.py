@@ -9,8 +9,7 @@ Variants:
                same pair count as unplanned, same scatter story as planned
   bf16       — each variant re-run in bfloat16 / with bf16 edge messages
 
-Prints one JSON line per variant.  Run under nohup; compiles go through the
-remote helper (~1-3 min each).
+Prints one JSON line per variant.
 """
 
 from __future__ import annotations

@@ -1,13 +1,13 @@
 #!/usr/bin/env python
 """Cross-round bench trend reports + a regression gate.
 
-Every driver round leaves a ``BENCH_r<N>.json`` wrapper in the repo
-root ({n, cmd, rc, tail, parsed}) and every local ``bench.py`` run
-rewrites ``bench_full.json`` (the bare result record).  Until now the
-only consumer of that trajectory was a human re-reading JSON — which is
-how BENCH_r04 (rc=0, ``parsed: null``) and BENCH_r05 (rc=124) went from
-"lost artifact" to "lesson" only after the fact.  This script is the
-first tool that reads the trajectory:
+Reads ``BENCH_r<N>.json`` round wrappers ({n, cmd, rc, tail, parsed})
+from a directory, and ``bench_full.json`` (the bare result record every
+local ``bench.py`` run rewrites).  The repo keeps no such wrappers any
+more — the ones taken before PR 1 were deleted with PR 21, and
+``PERF_LEDGER.jsonl`` is the record from the benchmark PR on — so the
+script works on whatever directory ``--dir`` names (the tests write
+their own).  What it gives:
 
 - **trend table** (markdown to stdout by default; ``--json`` for the
   machine-readable form; ``--out-json``/``--out-md`` write files):
@@ -19,7 +19,8 @@ first tool that reads the trajectory:
   (default 10%) worse than the best parseable round's — the check a
   perf PR runs before shipping, instead of eyeballing.
 
-Unparseable rounds (r04's null, r05's rc=124) are listed, never fatal:
+Unparseable rounds (rc=0 with ``parsed: null``, rc=124 with nothing)
+are listed, never fatal:
 a lost artifact must not hide the rounds around it.  Sentinel records
 (``metric`` of ``error`` / ``budget_exhausted``) appear in the rounds
 table but are excluded from series and gate — a watchdog's value=0 is

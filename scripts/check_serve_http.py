@@ -139,7 +139,8 @@ def _get(host: str, port: int, path: str):
 def main(out_dir: str | None = None) -> int:
     import numpy as np
 
-    from hyperspace_tpu.serve import QueryEngine, export_artifact
+    from hyperspace_tpu.serve import (QueryEngine, RequestBatcher,
+                                      export_artifact)
 
     table = np.asarray(build_table())
     spec = ("poincare", C)
@@ -151,7 +152,11 @@ def main(out_dir: str | None = None) -> int:
     try:
         export_artifact(out_dir, table, spec, model_config={"c": C},
                         overwrite=True)
-        live = QueryEngine(table, spec)
+        # the reference runs the SAME bucketed program the server runs
+        # (3 ids pad to min_bucket): XLA:CPU vectorizes a [3, D] and an
+        # [8, D] batch differently, so the unpadded engine call differs
+        # from the served answer in the last ulp of some rows
+        live = RequestBatcher(QueryEngine(table, spec))
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         proc = subprocess.Popen(
             [sys.executable, "-m", "hyperspace_tpu.cli.serve",
@@ -190,8 +195,7 @@ def main(out_dir: str | None = None) -> int:
         if status != 200:
             print(f"FIRST QUERY FAILED: {status} {first}")
             return 1
-        li, ld = (np.asarray(a) for a in live.topk_neighbors(
-            np.asarray(ids0, np.int32), K))
+        li, ld = (np.asarray(a) for a in live.topk(ids0, K))
         if not np.array_equal(li, np.asarray(first["neighbors"])):
             print(f"SERVED NEIGHBORS DIFFER from live engine:\n"
                   f"{li}\nvs\n{first['neighbors']}")

@@ -90,7 +90,7 @@ def main():
             raise SystemExit(
                 "--nodes only applies to the synthetic dataset; the "
                 "realistic disk graph has a fixed node count")
-        root = HB.ensure_disk_dataset()
+        root = G.ensure_arxiv_scale_dataset()
         edges, x, labels, ncls, source = G.load_graph("ogbn-arxiv", root)
         edges, x, labels, _ = G.apply_locality_order(edges, x, labels,
                                                      method="community")

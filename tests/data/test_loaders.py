@@ -168,6 +168,21 @@ def test_load_closure_tsv_parses(wordnet_tsv):
     assert (by_name["dog.n.01"], by_name["carnivore.n.01"]) in pairs
 
 
+def test_write_closure_tsv_round_trips(tmp_path):
+    """write → load gives the same closure back (ids are assigned in
+    order of first appearance, so compare through the names)."""
+    from hyperspace_tpu.data import wordnet
+
+    ds = wordnet.synthetic_tree(depth=3, branching=3)
+    path = str(tmp_path / "closure.tsv")
+    wordnet.write_closure_tsv(path, ds)
+    back = wordnet.load_closure_tsv(path)
+    assert back.num_nodes == ds.num_nodes
+    assert back.num_pairs == ds.num_pairs
+    named = {(int(back.names[u]), int(back.names[v])) for u, v in back.pairs}
+    assert named == ds.adjacency_set()
+
+
 def test_load_closure_tsv_closes_edges(wordnet_tsv):
     """already_closed=False must expand parent edges to full ancestry."""
     from hyperspace_tpu.data import wordnet

@@ -107,3 +107,43 @@ def test_sample_neighbors_matches_numpy_oracle():
     for i, u in enumerate(seeds[:-1]):
         nbrs = set(indices[indptr[u]:indptr[u + 1]].tolist())
         assert set(a[i].tolist()) <= nbrs
+
+
+def _copy_sources(monkeypatch, tmp_path):
+    """Point the builder at a private copy of the sources."""
+    import shutil
+
+    srcs = []
+    for s in native._SRCS:
+        srcs.append(shutil.copy(s, tmp_path))
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SRCS", srcs)
+    return srcs
+
+
+def test_library_is_named_by_its_sources(monkeypatch, tmp_path):
+    """A stale library — the old fixed name, or one built from other
+    sources, however fresh its mtime — is never the one loaded: the
+    name carries a hash of the sources and moves when they change."""
+    srcs = _copy_sources(monkeypatch, tmp_path)
+    (tmp_path / "libhsdata.so").write_bytes(b"stale, not even ELF")
+    before = native._lib_path()
+    assert before != str(tmp_path / "libhsdata.so")
+    built = native._build()
+    assert built == before and built.endswith(".so")
+    assert native._build() == built  # second call: found, not rebuilt
+    with open(srcs[0], "a") as f:
+        f.write("\n// edited\n")
+    assert native._lib_path() != before
+
+
+def test_build_failure_is_an_error_not_a_fallback(monkeypatch, tmp_path):
+    """With a compiler installed, sources that do not compile raise —
+    ImportError (which callers turn into the numpy path) is kept for a
+    machine with no compiler at all."""
+    srcs = _copy_sources(monkeypatch, tmp_path)
+    with open(srcs[0], "a") as f:
+        f.write("\nthis is not C++;\n")
+    with pytest.raises(RuntimeError, match="build failed"):
+        native._build()
+    assert not [p for p in tmp_path.iterdir() if p.suffix == ".so"]

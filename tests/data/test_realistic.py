@@ -17,7 +17,7 @@ def _small_graph(seed=0):
 def test_generator_shape_statistics():
     edges, x, labels, k = _small_graph()
     n = x.shape[0]
-    assert edges.ndim == 2 and edges.shape[1] == 2
+    assert edges.shape == (24000, 2)  # exactly the edge count asked for
     assert np.all(edges >= 0) and np.all(edges < n)
     assert np.all(edges[:, 0] != edges[:, 1])  # no self loops
     assert labels.shape == (n,) and labels.max() < k
@@ -48,6 +48,24 @@ def test_ogb_csv_roundtrip(tmp_path):
     e3, x3, l3, k3, source = G.load_graph("ogbn-arxiv", root)
     assert source == "disk"
     np.testing.assert_array_equal(e3, edges)
+
+
+def test_ensure_dataset_writes_once_and_loads_from_disk(tmp_path):
+    """The stand-in for the download: generated into the OGB layout,
+    loaded back as source "disk" at the size asked for, and not
+    regenerated when the files are already there."""
+    import os
+
+    shape = dict(num_nodes=300, num_edges=1500, num_classes=5, feat_dim=8,
+                 sub_size=40)
+    root = G.ensure_arxiv_scale_dataset(str(tmp_path / "ds"), seed=3,
+                                        **shape)
+    edges, x, labels, k, source = G.load_graph("ogbn-arxiv", root)
+    assert source == "disk"
+    assert x.shape == (300, 8) and edges.shape == (1500, 2) and k <= 5
+    stamp = os.path.getmtime(os.path.join(root, "raw", "edge.csv"))
+    assert G.ensure_arxiv_scale_dataset(root, seed=4, **shape) == root
+    assert os.path.getmtime(os.path.join(root, "raw", "edge.csv")) == stamp
 
 
 def test_community_order_is_permutation_and_deterministic():

@@ -1,0 +1,266 @@
+"""The main path's kernels, compiled for the chip without the chip.
+
+The TPU's compiler is installed beside jax and compiles for a device
+that is described, not attached (``jax.experimental.topologies``).  It
+refuses what the Pallas interpreter lets through — a slice off the
+tiling, too much fast memory, a kernel the partitioner cannot place, a
+program that does not fit 16 GB — so these compiles guard every later PR
+at no chip time.  Nothing runs: a compile that passes says nothing
+about results or speed (``chip_smoke.py`` is the run).
+
+All in ONE file, everything in the test's own process, the topology
+described inside a module-scoped fixture: only one process at a time
+may load the TPU's library, and under several test workers only the one
+that is given this file does (on-chip-measurement guide, section 2).
+The persistent compilation cache is off around the compiles — an
+executable compiled for a described device cannot be read back here.
+"""
+
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+# the published ogbn-arxiv shape (configs/hgcn_arxiv_lp.yaml's workload)
+# and the serving shapes chip_smoke.py and the old bench legs use
+ARXIV_FEATS, HIDDEN = 128, (128, 32)
+SLAB_ROWS = 65_536
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _pallas_kernels_and_no_cache():
+    """Kernel dispatch follows the backend, which here is the CPU: name
+    the Pallas path outright for this module; keep the compiles out of
+    the persistent cache; and trace as the program does on the chip —
+    in 32-bit mode, not the suite's 64-bit one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev_mode = os.environ.get("HYPERSPACE_KERNELS")
+    prev_cache = jax.config.jax_enable_compilation_cache
+    os.environ["HYPERSPACE_KERNELS"] = "pallas"
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with jax.enable_x64(False):
+        yield
+    jax.config.update("jax_enable_compilation_cache", prev_cache)
+    compilation_cache.reset_cache()
+    if prev_mode is None:
+        os.environ.pop("HYPERSPACE_KERNELS", None)
+    else:
+        os.environ["HYPERSPACE_KERNELS"] = prev_mode
+
+
+@pytest.fixture(scope="module")
+def arxiv_split():
+    """The LP split of the generated arxiv-shape graph, prepared the way
+    ``cli.train hgcn --yaml configs/hgcn_arxiv_lp.yaml`` prepares it:
+    the kernels below take their plan shapes from the real layout."""
+    from hyperspace_tpu.data import graphs as G
+
+    edges, x, labels, _ = G.community_power_law_graph(seed=0)
+    assert x.shape == (169_343, ARXIV_FEATS) and len(edges) == 1_166_243
+    edges, x, labels, _ = G.apply_locality_order(edges, x, labels,
+                                                 method="bfs", cache=False)
+    return G.split_edges(edges, x.shape[0], x, seed=0,
+                         cluster_min_pair=G.cluster_min_pair_for(False),
+                         cache=False)
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(fn, *args, n_kernels=None):
+    """Lower and compile ``fn`` for the shardings the argument shapes
+    carry; returns (compiled, its text).  ``n_kernels``: how many Mosaic
+    kernels the program must contain at least — a kernel that quietly
+    became its twin compiles too."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    if n_kernels:
+        assert text.count("tpu_custom_call") >= n_kernels, (
+            f"{text.count('tpu_custom_call')} Mosaic kernel(s) in the "
+            f"compiled program, wanted at least {n_kernels}")
+    return compiled, text
+
+
+def _arg(one_chip):
+    return lambda shape, dtype=F32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+
+# --- the graph kernels, on the real plan shapes -------------------------------
+
+
+def test_csr_segment_sum_bf16(one_chip, arxiv_split):
+    from hyperspace_tpu.kernels.segment import csr_segment_sum
+
+    g = arxiv_split.graph
+    n, e = g.num_nodes, g.senders.shape[0]
+    A = _arg(one_chip)
+    plan = _shapes(tuple(jnp.asarray(a) for a in g.csr_plan), one_chip)
+    _compile(lambda v, r, p: csr_segment_sum(v, r, p, n),
+             A((e, ARXIV_FEATS), BF16), A((e,), I32), plan, n_kernels=1)
+
+
+@pytest.fixture(scope="module")
+def cluster_args(one_chip, arxiv_split):
+    cs = arxiv_split.graph.cluster_split
+    assert cs is not None and len(cs.c_recv) > 100_000
+    A = _arg(one_chip)
+    ec = len(cs.c_recv)
+    plan = _shapes(tuple(jnp.asarray(a) for a in cs.c_plan), one_chip)
+    return A, arxiv_split.graph.num_nodes, (A((ec,), I32), A((ec,), I32),
+                                            plan), ec
+
+
+def test_cluster_aggregate(cluster_args):
+    from hyperspace_tpu.kernels.cluster import cluster_aggregate
+
+    A, n, (recv, send, plan), ec = cluster_args
+    _compile(lambda h, w, r, s, p: cluster_aggregate(h, w, r, s, p, n),
+             A((n, ARXIV_FEATS), BF16), A((ec,)), recv, send, plan,
+             n_kernels=1)
+
+
+def test_cluster_att_fwd(cluster_args):
+    from hyperspace_tpu.kernels.cluster import cluster_att_fwd
+
+    A, n, (recv, send, plan), _ = cluster_args
+    _compile(lambda h, a_s, a_r, r, s, p: cluster_att_fwd(
+        h, a_s, a_r, r, s, p, n),
+        A((n, ARXIV_FEATS), BF16), A((n,)), A((n,)), recv, send, plan,
+        n_kernels=1)
+
+
+def test_cluster_att_bwd(cluster_args):
+    from hyperspace_tpu.kernels.cluster import cluster_att_bwd
+
+    A, n, (recv, send, plan), _ = cluster_args
+    _compile(lambda g, h, a_s, a_r, r, s, p: cluster_att_bwd(
+        g, h, a_s, a_r, r, s, p, n),
+        A((n, ARXIV_FEATS + 1)), A((n, ARXIV_FEATS), BF16), A((n,)),
+        A((n,)), recv, send, plan, n_kernels=1)
+
+
+# --- dense kernels at the widths their callers use ----------------------------
+
+
+@pytest.mark.parametrize("manifold, dim", [("poincare", 10),
+                                           ("lorentz", 33)])
+def test_pdist(one_chip, manifold, dim):
+    from hyperspace_tpu.kernels.distmat import pdist
+
+    A = _arg(one_chip)
+    _compile(lambda x, y: pdist(x, y, 1.0, manifold=manifold),
+             A((1024, dim)), A((SLAB_ROWS, dim)), n_kernels=1)
+
+
+@pytest.mark.parametrize("lane", ["f32", "int4"])
+def test_fused_scan_topk(one_chip, lane):
+    from hyperspace_tpu.kernels import scan_topk as ST
+
+    A = _arg(one_chip)
+    spec, dim, b = ("poincare", 1.0), 16, 64
+    if lane == "f32":
+        _compile(lambda s, q, qi: ST.scan_topk(
+            s, q, qi, 0, spec=spec, k=10, n=SLAB_ROWS, exclude_self=True),
+            A((SLAB_ROWS, dim)), A((b, dim)), A((b,), I32), n_kernels=1)
+    else:
+        _compile(lambda s, q, qi, sc: ST.scan_topk(
+            s, q, qi, 0, spec=spec, k=42, n=SLAB_ROWS, exclude_self=True,
+            scale=sc, packed=True),
+            A((SLAB_ROWS, dim // 2), jnp.uint8), A((b, dim)), A((b,), I32),
+            A((SLAB_ROWS,), jnp.float16), n_kernels=1)
+
+
+def test_flash_attention_fwd_bwd(one_chip):
+    from hyperspace_tpu.kernels import flash_attention
+
+    A = _arg(one_chip)
+    q = A((256, 4, 128, 33))
+    loss = lambda q, k, v: flash_attention(q, k, v, 1.0).sum()
+    # forward + the two recomputing backward kernels (dq, dk/dv)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q, n_kernels=3)
+
+
+def test_hyp_linear(one_chip):
+    from hyperspace_tpu.kernels import hyp_linear
+
+    A = _arg(one_chip)
+    _compile(lambda x, m, b: hyp_linear(x, m, b, 1.0),
+             A((169_343, ARXIV_FEATS)), A((ARXIV_FEATS, HIDDEN[0])),
+             A((HIDDEN[0],)), n_kernels=1)
+
+
+def test_hyp_mlr(one_chip):
+    from hyperspace_tpu.kernels import hyp_mlr
+
+    A = _arg(one_chip)
+    _compile(lambda x, p, a: hyp_mlr(x, p, a, 1.0),
+             A((169_343, HIDDEN[1])), A((40, HIDDEN[1])), A((40, HIDDEN[1])),
+             n_kernels=1)
+
+
+# --- the whole step ------------------------------------------------------------
+
+
+def test_train_step_lp_at_arxiv_width(one_chip, arxiv_split):
+    """``hgcn.train_step_lp`` as ``cli.train hgcn --yaml
+    configs/hgcn_arxiv_lp.yaml`` builds it, at the published graph size:
+    it must compile for one chip, keep its Mosaic kernels, and fit the
+    chip's 16 GB with room for what else the process holds."""
+    from hyperspace_tpu.data import graphs as G
+    from hyperspace_tpu.models import hgcn
+    from hyperspace_tpu.precision import parse_dtype
+
+    g = arxiv_split.graph
+    cfg = hgcn.HGCNConfig(
+        feat_dim=ARXIV_FEATS, hidden_dims=HIDDEN, kind="lorentz",
+        agg_dtype=parse_dtype("bfloat16"),
+        decoder_dtype=parse_dtype("bfloat16"))
+    model, opt = hgcn.HGCNLinkPred(cfg), hgcn.make_optimizer(cfg)
+    ga = G.to_device(g)
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(lambda: model.init(
+        {"params": key}, ga, jnp.zeros((2, 2), I32))["params"])
+    state = hgcn.TrainState(params, jax.eval_shape(opt.init, params), key,
+                            jnp.zeros((), I32))
+    args = _shapes((state, ga, jnp.asarray(arxiv_split.train_pos)), one_chip)
+    t0 = time.perf_counter()
+    compiled = hgcn.train_step_lp.lower(model, opt, g.num_nodes,
+                                        *args).compile()
+    took = time.perf_counter() - t0
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert text.count("tpu_custom_call") >= 6, text.count("tpu_custom_call")
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held < 12 * 2**30, f"{held / 2**30:.2f} GiB of a 16 GB chip"
+    # the graph arrays are the arguments' bulk: ~127 MB at this size
+    assert 100e6 < mem.argument_size_in_bytes < 160e6
+    assert took < 300, f"compile took {took:.0f}s"

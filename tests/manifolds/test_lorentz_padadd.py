@@ -1,9 +1,10 @@
 """The Lorentz lifts are pad+add, bitwise-equal to the concat forms.
 
-jax 0.4.37's GSPMD partitioner miscompiles `concatenate` whose operands
-are sharded over a subset of a multi-axis mesh's axes (minimal repro:
-tests/parallel/test_node_sharded.py::test_gspmd_concat_constraint_
-miscompile), so every Lorentz time-coordinate lift/split was rewritten
+An earlier jax's GSPMD partitioner miscompiled `concatenate` whose
+operands are sharded over a subset of a multi-axis mesh's axes (the
+reduced program: tests/parallel/test_node_sharded.py::
+test_gspmd_concat_under_subset_constraint, which passes on the installed
+jax 0.9.0), so every Lorentz time-coordinate lift/split was rewritten
 as pad(+add) (manifolds/lorentz._pad_last / with_time_coordinate).
 These tests pin the rewrite to the old `jnp.concatenate` forms
 BITWISE on a single device — the rewrite is a partitioner dodge, never

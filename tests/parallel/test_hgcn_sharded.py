@@ -50,12 +50,13 @@ def _run_sharded(cfg, split, steps, axes, train_pos):
     pytest.param({"data": 1, "model": 8}, marks=pytest.mark.slow),
     # dp×tp — the fast-suite representative.  Red from PR 3 to PR 8
     # under an (incorrect) "partitioner reduction-order drift"
-    # diagnosis; PR 9 bisected the real op-level cause: jax 0.4.37
-    # GSPMD MISCOMPILES `concatenate` whose operands/consumers are
+    # diagnosis; PR 9 bisected the real op-level cause: the jax of that
+    # time MISCOMPILED `concatenate` whose operands/consumers are
     # sharded over a subset of a multi-axis mesh's axes — values
-    # garbled, not reordered (minimal repro, KEPT xfailed as the bug's
-    # documentation: tests/parallel/
-    # test_node_sharded.py::test_gspmd_concat_constraint_miscompile).
+    # garbled, not reordered (the reduced program is kept as
+    # tests/parallel/test_node_sharded.py::
+    # test_gspmd_concat_under_subset_constraint and passes on the
+    # installed jax 0.9.0).
     # The supervision-pair concat instance was fixed for every mesh by
     # hgcn.split_pair_logits; this legacy pair-sharded path additionally
     # hit the bug through the Lorentz time-coordinate concatenates when

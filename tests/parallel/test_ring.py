@@ -74,7 +74,7 @@ def test_ring_body_direct_shard_map_unmasked(mesh8):
     varying-type carry mismatch)."""
     from functools import partial as fpartial
 
-    from hyperspace_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     from hyperspace_tpu.parallel.ring import ring_lorentz_attention
     from jax.sharding import PartitionSpec as P
 

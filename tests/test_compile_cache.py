@@ -37,6 +37,7 @@ def _clean_activation_state():
 
 def test_resolution_precedence(monkeypatch):
     monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    monkeypatch.delenv(compile_cache.JAX_ENV_VAR, raising=False)
     # default ON, under the repo's .cache
     d = compile_cache.resolve_dir(None)
     assert d is not None and d.endswith(os.path.join(".cache", "jax_compile"))
@@ -52,9 +53,43 @@ def test_resolution_precedence(monkeypatch):
     assert compile_cache.resolve_dir("/flag/dir") == "/flag/dir"
 
 
+def test_jax_env_var_decides_where(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, that is where the cache
+    lives: no flag, own env var or default names another directory
+    (a disagreeing path is an error, not a second cache), and only
+    the off values still apply."""
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    monkeypatch.setenv(compile_cache.JAX_ENV_VAR, "/jax/dir")
+    assert compile_cache.resolve_dir(None) == "/jax/dir"
+    assert compile_cache.resolve_dir("/jax/dir") == "/jax/dir"
+    with pytest.raises(ValueError, match="disagrees"):
+        compile_cache.resolve_dir("/flag/dir")
+    monkeypatch.setenv(compile_cache.ENV_VAR, "/env/dir")
+    with pytest.raises(ValueError, match="disagrees"):
+        compile_cache.resolve_dir(None)
+    monkeypatch.setenv(compile_cache.ENV_VAR, "0")
+    assert compile_cache.resolve_dir(None) is None
+    assert compile_cache.resolve_dir("off") is None
+
+
 def test_off_spellings():
     for v in ("0", "false", "no", "off", "OFF", " 0 "):
         assert compile_cache.resolve_dir(v) is None
+
+
+def test_disable_switches_a_configured_cache_off(tmp_path):
+    """compile_cache_dir=0 must switch the cache off even where jax was
+    already given a directory (JAX_COMPILATION_CACHE_DIR does that
+    before any code runs), and deactivate restores what it found."""
+    import jax
+
+    prev = jax.config.jax_compilation_cache_dir  # the suite's own cache
+    assert prev is not None
+    assert compile_cache.activate("0") is None
+    assert jax.config.jax_compilation_cache_dir is None
+    assert not compile_cache.is_enabled()
+    compile_cache.deactivate()
+    assert jax.config.jax_compilation_cache_dir == prev
 
 
 def test_bad_dir_is_a_clean_error(tmp_path):
@@ -93,14 +128,20 @@ def test_activate_points_jax_and_deactivate_unpoints(tmp_path):
 # --- the subprocess pair (the ISSUE's acceptance shape) ----------------------
 
 
-def _query(art: str, cache_dir: str, extra=()):
-    """One serve-CLI query subprocess → (stdout record, telemetry ctrs)."""
+def _query(art: str, cache_dir: str, how: str = "flag"):
+    """One serve-CLI query subprocess → (stdout record, telemetry ctrs).
+    ``how`` names the cache directory through the CLI flag or through
+    ``JAX_COMPILATION_CACHE_DIR`` (no flag at all)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop(compile_cache.ENV_VAR, None)
+    env.pop(compile_cache.JAX_ENV_VAR, None)
+    extra = [f"compile_cache_dir={cache_dir}"]
+    if how == "env":
+        env[compile_cache.JAX_ENV_VAR] = cache_dir
+        extra = []
     res = subprocess.run(
         [sys.executable, "-m", "hyperspace_tpu.cli.serve", "query",
-         f"artifact={art}", "ids=0,1,2", "k=3", "telemetry=1",
-         f"compile_cache_dir={cache_dir}", *extra],
+         f"artifact={art}", "ids=0,1,2", "k=3", "telemetry=1", *extra],
         capture_output=True, text=True, cwd=ROOT, env=env, timeout=240)
     assert res.returncode == 0, res.stderr[-2000:]
     out = json.loads(res.stdout.strip().splitlines()[-1])
@@ -127,16 +168,19 @@ def artifact(tmp_path_factory):
     return out
 
 
-def test_subprocess_pair_hits_and_disabled_bitwise(tmp_path, artifact):
+@pytest.mark.parametrize("how", ["flag", "env"])
+def test_subprocess_pair_hits_and_disabled_bitwise(tmp_path, artifact, how):
     cache = str(tmp_path / "cc")
-    out1, t1 = _query(artifact, cache)
+    out1, t1 = _query(artifact, cache, how)
+    # the entries landed in the named directory
+    assert os.listdir(cache)
     # run #1: a cold cache has nothing to hit, and every compile missed
     # into it (entries written)
     assert t1.get("ctr/jax/compile_cache_hit", 0) == 0
     assert t1.get("ctr/jax/compile_cache_miss", 0) > 0
     assert t1.get("ctr/jax/recompiles", 0) > 0
 
-    out2, t2 = _query(artifact, cache)
+    out2, t2 = _query(artifact, cache, how)
     # run #2, same dir: executables deserialize — hits recorded, fewer
     # misses, and LOWER compile counters (this jax times the hit's
     # deserialization under the same backend_compile event, so

@@ -133,9 +133,11 @@ def test_compiled_cost_reports_flops():
         assert cost.get("flops", 0) > 0
 
 
-def test_cost_analysis_dict_normalizes_every_backend_shape():
-    # the ONE list-shape handler every consumer (bench step_cost, the
-    # profiling scripts) now routes through
+def test_cost_analysis_dict_shapes():
+    """A dict comes back as a plain dict, a backend without analysis
+    (None) as {}, and a backend error is not swallowed."""
+    import pytest
+
     from hyperspace_tpu.train.profiling import cost_analysis_dict
 
     class Fake:
@@ -148,10 +150,9 @@ def test_cost_analysis_dict_normalizes_every_backend_shape():
             return self._ret
 
     assert cost_analysis_dict(Fake({"flops": 2.0})) == {"flops": 2.0}
-    assert cost_analysis_dict(Fake([{"flops": 3.0}])) == {"flops": 3.0}
-    assert cost_analysis_dict(Fake([])) == {}
     assert cost_analysis_dict(Fake(None)) == {}
-    assert cost_analysis_dict(Fake(raise_=True)) == {}
+    with pytest.raises(RuntimeError):
+        cost_analysis_dict(Fake(raise_=True))
 
 
 def test_read_jsonl_tolerates_truncated_final_line(tmp_path):
