@@ -215,6 +215,7 @@ def cluster_aggregate(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S.round_up(n_pad, bn), fp),
                                        jnp.float32),
+        name="cluster_aggregate",
         interpret=S.interpret_flag(m),
     )(*tuple(plan)[:4], r2d, s2d, w2d, h_p)
     return out[:num_nodes, :f].astype(h.dtype)
@@ -404,6 +405,7 @@ def cluster_att_fwd(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S.round_up(n_pad, bn), fp_ext),
                                        jnp.float32),
+        name="cluster_att_fwd",
         interpret=S.interpret_flag(m),
     )(*tuple(plan)[:4], r2d, s2d, h_p, a_s2, a_r2)
     return out[:num_nodes, : f + 1]
@@ -578,6 +580,7 @@ def cluster_att_bwd(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S.round_up(n_pad, bn), fp_out),
                                        jnp.float32),
+        name="cluster_att_bwd",
         interpret=S.interpret_flag(m),
     )(*tuple(plan)[:4], r2d, s2d, g_p, g_p, h_p, h_p,
       a_s_rb, a_s_sb, a_r_rb, a_r_sb)

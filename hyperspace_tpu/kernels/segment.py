@@ -133,6 +133,7 @@ def _pallas_csr(vals, recv2d, plan_arrays, num_nodes, bn, bk, interpret):
         _body(bn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_pad, dp), jnp.float32),
+        name="csr_segment_sum",
         interpret=interpret,
     )(*plan_arrays, recv2d, vals)
     return out
@@ -258,6 +259,7 @@ def csr_segment_reduce_1d(
         _body_1d(bn, op),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_pad, 128), jnp.float32),
+        name="csr_segment_reduce_1d",
         interpret=S.interpret_flag(m),
     )(*tuple(plan), recv2d, v2d)
     red = jnp.sum(out, axis=-1) if op == "sum" else jnp.max(out, axis=-1)
@@ -396,6 +398,7 @@ def csr_att_bwd_edges(
             jax.ShapeDtypeStruct((e_pad // bk, bk // 128, 128), jnp.float32),
             jax.ShapeDtypeStruct((n_pad, 128), jnp.float32),
         ],
+        name="csr_att_bwd_edges",
         interpret=S.interpret_flag(m),
     )(pb, pc, pf, fc, recv2d, dn_p, h1_p, w2d, lm2d)
     return (dpre2d.reshape(e_pad)[:e],

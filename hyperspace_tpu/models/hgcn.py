@@ -158,7 +158,8 @@ class HGCNLinkPred(nn.Module):
         ddt = self.cfg.resolved_decoder_dtype()
         if ddt is not None and not deterministic:
             z = z.astype(ddt)  # train only; eval full-prec
-        sq = m.sqdist(z[pairs[:, 0]], z[pairs[:, 1]])
+        with jax.named_scope("pair_dist"):
+            sq = m.sqdist(z[pairs[:, 0]], z[pairs[:, 1]])
         return FermiDiracDecoder(name="decoder")(sq.astype(self.cfg.dtype))
 
     @nn.compact
@@ -176,8 +177,9 @@ class HGCNLinkPred(nn.Module):
         if ddt is not None and not deterministic:
             z = z.astype(ddt)  # train only; eval full-prec
         dec = FermiDiracDecoder(name="decoder")
-        sq_p = m.sqdist(z[pos[:, 0]], z[pos[:, 1]])
-        sq_n = m.sqdist(z[neg[:, 0]], z[neg[:, 1]])
+        with jax.named_scope("pair_dist"):
+            sq_p = m.sqdist(z[pos[:, 0]], z[pos[:, 1]])
+            sq_n = m.sqdist(z[neg[:, 0]], z[neg[:, 1]])
         return (dec(sq_p.astype(self.cfg.dtype)),
                 dec(sq_n.astype(self.cfg.dtype)))
 
@@ -200,12 +202,13 @@ class HGCNLinkPred(nn.Module):
         ddt = self.cfg.resolved_decoder_dtype()
         if ddt is not None:
             z = z.astype(ddt)
-        sq_pos = pair_sqdist_planned(
-            z, m.c, pos.u, pos.v, *pos.u_plan, pos.v_perm, pos.v_sorted,
-            *pos.v_plan, self.cfg.kind)
         npb, npc, npf = neg_plan
-        sq_neg = pair_sqdist_semi_planned(z, m.c, neg_u, neg_v,
-                                          npb, npc, npf, self.cfg.kind)
+        with jax.named_scope("pair_dist"):
+            sq_pos = pair_sqdist_planned(
+                z, m.c, pos.u, pos.v, *pos.u_plan, pos.v_perm, pos.v_sorted,
+                *pos.v_plan, self.cfg.kind)
+            sq_neg = pair_sqdist_semi_planned(z, m.c, neg_u, neg_v,
+                                              npb, npc, npf, self.cfg.kind)
         dec = FermiDiracDecoder(name="decoder")
         return (dec(sq_pos.astype(self.cfg.dtype)),
                 dec(sq_neg.astype(self.cfg.dtype)))
@@ -234,14 +237,15 @@ class HGCNLinkPred(nn.Module):
         if ddt is not None:
             z = z.astype(ddt)  # train-only method
         pb, pc, pf = g.plan if g.plan is not None else (None, None, None)
-        sq_pos = graph_edge_sqdist(z, m.c, g.senders, g.receivers, g.rev_perm,
-                                   pb, pc, pf, self.cfg.kind)
+        npb, npc, npf = neg_plan
+        with jax.named_scope("pair_dist"):
+            sq_pos = graph_edge_sqdist(z, m.c, g.senders, g.receivers,
+                                       g.rev_perm, pb, pc, pf, self.cfg.kind)
+            sq_neg = pair_sqdist_semi_planned(z, m.c, neg_u, neg_v,
+                                              npb, npc, npf, self.cfg.kind)
         sq_pos = sq_pos.astype(self.cfg.dtype)
         # self-loops are degenerate positives (d = 0); weight them out
         w_pos = (g.edge_mask & (g.senders != g.receivers)).astype(sq_pos.dtype)
-        npb, npc, npf = neg_plan
-        sq_neg = pair_sqdist_semi_planned(z, m.c, neg_u, neg_v,
-                                          npb, npc, npf, self.cfg.kind)
         dec = FermiDiracDecoder(name="decoder")
         return dec(sq_pos), w_pos, dec(sq_neg.astype(self.cfg.dtype))
 
@@ -301,15 +305,40 @@ def init_lp(cfg: HGCNConfig, g: graph_data.Graph, seed: int = 0):
     return model, opt, state
 
 
+def _bce_pos_neg(pos_logit, neg_logit, w_pos=None):
+    """Mean BCE of positives (label 1, optionally weighted) and negatives
+    (label 0) from sums alone — no concatenate of the two batches."""
+    with jax.named_scope("loss"):
+        bce_pos = optax.sigmoid_binary_cross_entropy(
+            pos_logit, jnp.ones_like(pos_logit))
+        bce_neg = optax.sigmoid_binary_cross_entropy(
+            neg_logit, jnp.zeros_like(neg_logit))
+        if w_pos is None:
+            n_pos = pos_logit.shape[0]
+        else:
+            bce_pos, n_pos = bce_pos * w_pos, jnp.sum(w_pos)
+        return ((jnp.sum(bce_pos) + jnp.sum(bce_neg))
+                / (n_pos + neg_logit.shape[0]))
+
+
+def _apply_grads(opt, state: TrainState, grads, key) -> TrainState:
+    """Clip + AdamW + apply: the LP steps' shared tail."""
+    with jax.named_scope("optimizer"):
+        updates, opt_state = opt.update(grads, state.opt_state, state.params)
+        params = optax.apply_updates(state.params, updates)
+    return TrainState(params, opt_state, key, state.step + 1)
+
+
 def _lp_step_impl(model, opt, num_nodes, state, g, train_pos, constrain=None,
                   split_pairs=False):
     """Shared LP step body: sample negatives on device, BCE on pos+neg
     logits.  ``constrain`` (optional) pins the supervision batch's sharding
     (GSPMD hint) — the only difference between the single-device and the
     mesh-sharded step, so both jit wrappers compile this same program."""
-    key, k_neg, k_drop = jax.random.split(state.key, 3)
     n_neg = train_pos.shape[0] * model.cfg.neg_per_pos
-    neg = jax.random.randint(k_neg, (n_neg, 2), 0, num_nodes)
+    with jax.named_scope("negatives"):
+        key, k_neg, k_drop = jax.random.split(state.key, 3)
+        neg = jax.random.randint(k_neg, (n_neg, 2), 0, num_nodes)
 
     def loss_fn(params):
         if constrain is not None and split_pairs:
@@ -334,12 +363,7 @@ def _lp_step_impl(model, opt, num_nodes, state, g, train_pos, constrain=None,
                 deterministic=False, rngs={"dropout": k_drop},
                 method=HGCNLinkPred.split_pair_logits,
             )
-            bce_pos = optax.sigmoid_binary_cross_entropy(
-                pos_logit, jnp.ones_like(pos_logit))
-            bce_neg = optax.sigmoid_binary_cross_entropy(
-                neg_logit, jnp.zeros_like(neg_logit))
-            return ((jnp.sum(bce_pos) + jnp.sum(bce_neg))
-                    / (pos_logit.shape[0] + neg_logit.shape[0]))
+            return _bce_pos_neg(pos_logit, neg_logit)
         tp, ng = train_pos, neg
         if constrain is not None:
             tp, ng = constrain(tp), constrain(ng)
@@ -348,15 +372,15 @@ def _lp_step_impl(model, opt, num_nodes, state, g, train_pos, constrain=None,
             {"params": params}, g, pairs,
             deterministic=False, rngs={"dropout": k_drop},
         )
-        labels = jnp.concatenate(
-            [jnp.ones(train_pos.shape[0]), jnp.zeros(n_neg)]
-        ).astype(logits.dtype)
-        return jnp.mean(optax.sigmoid_binary_cross_entropy(logits, labels))
+        with jax.named_scope("loss"):
+            labels = jnp.concatenate(
+                [jnp.ones(train_pos.shape[0]), jnp.zeros(n_neg)]
+            ).astype(logits.dtype)
+            return jnp.mean(
+                optax.sigmoid_binary_cross_entropy(logits, labels))
 
     loss, grads = jax.value_and_grad(loss_fn)(state.params)
-    updates, opt_state = opt.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
-    return TrainState(params, opt_state, key, state.step + 1), loss
+    return _apply_grads(opt, state, grads, key), loss
 
 
 @partial(jax.jit, static_argnames=("model", "opt", "num_nodes"), donate_argnames=("state",))
@@ -425,8 +449,9 @@ def train_step_lp_pairs(
         f"neg_u has {neg_u.shape[0]} rows; cfg.neg_per_pos="
         f"{model.cfg.neg_per_pos} needs {pos.u.shape[0]} * neg_per_pos "
         "(size the static negatives with make_static_negatives accordingly)")
-    key, k_neg, k_drop = jax.random.split(state.key, 3)
-    neg_v = jax.random.randint(k_neg, neg_u.shape, 0, num_nodes)
+    with jax.named_scope("negatives"):
+        key, k_neg, k_drop = jax.random.split(state.key, 3)
+        neg_v = jax.random.randint(k_neg, neg_u.shape, 0, num_nodes)
 
     def loss_fn(params):
         pos_logit, neg_logit = model.apply(
@@ -434,17 +459,10 @@ def train_step_lp_pairs(
             deterministic=False, rngs={"dropout": k_drop},
             method=HGCNLinkPred.pair_logits,
         )
-        bce_pos = optax.sigmoid_binary_cross_entropy(
-            pos_logit, jnp.ones_like(pos_logit))
-        bce_neg = optax.sigmoid_binary_cross_entropy(
-            neg_logit, jnp.zeros_like(neg_logit))
-        return ((jnp.sum(bce_pos) + jnp.sum(bce_neg))
-                / (pos_logit.shape[0] + neg_logit.shape[0]))
+        return _bce_pos_neg(pos_logit, neg_logit)
 
     loss, grads = jax.value_and_grad(loss_fn)(state.params)
-    updates, opt_state = opt.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
-    return TrainState(params, opt_state, key, state.step + 1), loss
+    return _apply_grads(opt, state, grads, key), loss
 
 
 def make_static_negatives(num_nodes: int, n_neg: int, seed: int = 0):
@@ -472,8 +490,9 @@ def train_step_lp_planned(
 ):
     """One LP step with every decoder gradient scatter planned: positives
     are the graph's own edge list, negatives corrupt only the v side."""
-    key, k_neg, k_drop = jax.random.split(state.key, 3)
-    neg_v = jax.random.randint(k_neg, neg_u.shape, 0, num_nodes)
+    with jax.named_scope("negatives"):
+        key, k_neg, k_drop = jax.random.split(state.key, 3)
+        neg_v = jax.random.randint(k_neg, neg_u.shape, 0, num_nodes)
 
     def loss_fn(params):
         pos_logit, w_pos, neg_logit = model.apply(
@@ -481,17 +500,10 @@ def train_step_lp_planned(
             deterministic=False, rngs={"dropout": k_drop},
             method=HGCNLinkPred.edge_logits,
         )
-        bce_pos = optax.sigmoid_binary_cross_entropy(
-            pos_logit, jnp.ones_like(pos_logit))
-        bce_neg = optax.sigmoid_binary_cross_entropy(
-            neg_logit, jnp.zeros_like(neg_logit))
-        denom = jnp.sum(w_pos) + neg_logit.shape[0]
-        return (jnp.sum(bce_pos * w_pos) + jnp.sum(bce_neg)) / denom
+        return _bce_pos_neg(pos_logit, neg_logit, w_pos)
 
     loss, grads = jax.value_and_grad(loss_fn)(state.params)
-    updates, opt_state = opt.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
-    return TrainState(params, opt_state, key, state.step + 1), loss
+    return _apply_grads(opt, state, grads, key), loss
 
 
 def _concat_hazard(mesh) -> bool:
@@ -719,9 +731,7 @@ def _nc_step_impl(model, opt, state, g, labels, train_mask, constrain=None):
         return jnp.sum(ce * w) / jnp.maximum(jnp.sum(w), 1.0)
 
     loss, grads = jax.value_and_grad(loss_fn)(state.params)
-    updates, opt_state = opt.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
-    return TrainState(params, opt_state, key, state.step + 1), loss
+    return _apply_grads(opt, state, grads, key), loss
 
 
 @partial(jax.jit, static_argnames=("model", "opt"), donate_argnames=("state",))

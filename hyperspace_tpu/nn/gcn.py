@@ -176,128 +176,119 @@ class HGCConv(nn.Module):
             c_out = self.c_out
         m_out = make_manifold(self.kind, c_out)
 
-        n = x.shape[0]
-        v = tangent0_coords(m_in, x)  # [N, d_in]
-        kernel = self.param("kernel", self.kernel_init, (v.shape[-1], self.features), v.dtype)
-        h = v @ kernel  # the MXU matmul
-        if self.use_bias:
-            h = h + self.param("bias", nn.initializers.zeros, (self.features,), v.dtype)
-        if self.dropout_rate > 0.0:
-            h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
+        # three scopes, the layer's parts as a device profile shows them
+        # (docs/observability.md): they are HLO metadata and cost nothing
+        with jax.named_scope("linear"):
+            v = tangent0_coords(m_in, x)  # [N, d_in]
+            kernel = self.param("kernel", self.kernel_init, (v.shape[-1], self.features), v.dtype)
+            h = v @ kernel  # the MXU matmul
+            if self.use_bias:
+                h = h + self.param("bias", nn.initializers.zeros, (self.features,), v.dtype)
+            if self.dropout_rate > 0.0:
+                h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
+            alpha = None
+            if self.use_att:
+                # GAT-style additive attention in the tangent chart.
+                a_s = self.param("att_src", self.kernel_init, (self.features, 1), h.dtype)
+                a_r = self.param("att_dst", self.kernel_init, (self.features, 1), h.dtype)
+                alpha = (h @ a_s)[:, 0], (h @ a_r)[:, 0]
+        # jax carries the scope of a custom_vjp's call into its backward
+        # rule, so the rules of nn/scatter.py open none of their own
+        with jax.named_scope("aggregate"):
+            agg = _aggregate(h, alpha, g, self.agg_dtype).astype(h.dtype)
+        with jax.named_scope("act"):
+            out = from_tangent0_coords(m_out, self.activation(agg))
+        return out, m_out
 
-        # node-sharded graphs (parallel/node_shard.py) carry their own
-        # per-shard edge lists + precomputed mean weights: aggregation is
-        # a shard_map (all-gather + local block-CSR) and the rest of the
-        # layer is ordinary row-wise math that GSPMD keeps node-sharded
-        if hasattr(g, "w_fwd"):
-            from hyperspace_tpu.parallel.node_shard import (
-                node_sharded_aggregate,
-                node_sharded_att_aggregate,
+
+def _aggregate(h: jax.Array, alpha, g, agg_dtype) -> jax.Array:
+    """``[N, F]`` neighbourhood aggregate of the tangent features ``h``
+    over ``g``: attention-weighted when ``alpha`` = (sender scores,
+    receiver scores) is given, else the degree mean."""
+    n = h.shape[0]
+    # node-sharded graphs (parallel/node_shard.py) carry their own
+    # per-shard edge lists + precomputed mean weights: aggregation is
+    # a shard_map (all-gather + local block-CSR) and the rest of the
+    # layer is ordinary row-wise math that GSPMD keeps node-sharded
+    if hasattr(g, "w_fwd"):
+        from hyperspace_tpu.parallel.node_shard import (
+            node_sharded_aggregate,
+            node_sharded_att_aggregate,
+        )
+
+        if alpha is not None:
+            # receiver partitioning keeps the segment softmax
+            # shard-local; autodiff collectives carry the backward
+            return node_sharded_att_aggregate(h, *alpha, g, agg_dtype)
+        return node_sharded_aggregate(h, g, agg_dtype)
+
+    senders, receivers, edge_mask = g.senders, g.receivers, g.edge_mask
+    sorted_fast = g.rev_perm is not None
+    h_in = h if agg_dtype is None else h.astype(agg_dtype)
+    w_static = False
+    if alpha is not None:
+        alpha_s, alpha_r = alpha
+        if sorted_fast and g.plan is not None:
+            # fused planned path (nn/scatter.att_partial_planned):
+            # the sender pick rides the message gather as an extra
+            # feature column (ONE random [E] gather/layer), bounded-
+            # logit softmax needs no max pass, num/den are one CSR
+            # pass each, and the backward re-uses saved residual rows
+            # instead of re-gathering.  (Row gathers cost ~28 ms per
+            # 2.4 M edges on v5e regardless of width — pass count is
+            # the whole game.)  On well-clustered graphs the
+            # clustered edges drop out of the [E] stream entirely:
+            # their logits, weights, aggregation, and whole backward
+            # run in-tile from VMEM-resident blocks
+            # (nn/scatter.cluster_att_partial), and only the
+            # straggler subset pays the planned passes.  The two
+            # [N, F+1] (num | den) partials add and divide ONCE.
+            from hyperspace_tpu.nn.scatter import (
+                att_combine,
+                att_partial_planned,
+                cluster_att_partial,
             )
 
-            if self.use_att:
-                # receiver partitioning keeps the segment softmax
-                # shard-local; autodiff collectives carry the backward
-                a_s = self.param("att_src", self.kernel_init,
-                                 (self.features, 1), h.dtype)
-                a_r = self.param("att_dst", self.kernel_init,
-                                 (self.features, 1), h.dtype)
-                agg = node_sharded_att_aggregate(
-                    h, (h @ a_s)[:, 0], (h @ a_r)[:, 0], g, self.agg_dtype)
+            cl = g.cluster
+            if cl is not None and cl.att_ok:
+                nd = cluster_att_partial(h_in, alpha_s, alpha_r, cl, n, 0.2)
+                nd = nd + att_partial_planned(
+                    h, alpha_s, alpha_r, cl.s_send, cl.s_recv,
+                    cl.s_rev_local, cl.s_mask, cl.s_plan, n, agg_dtype, 0.2)
             else:
-                agg = node_sharded_aggregate(h, g, self.agg_dtype)
-            agg = agg.astype(h.dtype)
-            out = from_tangent0_coords(m_out, self.activation(agg))
-            return out, m_out
+                nd = att_partial_planned(
+                    h, alpha_s, alpha_r, senders, receivers,
+                    g.rev_perm, edge_mask, g.plan, n, agg_dtype, 0.2)
+            return att_combine(nd, h.dtype)
+        logits = bounded_att_logits(alpha_s[senders] + alpha_r[receivers])
+        w = segment_softmax(logits, receivers, n, mask=edge_mask,
+                            indices_are_sorted=sorted_fast)
+    elif g.cluster is not None:
+        # cluster-pair SpMM kernel (kernels/cluster.py): block-dense
+        # edges aggregate as two one-hot MXU matmuls over VMEM tiles
+        # (no [E, F] message round-trip); stragglers keep the CSR
+        # path; the symmetric backward runs the same two-path program
+        from hyperspace_tpu.nn.scatter import cluster_sym_aggregate
 
-        senders, receivers, edge_mask = g.senders, g.receivers, g.edge_mask
-
-        sorted_fast = g.rev_perm is not None
-        w_static = False
-        if self.use_att:
-            # GAT-style additive attention in the tangent chart.
-            a_s = self.param("att_src", self.kernel_init, (self.features, 1), h.dtype)
-            a_r = self.param("att_dst", self.kernel_init, (self.features, 1), h.dtype)
-            alpha_s = (h @ a_s)[:, 0]
-            alpha_r = (h @ a_r)[:, 0]
-            if sorted_fast and g.plan is not None:
-                # fused planned path (nn/scatter.att_partial_planned):
-                # the sender pick rides the message gather as an extra
-                # feature column (ONE random [E] gather/layer), bounded-
-                # logit softmax needs no max pass, num/den are one CSR
-                # pass each, and the backward re-uses saved residual rows
-                # instead of re-gathering.  (Row gathers cost ~28 ms per
-                # 2.4 M edges on v5e regardless of width — pass count is
-                # the whole game.)  On well-clustered graphs the
-                # clustered edges drop out of the [E] stream entirely:
-                # their logits, weights, aggregation, and whole backward
-                # run in-tile from VMEM-resident blocks
-                # (nn/scatter.cluster_att_partial), and only the
-                # straggler subset pays the planned passes.  The two
-                # [N, F+1] (num | den) partials add and divide ONCE.
-                from hyperspace_tpu.nn.scatter import (
-                    att_combine,
-                    att_partial_planned,
-                    cluster_att_partial,
-                )
-
-                cl = g.cluster
-                if cl is not None and cl.att_ok:
-                    h_in = (h if self.agg_dtype is None
-                            else h.astype(self.agg_dtype))
-                    nd = cluster_att_partial(h_in, alpha_s, alpha_r, cl,
-                                             n, 0.2)
-                    nd = nd + att_partial_planned(
-                        h, alpha_s, alpha_r, cl.s_send, cl.s_recv,
-                        cl.s_rev_local, cl.s_mask, cl.s_plan, n,
-                        self.agg_dtype, 0.2)
-                else:
-                    nd = att_partial_planned(
-                        h, alpha_s, alpha_r, senders, receivers,
-                        g.rev_perm, edge_mask, g.plan, n, self.agg_dtype,
-                        0.2)
-                agg = att_combine(nd, h.dtype)
-                out = from_tangent0_coords(m_out, self.activation(agg))
-                return out, m_out
-            logits = bounded_att_logits(
-                alpha_s[senders] + alpha_r[receivers])
-            w = segment_softmax(logits, receivers, n, mask=edge_mask,
-                                indices_are_sorted=sorted_fast)
-        elif g.cluster is not None:
-            # cluster-pair SpMM kernel (kernels/cluster.py): block-dense
-            # edges aggregate as two one-hot MXU matmuls over VMEM tiles
-            # (no [E, F] message round-trip); stragglers keep the CSR
-            # path; the symmetric backward runs the same two-path program
-            from hyperspace_tpu.nn.scatter import cluster_sym_aggregate
-
-            h_in = h if self.agg_dtype is None else h.astype(self.agg_dtype)
-            agg = cluster_sym_aggregate(h_in, g.cluster, n).astype(h.dtype)
-            out = from_tangent0_coords(m_out, self.activation(agg))
-            return out, m_out
+        return cluster_sym_aggregate(h_in, g.cluster, n)
+    else:
+        # mean aggregation: 1/deg; degree is static per graph, so prefer
+        # the precomputed g.deg over a per-step segment count
+        ones = edge_mask.astype(h.dtype)
+        if g.deg is not None:
+            deg = g.deg.astype(h.dtype)
         else:
-            # mean aggregation: 1/deg; degree is static per graph, so prefer
-            # the precomputed g.deg over a per-step segment count
-            ones = edge_mask.astype(h.dtype)
-            if g.deg is not None:
-                deg = g.deg.astype(h.dtype)
-            else:
-                deg = jax.ops.segment_sum(ones, receivers, n,
-                                          indices_are_sorted=sorted_fast)
-            w = ones / jnp.maximum(deg[receivers], 1.0)
-            w_static = True
-        h_in = h if self.agg_dtype is None else h.astype(self.agg_dtype)
-        w_in = w if self.agg_dtype is None else w.astype(self.agg_dtype)
-        if sorted_fast:
-            # receiver-sorted scatter in forward AND backward (nn/scatter.py)
-            pb, pc, pf = g.plan if g.plan is not None else (None, None, None)
-            agg = sym_segment_aggregate(h_in, w_in, senders, receivers,
-                                        g.rev_perm, pb, pc, pf, n, not w_static)
-        else:
-            msgs = w_in[:, None] * h_in[senders]
-            agg = jax.ops.segment_sum(
-                msgs.astype(jnp.promote_types(msgs.dtype, jnp.float32)),
-                receivers, n)
-        agg = agg.astype(h.dtype)
-
-        out = from_tangent0_coords(m_out, self.activation(agg))
-        return out, m_out
+            deg = jax.ops.segment_sum(ones, receivers, n,
+                                      indices_are_sorted=sorted_fast)
+        w = ones / jnp.maximum(deg[receivers], 1.0)
+        w_static = True
+    w_in = w if agg_dtype is None else w.astype(agg_dtype)
+    if sorted_fast:
+        # receiver-sorted scatter in forward AND backward (nn/scatter.py)
+        pb, pc, pf = g.plan if g.plan is not None else (None, None, None)
+        return sym_segment_aggregate(h_in, w_in, senders, receivers,
+                                     g.rev_perm, pb, pc, pf, n, not w_static)
+    msgs = w_in[:, None] * h_in[senders]
+    return jax.ops.segment_sum(
+        msgs.astype(jnp.promote_types(msgs.dtype, jnp.float32)),
+        receivers, n)

@@ -17,12 +17,17 @@ this module answers the host-side one with nested wall-clock spans that
 - optionally retain every event for a Chrome/Perfetto ``trace_events``
   dump (:meth:`Tracer.dump_chrome_trace`): load the JSON in
   https://ui.perfetto.dev to see the nested host timeline next to the
-  numbers the JSONL already carries.
+  numbers the JSONL already carries;
+- show in any ``jax.profiler`` session taken while the tracer is on:
+  an enabled span also enters a ``jax.profiler.TraceAnnotation`` (a
+  ``StepTraceAnnotation`` when it carries ``step_num``), so the
+  profile's host plane holds the program's spans on the device's own
+  clock, beside the device operations.
 
-Span names in use are cataloged in docs/observability.md (``prep``,
-``prefetch_wait``, ``dispatch``, ``metrics_flush``, ``ckpt_save``,
-``eval``); the catalog lint covers counters only, but keep the doc in
-step when adding span call sites.
+Span names in use are cataloged in docs/observability.md
+(``train_step``, ``prep``, ``prefetch_wait``, ``dispatch``,
+``metrics_flush``, ``ckpt_save``, ``eval``); the catalog lint covers
+counters only, but keep the doc in step when adding span call sites.
 """
 
 from __future__ import annotations
@@ -46,6 +51,23 @@ _NULL = contextlib.nullcontext()
 # what happened just before the failure (drop count kept for honesty).
 _MAX_EVENTS = 1_000_000
 
+# a span that carries ``step_num`` is a step marker: the profiler gets
+# ``StepTraceAnnotation(<name less this suffix>, step_num=...)`` — "train"
+# for ``train_step``, the name its step views group by
+_STEP_SUFFIX = "_step"
+
+
+def _annotation(name: str, args, step_num):
+    """The profiler's view of one enabled span (imported on first use:
+    the disabled path never touches jax).  Outside a profiler session
+    entering it is a flag check."""
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+    if step_num is not None:
+        return StepTraceAnnotation(name.removesuffix(_STEP_SUFFIX),
+                                   step_num=step_num)
+    return TraceAnnotation(name, **args) if args else TraceAnnotation(name)
+
 
 class _Span:
     """The enabled-path context manager (one fresh object per span —
@@ -56,22 +78,27 @@ class _Span:
     so Perfetto can correlate spans with load.  The dict is held by
     REFERENCE and read at ``__exit__``: a call site may create it with
     what it knows up front and fill in the rest (e.g. cache hits) before
-    the span closes."""
+    the span closes.  The profiler's annotation takes what the dict
+    holds when the span is made."""
 
-    __slots__ = ("_tracer", "_name", "_t0", "_args")
+    __slots__ = ("_tracer", "_name", "_t0", "_args", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, args=None):
+    def __init__(self, tracer: "Tracer", name: str, args=None,
+                 step_num=None):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._ann = _annotation(name, args, step_num)
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tracer._record(self._name, self._t0, time.perf_counter(),
-                             self._args)
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._tracer._record(self._name, self._t0, t1, self._args)
         return False
 
 
@@ -94,12 +121,14 @@ class Tracer:
 
     # --- recording ------------------------------------------------------------
 
-    def span(self, name: str, args: Optional[dict] = None):
+    def span(self, name: str, args: Optional[dict] = None,
+             step_num: Optional[int] = None):
         """Context manager timing one ``name`` span; nests freely.
-        ``args`` (optional metadata dict) rides into the Chrome dump."""
+        ``args`` (optional metadata dict) rides into the Chrome dump;
+        ``step_num`` makes the span a step marker in a device profile."""
         if not self.enabled:
             return _NULL
-        return _Span(self, name, args)
+        return _Span(self, name, args, step_num)
 
     def record_span(self, name: str, t0: float, t1: float,
                     args: Optional[dict] = None) -> None:
@@ -224,18 +253,20 @@ def tracing() -> bool:
     return t is not None and t.enabled
 
 
-def span(name: str, args: Optional[dict] = None):
+def span(name: str, args: Optional[dict] = None,
+         step_num: Optional[int] = None):
     """``with span("prep"): ...`` on the default tracer.
 
     Call sites keep this unconditionally: disabled (the default) it
     returns the shared nullcontext without allocating.  ``args`` is the
     optional metadata dict for the Chrome dump — held by reference, so
-    a call site may fill it in before the span exits.
+    a call site may fill it in before the span exits.  ``step_num``
+    marks the span as one step of the run (:data:`_STEP_SUFFIX`).
     """
     t = _tracer
     if t is None or not t.enabled:
         return _NULL
-    return _Span(t, name, args)
+    return _Span(t, name, args, step_num)
 
 
 def enable(*, keep_events: bool = False) -> Tracer:

@@ -128,8 +128,7 @@ class HostPlannedTrainer:
         # host_gather / device_step / write_back histograms; profile=
         # makes device_step block on the chunk's output (honest
         # execution window — the CLI's profile_steps= flag)
-        self.phases = phases or StepPhases(profile=profile,
-                                           annotate=profile)
+        self.phases = phases or StepPhases(profile=profile)
         self.cache = DeviceHotCache(master, self.hot_rows)
         # ONE local config per capacity: the chunk program's static
         # num_nodes is the cache size C (remapped sentinel = C), so

@@ -417,7 +417,6 @@ def run_loop(run, state, stepper, project=None, steps_per_call=1,
         prof_until = start + profile_steps
         while True:
             while done < run.steps:
-                t_disp = time.perf_counter()
                 # span args: step-at-dispatch + chunk size, so a slow
                 # span in the Perfetto timeline is attributable to its
                 # position (built only while tracing — the disabled hot
@@ -425,107 +424,112 @@ def run_loop(run, state, stepper, project=None, steps_per_call=1,
                 args = ({"step": done, "chunk": steps_per_call}
                         if tracing() else None)
                 prof = profile_steps > 0 and done < prof_until
-                with span("dispatch", args=args):
-                    state, loss = stepper(state)
+                # one iteration = one step marker in a device profile: its
+                # self time (no child span covers it) is the loop's own host
+                # work — back-pressure waits sit inside `dispatch`
+                with span("train_step", args=args, step_num=done):
+                    t_disp = time.perf_counter()
+                    with span("dispatch", args=args):
+                        state, loss = stepper(state)
+                        if prof:
+                            # profiled window: the dispatch time must read
+                            # execution, not enqueue (block_until_ready is
+                            # not a host fetch — no value crosses to the host)
+                            jax.block_until_ready(loss)
+                    disp_ms = (time.perf_counter() - t_disp) * 1e3
+                    telem.observe("train/dispatch_ms", disp_ms)
                     if prof:
-                        # profiled window: the dispatch time must read
-                        # execution, not enqueue (block_until_ready is
-                        # not a host fetch — no value crosses to the host)
-                        jax.block_until_ready(loss)
-                disp_ms = (time.perf_counter() - t_disp) * 1e3
-                telem.observe("train/dispatch_ms", disp_ms)
-                if prof:
-                    telem.observe("train/phase/device_step_ms", disp_ms)
-                telem.inc("train/dispatches")
-                if mwriter is not None:
-                    try:
-                        mwriter.maybe_write()
-                    except OSError:
-                        pass  # scrape-file loss never sinks the run
-                if faults.active() and faults.poison("train.step_nan"):
-                    # chaos: the device-side shape one poisoned batch
-                    # leaves after its step (docs/resilience.md)
-                    state, loss = _poison(state, loss)
-                chunk_i += 1
-                if acc is not None:
-                    acc.add(loss)
-                if jnp.ndim(loss):  # scanned chunk: [spc] losses
-                    loss = loss[-1]
-                # the stepper always executes exactly steps_per_call
-                # steps (the scan length is baked into the program), so
-                # the recorded step count is the TRUE count — never
-                # clamped
-                prev, done = done, done + steps_per_call
-                # boundary-crossing gates: with chunked stepping, `done`
-                # only takes chunk multiples, so exact-equality cadence
-                # would degrade to lcm(chunk, interval); fire whenever
-                # the chunk crossed an interval boundary (identical to
-                # the old `done % every == 0` when steps_per_call == 1)
-                if (done // every) > (prev // every):
-                    # the float(loss) fetch is the interval's real
-                    # block-until-device-done (dispatch is async
-                    # enqueue), so it must sit INSIDE the span or the
-                    # wait would show up nowhere in the span breakdown
-                    t_flush = time.perf_counter()
-                    with span("metrics_flush"):
-                        kw = {"loss": float(loss)}  # hyperlint: disable=host-sync-in-hot-path — the documented per-boundary fetch
-                        if acc is not None:
-                            stats = acc.flush()
-                            if stats is not None:
-                                kw.update(stats)
-                    telem.observe("train/metrics_flush_ms",
-                                  (time.perf_counter() - t_flush) * 1e3)
-                    if ctrl is not None and ctrl.divergent(kw["loss"]):
-                        # the poisoned interval's record is the incident
-                        # event, not a loss row
-                        state, done = do_rollback(
-                            state, done, log,
-                            f"non-finite loss at step {done}")
-                        loss = jnp.nan
-                        continue
-                    log.log(done, **kw, **record_fields())
-                # health sampling rides the chunk cadence, not the log
-                # one: a diverging run should flag BEFORE the next log
-                # boundary
-                if monitor is not None and chunk_i % health_every == 0:
-                    if ctrl is None:
-                        monitor.check(state, done, log)
-                    else:
-                        # guard mode: a threshold violation (or the
-                        # monitor's own abort) is a rollback trigger,
-                        # not a warning/abort — until the budget runs out
+                        telem.observe("train/phase/device_step_ms", disp_ms)
+                    telem.inc("train/dispatches")
+                    if mwriter is not None:
                         try:
-                            bad = monitor.problems(
-                                monitor.check(state, done, log))
-                        except FloatingPointError as e:
-                            bad = [str(e)]
-                        if bad:
+                            mwriter.maybe_write()
+                        except OSError:
+                            pass  # scrape-file loss never sinks the run
+                    if faults.active() and faults.poison("train.step_nan"):
+                        # chaos: the device-side shape one poisoned batch
+                        # leaves after its step (docs/resilience.md)
+                        state, loss = _poison(state, loss)
+                    chunk_i += 1
+                    if acc is not None:
+                        acc.add(loss)
+                    if jnp.ndim(loss):  # scanned chunk: [spc] losses
+                        loss = loss[-1]
+                    # the stepper always executes exactly steps_per_call
+                    # steps (the scan length is baked into the program), so
+                    # the recorded step count is the TRUE count — never
+                    # clamped
+                    prev, done = done, done + steps_per_call
+                    # boundary-crossing gates: with chunked stepping, `done`
+                    # only takes chunk multiples, so exact-equality cadence
+                    # would degrade to lcm(chunk, interval); fire whenever
+                    # the chunk crossed an interval boundary (identical to
+                    # the old `done % every == 0` when steps_per_call == 1)
+                    if (done // every) > (prev // every):
+                        # the float(loss) fetch is the interval's real
+                        # block-until-device-done (dispatch is async
+                        # enqueue), so it must sit INSIDE the span or the
+                        # wait would show up nowhere in the span breakdown
+                        t_flush = time.perf_counter()
+                        with span("metrics_flush"):
+                            kw = {"loss": float(loss)}  # hyperlint: disable=host-sync-in-hot-path — the documented per-boundary fetch
+                            if acc is not None:
+                                stats = acc.flush()
+                                if stats is not None:
+                                    kw.update(stats)
+                        telem.observe("train/metrics_flush_ms",
+                                      (time.perf_counter() - t_flush) * 1e3)
+                        if ctrl is not None and ctrl.divergent(kw["loss"]):
+                            # the poisoned interval's record is the incident
+                            # event, not a loss row
                             state, done = do_rollback(
                                 state, done, log,
-                                "health: " + "; ".join(bad))
+                                f"non-finite loss at step {done}")
                             loss = jnp.nan
                             continue
-                # ckpt_every <= 0 = final save only (mirrors
-                # eval_every's "0 = eval only at the end"; orbax's
-                # interval gate divides by the interval, so it never
-                # sees a 0)
-                if ck is not None and run.ckpt_every > 0:
-                    iv = run.ckpt_every
-                    crossed = (done // iv) > (prev // iv)
-                    if ctrl is not None and crossed:
-                        # guard-only fetch: a poisoned state must never
-                        # be saved — it would become the rollback target
-                        lv = float(loss)
-                        if ctrl.divergent(lv):
-                            state, done = do_rollback(
-                                state, done, log,
-                                f"non-finite loss at save boundary, "
-                                f"step {done}")
-                            loss = jnp.nan
-                            continue
-                    if ck.save(done, state,
-                               force=crossed and steps_per_call > 1):
-                        last_saved = done
+                        log.log(done, **kw, **record_fields())
+                    # health sampling rides the chunk cadence, not the log
+                    # one: a diverging run should flag BEFORE the next log
+                    # boundary
+                    if monitor is not None and chunk_i % health_every == 0:
+                        if ctrl is None:
+                            monitor.check(state, done, log)
+                        else:
+                            # guard mode: a threshold violation (or the
+                            # monitor's own abort) is a rollback trigger,
+                            # not a warning/abort — until the budget runs out
+                            try:
+                                bad = monitor.problems(
+                                    monitor.check(state, done, log))
+                            except FloatingPointError as e:
+                                bad = [str(e)]
+                            if bad:
+                                state, done = do_rollback(
+                                    state, done, log,
+                                    "health: " + "; ".join(bad))
+                                loss = jnp.nan
+                                continue
+                    # ckpt_every <= 0 = final save only (mirrors
+                    # eval_every's "0 = eval only at the end"; orbax's
+                    # interval gate divides by the interval, so it never
+                    # sees a 0)
+                    if ck is not None and run.ckpt_every > 0:
+                        iv = run.ckpt_every
+                        crossed = (done // iv) > (prev // iv)
+                        if ctrl is not None and crossed:
+                            # guard-only fetch: a poisoned state must never
+                            # be saved — it would become the rollback target
+                            lv = float(loss)  # hyperlint: disable=host-sync-in-hot-path — guard mode only, once per save boundary
+                            if ctrl.divergent(lv):
+                                state, done = do_rollback(
+                                    state, done, log,
+                                    f"non-finite loss at save boundary, "
+                                    f"step {done}")
+                                loss = jnp.nan
+                                continue
+                        if ck.save(done, state,
+                                   force=crossed and steps_per_call > 1):
+                            last_saved = done
             # end-of-run divergence check: a chunk past the last crossed
             # boundary can still be poisoned — never close (or final-
             # save) a diverged run while the guard has budget left

@@ -27,10 +27,9 @@ Prometheus families the serve plane does — and the multihost
 aggregation hook (``parallel/multihost.gather_metric_exports``) merges
 them across processes unchanged.
 
-``annotate=True`` additionally wraps each phase in a
-``jax.profiler.TraceAnnotation`` so the phases appear as named ranges
-in a captured device profile; the import is lazy and degrades to a
-no-op where the profiler is unavailable.
+Each phase is also a trace span (``telemetry/trace.py``): with the
+tracer on, the phases show as named ranges in the Chrome dump and in
+any captured device profile.
 
 Host-table cache effectiveness (hit/miss/evict counters and the
 ``host_table/cache_hit_rate`` gauge) ticks inside
@@ -46,6 +45,7 @@ import time
 from typing import Callable, Optional
 
 from hyperspace_tpu.telemetry import registry as telem
+from hyperspace_tpu.telemetry.trace import span
 
 # chunk-phase order: consecutive phases of one chunk never overlap, so
 # their bounds are monotone in this order (tested)
@@ -58,30 +58,17 @@ def install_hooks() -> None:
     telem.install_jax_monitoring_hook()
 
 
-def _annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` for ``name``, or a no-op
-    where the profiler API is unavailable (stripped builds)."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except (ImportError, AttributeError):
-        return contextlib.nullcontext()
-    return TraceAnnotation(name)
-
-
 class StepPhases:
     """Per-chunk phase timers (module docstring).
 
     ``profile=True`` makes the ``device_step`` phase block on its
     output before closing (honest execution window — the bench/debug
-    mode the CLI's ``profile_steps=`` flag arms); ``annotate=True``
-    adds profiler trace annotations.  The last chunk's readings stay
-    on :attr:`last` (ms) and :attr:`last_bounds` (raw perf_counter
-    pairs) for assertions and log records."""
+    mode the CLI's ``profile_steps=`` flag arms).  The last chunk's
+    readings stay on :attr:`last` (ms) and :attr:`last_bounds` (raw
+    perf_counter pairs) for assertions and log records."""
 
-    def __init__(self, profile: bool = False,
-                 annotate: bool = False):
+    def __init__(self, profile: bool = False):
         self.profile = bool(profile)
-        self.annotate = bool(annotate)
         self.last: dict[str, float] = {}
         self.last_bounds: dict[str, tuple] = {}
 
@@ -92,18 +79,14 @@ class StepPhases:
         ``profile`` mode, AFTER the body, so late-bound locals are
         fine: ``with phases.phase("device_step", lambda: out.packed):``
         """
-        ann = _annotation(name) if self.annotate else None
         t0 = time.perf_counter()
         try:
-            if ann is not None:
-                with ann:
-                    yield
-            else:
+            with span(name):
                 yield
-            if self.profile and block is not None:
-                import jax
+                if self.profile and block is not None:
+                    import jax
 
-                jax.block_until_ready(block())
+                    jax.block_until_ready(block())
         finally:
             t1 = time.perf_counter()
             self.last[name] = (t1 - t0) * 1e3

@@ -131,3 +131,51 @@ def test_enable_disable_roundtrip():
         assert not t.enabled
     finally:
         t.enabled, t.keep_events = was_enabled, was_keep
+
+
+def test_disabled_step_marker_is_the_same_nullcontext():
+    # the loop's per-iteration step marker keeps the zero-cost contract:
+    # no _Span, no profiler annotation, nothing recorded
+    t = trace.default_tracer()
+    was = t.enabled
+    t.enabled = False
+    try:
+        before = t.total_fields()
+        assert trace.span("train_step", None, step_num=7) is trace._NULL
+        assert t.span("train_step", step_num=7) is trace._NULL
+        assert t.total_fields() == before
+    finally:
+        t.enabled = was
+
+
+def test_enabled_span_enters_a_profiler_annotation(monkeypatch):
+    # what a jax.profiler session sees of an enabled span: a step marker
+    # named without the "_step" suffix, else the span's own name + args
+    import jax.profiler as jp
+
+    made = []
+
+    class Ann:
+        def __init__(self, name, **kw):
+            made.append((type(self).__name__, name, kw, []))
+            self._log = made[-1][3]
+
+        def __enter__(self):
+            self._log.append("enter")
+
+        def __exit__(self, *exc):
+            self._log.append("exit")
+
+    monkeypatch.setattr(jp, "TraceAnnotation", type("Plain", (Ann,), {}))
+    monkeypatch.setattr(jp, "StepTraceAnnotation", type("Step", (Ann,), {}))
+    t = _fresh()
+    with t.span("train_step", {"step": 3, "chunk": 1}, step_num=3):
+        with t.span("dispatch", {"step": 3}):
+            pass
+        with t.span("metrics_flush"):
+            pass
+    assert made == [
+        ("Step", "train", {"step_num": 3}, ["enter", "exit"]),
+        ("Plain", "dispatch", {"step": 3}, ["enter", "exit"]),
+        ("Plain", "metrics_flush", {}, ["enter", "exit"])]
+    assert t.total_fields()["span/train_step_n"] == 1
