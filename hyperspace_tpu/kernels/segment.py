@@ -89,6 +89,51 @@ def build_csr_plan(
     return CsrPlan(block=block, chunk=chunk.astype(np.int32), first=first)
 
 
+def rows_for_device_plan(e: int) -> int:
+    """``e`` rows as whole chunks, plus the one all-zero chunk that
+    :func:`device_csr_plan`'s unused items point at."""
+    return S.round_up(e, _BK) + _BK
+
+
+def device_csr_plan(receivers: jax.Array, num_nodes: int) -> tuple:
+    """:func:`build_csr_plan` on the device, for sorted receivers that
+    are new on every step (the LP decoder's pair indices).
+
+    The item count T depends on the data and a compiled program's shapes
+    cannot, so the plan has the static length ``T_max = n_chunks +
+    n_blocks`` (each node block adds at most one boundary chunk to the
+    chunks, so T < T_max).  Its first T items are ``build_csr_plan``'s;
+    the rest revisit the last block (``first = 0``: nothing is zeroed)
+    with the LAST chunk, which must therefore hold zero rows only:
+    ``len(receivers)`` is :func:`rows_for_device_plan` of the data's
+    length, the rows behind the data zero, their ids ``num_nodes`` (so
+    that a sort puts them last).
+    """
+    e, bn, bk = receivers.shape[0], _BN, _BK
+    if e % bk:
+        raise ValueError(f"device_csr_plan needs a whole number of {bk}-row "
+                         f"chunks, got {e} rows")
+    i32 = jnp.int32
+    nchunks, nb = e // bk, -(-num_nodes // bn)
+    bounds = jnp.searchsorted(
+        receivers, jnp.minimum(jnp.arange(nb + 1, dtype=i32) * bn, num_nodes),
+        side="left", method="scan_unrolled").astype(i32)
+    starts, ends = bounds[:-1], bounds[1:]
+    # the same clamps, in the same order, as build_csr_plan
+    c0 = jnp.minimum(starts // bk, nchunks - 1)
+    c1 = jnp.clip(-(-ends // bk), c0 + 1, nchunks)
+    counts = c1 - c0
+    offsets = jnp.cumsum(counts, dtype=i32) - counts  # strictly increasing
+    t_max = nchunks + nb
+    first = jnp.zeros(t_max, i32).at[offsets].set(
+        1, indices_are_sorted=True, unique_indices=True)
+    block = jnp.cumsum(first, dtype=i32) - 1  # stays nb - 1 past item T
+    t = jnp.arange(t_max, dtype=i32)
+    chunk = jnp.where(t < offsets[-1] + counts[-1],
+                      t - offsets[block] + c0[block], nchunks - 1)
+    return block, chunk, first
+
+
 def _body(bn: int):
     def body(blk_ref, chk_ref, first_ref, recv_ref, vals_ref, o_ref):
         t = pl.program_id(0)
@@ -167,6 +212,114 @@ def csr_segment_sum(
     out = _pallas_csr(vals, recv2d, tuple(plan), num_segments, bn, bk,
                       S.interpret_flag(m))
     return out[:num_segments, :f].astype(values.dtype)
+
+
+def _body_t(bn: int, precision):
+    def body(blk_ref, chk_ref, first_ref, recv_ref, vals_ref, o_ref):
+        t = pl.program_id(0)
+        b = blk_ref[t]
+
+        @pl.when(first_ref[t] == 1)
+        def _():
+            o_ref[:] = jnp.zeros_like(o_ref)
+
+        recv = recv_ref[0]                       # [bk//128, 128] int32
+        local = recv - b * bn
+        acc = jnp.zeros_like(o_ref[:], jnp.float32)        # [F, bn]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bn, 128), 0)
+        for j in range(recv.shape[0]):
+            oh = (rows == local[j : j + 1, :]).astype(jnp.float32)
+            vals = vals_ref[:, j * 128 : (j + 1) * 128].astype(jnp.float32)
+            # [F, 128 edges] x [bn, 128 edges], contracted over the edges
+            acc += jax.lax.dot_general(
+                vals, oh, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision)
+        o_ref[:] += acc
+
+    return body
+
+
+def pair_scatter_sum(
+    values_t: jax.Array,   # [F, E] edge values, one edge a column
+    receivers: jax.Array,  # [E] int32, sorted, laid out for device_csr_plan
+    num_segments: int,
+) -> jax.Array:
+    """:func:`csr_segment_sum` of narrow rows handed over TRANSPOSED
+    (the LP decoder's ``[E, 33]`` cotangent rows); ``[F, num_segments]``
+    float32.  XLA keeps such an array with E on the lanes (33 of 128
+    lanes would idle), which is this layout for free, while the
+    row-major ``[E, 128]`` that ``csr_segment_sum`` reads costs a
+    transposing copy and a pad, 2 + 3 ms at 3.8 M rows (PERF.md §6,
+    PR 27).  The same one-hot matmuls, operands swapped, under a plan
+    built on the device (the receivers are new on every step); a call
+    name of its own, so that a trace tells the decoder's call from the
+    aggregation's.  Twin: sorted ``segment_sum`` in float32."""
+    f, e = values_t.shape
+    m = S.mode()
+    if m == "xla":
+        acc_dt = jnp.promote_types(values_t.dtype, jnp.float32)
+        return jax.ops.segment_sum(values_t.T.astype(acc_dt), receivers,
+                                   num_segments, indices_are_sorted=True).T
+    bn, bk = _BN, _BK
+    if e % bk:
+        raise ValueError(f"pair_scatter_sum needs whole {bk}-edge chunks, "
+                         f"got {e} edges")
+    # a 0/1 one-hot times a bfloat16 value is exact in ONE bf16 pass with
+    # float32 accumulation; float32 values need the three-pass split
+    precision = (jax.lax.Precision.DEFAULT if values_t.dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
+    plan = device_csr_plan(receivers, num_segments)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(plan[0].shape[0],),
+        in_specs=[
+            pl.BlockSpec((1, bk // 128, 128),
+                         lambda t, blk, chk, first: (chk[t], 0, 0)),
+            pl.BlockSpec((f, bk), lambda t, blk, chk, first: (0, chk[t])),
+        ],
+        out_specs=pl.BlockSpec((f, bn), lambda t, blk, chk, first: (0, blk[t])),
+    )
+    out = pl.pallas_call(
+        _body_t(bn, precision),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((f, S.round_up(num_segments, bn)),
+                                       jnp.float32),
+        name="pair_scatter_sum",
+        interpret=S.interpret_flag(m),
+    )(*plan, receivers.reshape(e // bk, bk // 128, 128), values_t)
+    return out[:, :num_segments]
+
+
+def rows_to_columns(x: jax.Array) -> jax.Array:
+    """``x.T`` for a long narrow row-major ``x`` ``[R, F]`` (R a
+    multiple of 512), as ONE pass.  XLA's gather writes ``[R, 33]`` rows
+    row-major and its elementwise consumers want R on the lanes; left to
+    itself it slices the rows row-major and then copies every slice,
+    19 ms for the LP decoder's 7.5 M re-gathered rows (PERF.md §6,
+    PR 27).  A Pallas
+    call's operand is row-major and its result what the kernel writes,
+    so the transposition happens here and nowhere else."""
+    r, f = x.shape
+    m = S.mode()
+    if m == "xla":
+        return x.T
+    if r % 512:
+        raise ValueError(f"rows_to_columns needs whole 512-row blocks, "
+                         f"got {r} rows")
+    block_rows = next(b for b in (2048, 1024, 512) if r % b == 0)
+
+    def body(x_ref, o_ref):
+        o_ref[...] = x_ref[...].T
+
+    return pl.pallas_call(
+        body,
+        grid=(r // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, f), lambda t: (t, 0))],
+        out_specs=pl.BlockSpec((f, block_rows), lambda t: (0, t)),
+        out_shape=jax.ShapeDtypeStruct((f, r), x.dtype),
+        name="rows_to_columns",
+        interpret=S.interpret_flag(m),
+    )(x)
 
 
 # --- scalar (per-edge) segment reductions -------------------------------------

@@ -62,9 +62,10 @@ class HGCNConfig:
     agg_dtype: Any = None
     # dtype of the LP decoder's pair-distance pass during TRAINING
     # (None = dtype); eval always scores in full precision.  bf16 halves
-    # the 2.2 M-pair gather/scatter traffic; the planned scatters
-    # (train_step_lp_pairs / _planned) get the full bandwidth win, the
-    # unplanned step's XLA scatter much less — docs/benchmarks.md
+    # the bytes of the 1.9 M-pair gathers, elementwise passes and
+    # cotangent rows.  (train_step_lp_pairs / _planned are other steps,
+    # not faster forms of this one: nn/edge_dist.py says what each
+    # changes about the sampler and the pair set.)
     decoder_dtype: Any = None
     # rematerialize each conv layer in the backward pass (jax.checkpoint):
     # trades an extra forward per layer for not storing its residuals.
@@ -151,7 +152,8 @@ class HGCNLinkPred(nn.Module):
     cfg: HGCNConfig
 
     @nn.compact
-    def __call__(self, g: graph_data.DeviceGraph, pairs, *, deterministic=True):
+    def __call__(self, g: graph_data.DeviceGraph, pairs, *, deterministic=True,
+                 sorted_vjp=False):
         z, m = HGCNEncoder(self.cfg, name="encoder")(
             g, deterministic=deterministic
         )
@@ -159,7 +161,17 @@ class HGCNLinkPred(nn.Module):
         if ddt is not None and not deterministic:
             z = z.astype(ddt)  # train only; eval full-prec
         with jax.named_scope("pair_dist"):
-            sq = m.sqdist(z[pairs[:, 0]], z[pairs[:, 1]])
+            u, v = pairs[:, 0], pairs[:, 1]
+            if sorted_vjp:
+                # the same forward; the backward sums the cotangent rows
+                # with one block-CSR kernel call in place of XLA's two
+                # scatter-adds.  A Pallas call, so only where no mesh
+                # partitions the step (_lp_step_impl)
+                from hyperspace_tpu.nn.edge_dist import pair_sqdist
+
+                sq = pair_sqdist(z, m.c, u, v, self.cfg.kind)
+            else:
+                sq = m.sqdist(z[u], z[v])
         return FermiDiracDecoder(name="decoder")(sq.astype(self.cfg.dtype))
 
     @nn.compact
@@ -333,8 +345,11 @@ def _lp_step_impl(model, opt, num_nodes, state, g, train_pos, constrain=None,
                   split_pairs=False):
     """Shared LP step body: sample negatives on device, BCE on pos+neg
     logits.  ``constrain`` (optional) pins the supervision batch's sharding
-    (GSPMD hint) — the only difference between the single-device and the
-    mesh-sharded step, so both jit wrappers compile this same program."""
+    (GSPMD hint).  Without one no mesh partitions the step, and the
+    decoder's pair distances take the VJP that sums their cotangent rows
+    with a Pallas kernel (`nn.edge_dist.pair_sqdist`); under a mesh Mosaic
+    kernels cannot be partitioned and the pair batch is sharded while
+    ``dz`` is not, so the mesh steps keep XLA's scatter-add."""
     n_neg = train_pos.shape[0] * model.cfg.neg_per_pos
     with jax.named_scope("negatives"):
         key, k_neg, k_drop = jax.random.split(state.key, 3)
@@ -371,6 +386,7 @@ def _lp_step_impl(model, opt, num_nodes, state, g, train_pos, constrain=None,
         logits = model.apply(
             {"params": params}, g, pairs,
             deterministic=False, rngs={"dropout": k_drop},
+            sorted_vjp=constrain is None,
         )
         with jax.named_scope("loss"):
             labels = jnp.concatenate(

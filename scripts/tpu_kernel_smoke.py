@@ -102,6 +102,39 @@ def main():
     oks.append(run("csr_segment_sum",
                    lambda: csr_segment_sum(vals, recv_d, plan, 200)))
 
+    # the LP decoder's call: rows handed over transposed, the plan built
+    # on the device from ids no host has seen, bf16 rows in one MXU pass
+    from hyperspace_tpu.kernels.segment import (
+        pair_scatter_sum,
+        rows_for_device_plan,
+        rows_to_columns,
+    )
+    from hyperspace_tpu.nn.edge_dist import pair_sqdist
+
+    e = 5000
+    ids = np.full(rows_for_device_plan(e), 200, np.int32)
+    ids[:e] = np.sort(rng.integers(0, 200, e))
+    ids[: e // 3] = 7  # one hub
+    ids_d = jnp.asarray(np.sort(ids))
+    vt = np.zeros((33, len(ids)), np.float32)
+    vt[:, :e] = rng.normal(size=(33, e))
+    for dt in (jnp.bfloat16, jnp.float32):
+        vt_d = jnp.asarray(vt, dt)
+        oks.append(run(
+            f"pair_scatter_sum_device_plan_{jnp.dtype(dt).name}",
+            lambda: pair_scatter_sum(vt_d, ids_d, 200)))
+    for dt in (jnp.bfloat16, jnp.float32):
+        xr = jnp.asarray(rng.normal(size=(3072, 33)), dt)
+        oks.append(run(f"rows_to_columns_{jnp.dtype(dt).name}",
+                       lambda: rows_to_columns(xr).astype(jnp.float32),
+                       tol=1e-12))
+    zl = lor.random_normal(ks[15], (200, 33), jnp.float32, std=0.3)
+    pu = jnp.asarray(rng.integers(0, 200, e), jnp.int32)
+    pv = jnp.asarray(rng.integers(0, 200, e), jnp.int32)
+    tw = jnp.asarray(rng.normal(size=e), jnp.float32)
+    oks.append(run("pair_sqdist_grad", lambda: jax.grad(
+        lambda zz: jnp.sum(pair_sqdist(zz, c, pu, pv, "lorentz") * tw))(zl)))
+
     # scalar CSR reductions: the lane-partial accumulator layout is exactly
     # what interpret mode can't exercise — real-chip parity matters here
     from hyperspace_tpu.kernels.segment import csr_segment_reduce_1d

@@ -11,10 +11,15 @@ import pytest
 from tests.tiny_lp import lp_step
 
 # kernel -> the pallas_calls one step makes of it (two layers; forward
-# and backward where the backward reuses the kernel)
-MEAN = {"cluster_aggregate": 4, "csr_segment_sum": 4}
+# and backward where the backward reuses the kernel).  The decoder's
+# backward (nn.edge_dist.pair_sqdist) makes two: rows_to_columns turns its
+# re-gathered rows, pair_scatter_sum sums the cotangent rows, a
+# block-CSR sum under a name of its own, so that a trace tells the
+# decoder's call from the aggregation's four
+DECODER = {"rows_to_columns": 1, "pair_scatter_sum": 1}
+MEAN = {"cluster_aggregate": 4, "csr_segment_sum": 4, **DECODER}
 ATT = {"cluster_att_fwd": 2, "cluster_att_bwd": 2, "csr_segment_sum": 4,
-       "csr_segment_reduce_1d": 2, "csr_att_bwd_edges": 2}
+       "csr_segment_reduce_1d": 2, "csr_att_bwd_edges": 2, **DECODER}
 
 
 def _sub_jaxprs(params):

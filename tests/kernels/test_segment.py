@@ -99,3 +99,114 @@ def test_csr_segment_reduce_1d_parity(op, monkeypatch):
                        -np.inf, np.asarray(ref))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
+
+
+# --- the plan built on the device, and the transposed call that runs on it ----
+
+
+def _sorted_ids(case, n, e, rng):
+    """Sorted ids as the LP decoder's backward hands them over: the
+    data, then `rows_for_device_plan`'s tail with id ``n``."""
+    from hyperspace_tpu.kernels.segment import rows_for_device_plan
+
+    if case == "hub":  # one node takes a third of the rows
+        ids = rng.integers(0, n, e)
+        ids[: e // 3] = n // 2
+    elif case == "empty_ends":  # empty leading and trailing node blocks
+        ids = rng.integers(n // 3, 2 * n // 3, e)
+    elif case == "last_node":  # the data's largest id beside the tail's
+        ids = np.full(e, n - 1)
+    else:
+        ids = rng.integers(0, n, e)
+    out = np.full(rows_for_device_plan(e), n, np.int32)
+    out[:e] = np.sort(ids)
+    return out
+
+
+PLAN_CASES = [("uniform", 1000, 5000), ("uniform", 300, 1024),
+              ("uniform", 128, 513), ("uniform", 130, 100),
+              ("hub", 1000, 5000), ("hub", 300, 1536),
+              ("empty_ends", 1000, 5000), ("empty_ends", 300, 700),
+              ("last_node", 200, 600)]
+
+
+@pytest.mark.parametrize("case,n,e", PLAN_CASES)
+def test_device_plan_is_the_host_plan_with_an_inert_tail(case, n, e, rng):
+    from hyperspace_tpu.kernels.segment import device_csr_plan
+
+    ids = _sorted_ids(case, n, e, rng)
+    host = build_csr_plan(ids, n)
+    dev = [np.asarray(a) for a in
+           jax.jit(lambda r: device_csr_plan(r, n))(jnp.asarray(ids))]
+    t, nb, nchunks = len(host.block), -(-n // 128), len(ids) // 512
+    assert all(a.shape == (nchunks + nb,) and a.dtype == np.int32
+               for a in dev)
+    assert t < nchunks + nb
+    for want, got in zip(host, dev):
+        np.testing.assert_array_equal(got[:t], want)
+    # the unused items: the last block again, nothing zeroed, the chunk
+    # that holds only the tail's zero rows
+    assert (dev[0][t:] == nb - 1).all() and (dev[2][t:] == 0).all()
+    assert (dev[1][t:] == nchunks - 1).all() and (ids[-512:] == n).all()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case,n,e", PLAN_CASES)
+def test_pair_scatter_sum_matches_segment_sum(case, n, e, dtype, rng, interp):
+    from hyperspace_tpu.kernels.segment import pair_scatter_sum
+
+    ids = _sorted_ids(case, n, e, rng)
+    vt = np.zeros((33, len(ids)), np.float32)
+    vt[:, :e] = rng.standard_normal((33, e))
+    vt_d, ids_d = jnp.asarray(vt, dtype), jnp.asarray(ids)
+    got = pair_scatter_sum(vt_d, ids_d, n)
+    assert got.shape == (33, n) and got.dtype == jnp.float32
+    # the oracle accumulates what the kernel was given, in float32
+    want = jax.ops.segment_sum(vt_d.astype(jnp.float32).T[:e],
+                               ids_d[:e], n).T
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=5e-4)
+
+
+def test_pair_scatter_sum_twin_is_the_same_sum(rng, monkeypatch):
+    from hyperspace_tpu.kernels.segment import pair_scatter_sum
+
+    monkeypatch.setenv("HYPERSPACE_KERNELS", "xla")
+    ids = _sorted_ids("hub", 300, 1536, rng)
+    vt = jnp.asarray(rng.standard_normal((33, len(ids))), jnp.bfloat16)
+    vt = vt.at[:, 1536:].set(0)
+    got = pair_scatter_sum(vt, jnp.asarray(ids), 300)
+    want = jax.ops.segment_sum(vt.astype(jnp.float32).T, jnp.asarray(ids),
+                               300).T
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("what", ["plan", "kernel"])
+def test_device_plan_wants_whole_chunks(what, interp):
+    from hyperspace_tpu.kernels.segment import (
+        device_csr_plan,
+        pair_scatter_sum,
+    )
+
+    ids = jnp.zeros(700, jnp.int32)
+    with pytest.raises(ValueError, match="whole"):
+        if what == "plan":
+            device_csr_plan(ids, 100)
+        else:
+            pair_scatter_sum(jnp.zeros((33, 700)), ids, 100)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows", [512, 3072, 4096])
+def test_rows_to_columns_is_the_transpose(rows, dtype, rng, interp):
+    from hyperspace_tpu.kernels.segment import rows_to_columns
+
+    x = jnp.asarray(rng.standard_normal((rows, 33)), dtype)
+    got = rows_to_columns(x)
+    assert got.shape == (33, rows) and got.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(x.T, np.float32))
+    with pytest.raises(ValueError, match="whole"):
+        rows_to_columns(x[:500])

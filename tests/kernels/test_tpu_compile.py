@@ -127,6 +127,50 @@ def test_csr_segment_sum_bf16(one_chip, arxiv_split):
              A((e, ARXIV_FEATS), BF16), A((e,), I32), plan, n_kernels=1)
 
 
+def test_pair_scatter_sum_at_the_cell_shape(one_chip):
+    """The LP decoder's backward sum as both benchmark cells run it: the
+    cotangent rows of 2 x 1,880,610 pair ends, 33 bf16 lanes, handed over
+    transposed, into 169,343 nodes under a plan built on the device."""
+    from hyperspace_tpu.kernels.segment import (
+        pair_scatter_sum,
+        rows_for_device_plan,
+    )
+
+    n, e = 169_343, rows_for_device_plan(2 * 1_880_610)
+    A = _arg(one_chip)
+    _, text = _compile(lambda v, r: pair_scatter_sum(v, r, n),
+                       A((33, e), BF16), A((e,), I32), n_kernels=1)
+    assert "pair_scatter_sum" in text
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_rows_to_columns_at_the_cell_shape(one_chip, dtype):
+    """The 2 x 3,762,176 rows the decoder's backward re-gathers, at the
+    stated lanes and at the twin's."""
+    from hyperspace_tpu.kernels.segment import rows_to_columns
+
+    _compile(rows_to_columns, _arg(one_chip)((7_524_352, 33), dtype),
+             n_kernels=1)
+
+
+@pytest.mark.parametrize("kind", ["lorentz", "poincare"])
+def test_pair_sqdist_grad_at_the_cell_shape(one_chip, kind):
+    """`nn.edge_dist.pair_sqdist` forward and backward alone, bf16 lanes:
+    the sort, the one gather, sqdist's VJP and the kernel compile and fit."""
+    from hyperspace_tpu.nn.edge_dist import pair_sqdist
+
+    n, p, d = 169_343, 1_880_610, 33 if kind == "lorentz" else 32
+    A = _arg(one_chip)
+    compiled, text = _compile(
+        jax.grad(lambda z, u, v, w: jnp.sum(
+            pair_sqdist(z, 1.0, u, v, kind).astype(F32) * w)),
+        A((n, d), BF16), A((p,), I32), A((p,), I32), A((p,)), n_kernels=2)
+    assert "pair_scatter_sum" in text and "rows_to_columns" in text
+    assert "scatter-add" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 6 * 2**30
+
+
 @pytest.fixture(scope="module")
 def cluster_args(one_chip, arxiv_split):
     cs = arxiv_split.graph.cluster_split
@@ -258,6 +302,8 @@ def test_train_step_lp_at_arxiv_width(one_chip, arxiv_split):
     text = compiled.as_text()
     mem = compiled.memory_analysis()
     assert text.count("tpu_custom_call") >= 6, text.count("tpu_custom_call")
+    # the decoder's backward runs on its kernels, not on XLA's scatter-add
+    assert "pair_scatter_sum" in text and "rows_to_columns" in text
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert held < 12 * 2**30, f"{held / 2**30:.2f} GiB of a 16 GB chip"

@@ -3,9 +3,11 @@
 The north-star workload (HGCN LP) trains through
 `models/hgcn.make_sharded_step_lp` on dp-only, tp-only and dp×tp meshes
 over the 8 virtual CPU devices; each must agree with the plain
-`train_step_lp` run — same PRNG stream both ways, so only collective
+single-device step — same PRNG stream both ways, so only collective
 reduction order differs (float tolerance, not bitwise).
 """
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -28,9 +30,14 @@ def _setup(seed=0):
 def _run_single(cfg, split, steps, train_pos):
     model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
     ga = G.to_device(split.graph)
+    # the mesh steps' own program on one device: `train_step_lp`'s body
+    # with the decoder's sorted VJP off (an identity ``constrain``), so
+    # that sharding is all that differs.  The sorted VJP against this
+    # step is tests/models/test_lp_sorted_vjp.py's, at its own tolerance.
+    step = jax.jit(partial(hgcn._lp_step_impl, model, opt,
+                           split.graph.num_nodes, constrain=lambda x: x))
     for _ in range(steps):
-        state, loss = hgcn.train_step_lp(
-            model, opt, split.graph.num_nodes, state, ga, train_pos)
+        state, loss = step(state, ga, train_pos)
     return state, loss
 
 

@@ -3,7 +3,10 @@ program (``jax.named_scope`` is HLO metadata): forward and, for the two
 scopes whose backward is most of the step, under ``transpose(`` too.
 The scope round a ``custom_vjp`` call follows into its backward rule on
 the installed jax, so ``nn/scatter.py``'s rules open none of their own
-(a second ``aggregate`` would read ``aggregate/aggregate``)."""
+(a second ``aggregate`` would read ``aggregate/aggregate``), and
+`nn.edge_dist.pair_sqdist`'s sort, gathers and kernel call land under
+``pair_dist`` with ``transpose(`` in the path, where
+``pair_dist_bwd_ms`` reads them."""
 
 import re
 
@@ -64,3 +67,12 @@ def test_kernels_sit_inside_aggregate_and_nothing_is_scoped_twice(
               if any(f"/{k}" in p for k in kernels)]
     assert inside and all("/aggregate/" in p for p in inside), inside
     assert not [p for p in paths[use_att] if "aggregate/aggregate" in p]
+
+
+@pytest.mark.parametrize("use_att", [False, True], ids=["mean", "attention"])
+def test_decoder_backward_sits_under_pair_dist(paths, use_att):
+    """The sorted VJP's kernel call, hence the rule it is called from."""
+    inside = [p for p in paths[use_att] if "pair_scatter_sum" in p]
+    assert inside, sorted(paths[use_att])
+    assert all(p.startswith("transpose(") and "/pair_dist/" in p
+               and "pair_dist/pair_dist" not in p for p in inside), inside
