@@ -507,7 +507,6 @@ def run_hgcn(run: RunConfig, overrides: dict):
             return {"workload": "hgcn", "task": "lp", **data,
                     "steps": int(state.step), "sampled": True, **res}
         model, opt, state = hgcn.init_lp(cfg, split.graph, seed=run.seed)
-        ga = None  # mesh runs place the whole graph only for the eval
         if mesh is not None:
             from hyperspace_tpu.parallel import multihost as mh
 
@@ -523,9 +522,11 @@ def run_hgcn(run: RunConfig, overrides: dict):
             # owns N/ndev nodes and their incoming edges (mean AND
             # attention aggregation; the receiver partition keeps the
             # attention softmax shard-local)
-            step, state, ga_s = hgcn.make_node_sharded_step_lp(
+            # the node-sharded graph serves the final evaluation too: one
+            # device need not hold the whole graph, nor the parameters
+            step, state, ga = hgcn.make_node_sharded_step_lp(
                 model, opt, num_nodes, mesh, state, split)
-            stepper, spc = _chunked(run, lambda st: step(st, ga_s, train_pos))
+            stepper, spc = _chunked(run, lambda st: step(st, ga, train_pos))
         else:
             ga = hgcn._device_graph(split.graph)
             train_pos = jnp.asarray(split.train_pos)
@@ -537,8 +538,7 @@ def run_hgcn(run: RunConfig, overrides: dict):
                                   data=data)
         with _eval_span():
             res = {"loss": float(loss), **hgcn.evaluate_lp(
-                model, _eval_params(state.params, mesh), split, "test",
-                ga=ga)}
+                model, state.params, split, "test", ga=ga)}
     else:
         tr, va, te = G.node_split_masks(num_nodes, seed=run.seed)
         g = G.prepare(edges, num_nodes, x, labels=labels, num_classes=ncls,

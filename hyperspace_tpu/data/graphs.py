@@ -452,8 +452,22 @@ def _read_csv(path: str, dtype):
         return np.loadtxt(path, delimiter=",", dtype=dtype)
 
 
-def load_ogbn_arxiv(root: str):
-    """OGB extracted-csv layout (``raw/edge.csv``, ``raw/node-feat.csv``...)."""
+# the OGB node-property datasets :func:`load_graph` reads from disk, each
+# with its published shape.  ``ogbn-mag-cites`` is ogbn-mag's
+# paper-cites-paper relation alone: the homogeneous projection its plain
+# GCN / GraphSAGE baselines train on (Hu et al. 2020, arxiv 2005.00687)
+OGB_SHAPES = {
+    "ogbn-arxiv": dict(num_nodes=169_343, num_edges=1_166_243,
+                       feat_dim=128, num_classes=40),
+    "ogbn-mag-cites": dict(num_nodes=736_389, num_edges=5_416_271,
+                           feat_dim=128, num_classes=349),
+}
+
+
+def load_ogb_csv(root: str):
+    """OGB extracted-csv layout (``raw/edge.csv``, ``raw/node-feat.csv``,
+    ``raw/node-label.csv``), whichever dataset of :data:`OGB_SHAPES` it
+    holds."""
     raw = os.path.join(root, "raw")
     edges = _read_csv(os.path.join(raw, "edge.csv"), np.int64)
     x = np.ascontiguousarray(
@@ -462,9 +476,55 @@ def load_ogbn_arxiv(root: str):
     return edges, x, labels.astype(np.int32).reshape(-1), int(labels.max()) + 1
 
 
+# from this many values on, a csv is formatted by several processes (the
+# published ogbn-mag feature matrix is 94 M floats, 100 s in one; the
+# arxiv shape's 21.7 M stay in one)
+_PARALLEL_CSV_MIN_VALUES = 32_000_000
+# one worker of :func:`_write_csv_parallel`: argv = chunk.npy, part.csv.
+# A plain interpreter that imports numpy and pandas alone: it never
+# re-imports the caller's ``__main__`` (as a spawned pool would) and
+# never touches an accelerator the caller holds
+_CSV_WORKER = (
+    "import sys, numpy, pandas; "
+    "pandas.DataFrame(numpy.load(sys.argv[1])).to_csv("
+    "sys.argv[2], header=False, index=False, float_format='%.6g')")
+
+
+def _write_csv_parallel(path: str, a: np.ndarray, workers: int) -> None:
+    """The bytes pandas writes for ``a`` whole, from ``workers`` row
+    chunks each formatted by a process of its own (a row's text does not
+    depend on its neighbours) and joined in order."""
+    import shutil
+    import subprocess
+    import sys
+    import tempfile
+
+    with tempfile.TemporaryDirectory(dir=os.path.dirname(path)) as tmp:
+        procs = []
+        try:
+            for i, chunk in enumerate(np.array_split(a, workers)):
+                src = os.path.join(tmp, f"{i}.npy")
+                np.save(src, chunk)
+                procs.append(subprocess.Popen(
+                    [sys.executable, "-c", _CSV_WORKER, src,
+                     os.path.join(tmp, f"{i}.csv")]))
+            with open(path, "wb") as out:
+                for i, proc in enumerate(procs):
+                    if proc.wait() != 0:
+                        raise RuntimeError(
+                            f"csv worker {i} of {path} exited "
+                            f"{proc.returncode}")
+                    with open(os.path.join(tmp, f"{i}.csv"), "rb") as part:
+                        shutil.copyfileobj(part, out)
+        finally:
+            for proc in procs:
+                if proc.poll() is None:
+                    proc.kill()
+
+
 def write_ogb_csv_layout(root: str, edges: np.ndarray, x: np.ndarray,
                          labels: np.ndarray) -> None:
-    """Write a graph to the OGB extracted-csv layout ``load_ogbn_arxiv``
+    """Write a graph to the OGB extracted-csv layout ``load_ogb_csv``
     reads (``raw/{edge,node-feat,node-label}.csv``) — the disk end of the
     disk → load → prepare → train pipeline."""
     raw = os.path.join(root, "raw")
@@ -474,6 +534,10 @@ def write_ogb_csv_layout(root: str, edges: np.ndarray, x: np.ndarray,
         try:  # pandas C writer: ~10x np.savetxt on the 21.7M-float feat
             import pandas as pd
 
+            workers = min(8, os.cpu_count() or 1)
+            if a.size >= _PARALLEL_CSV_MIN_VALUES and workers > 1:
+                _write_csv_parallel(path, a, workers)
+                return
             pd.DataFrame(a).to_csv(path, header=False, index=False,
                                    float_format="%.6g")
         except ImportError:
@@ -663,26 +727,24 @@ def community_power_law_graph(
     return edges.astype(np.int64), x, labels, num_classes
 
 
-def ensure_arxiv_scale_dataset(root: str | None = None, seed: int = 0,
-                               **graph_kw) -> str:
-    """Materialize :func:`community_power_law_graph` at its default, the
-    published ogbn-arxiv shape (169,343 nodes, 1,166,243 directed edges,
-    128 features, 40 classes), on disk in the OGB extracted-csv layout
-    that ``load_graph("ogbn-arxiv", root)`` reads (~200 MB; generated
-    once per ``root``, default ``<repo>/.cache/arxiv-synth``).  Returns
-    ``root``.  ``graph_kw`` go to the generator: tests shrink the graph
-    with them, everything else runs the default shape.
+def ensure_ogb_scale_dataset(root: str, name: str, seed: int = 0,
+                             **graph_kw) -> str:
+    """Materialize :func:`community_power_law_graph` at the published
+    shape of the OGB dataset ``name`` (:data:`OGB_SHAPES`) on disk, in
+    the extracted-csv layout that ``load_graph(name, root)`` reads
+    (~200 MB at the ogbn-arxiv shape, ~0.9 GB at ogbn-mag's citation
+    relation; generated once per ``root``).  Returns ``root``.
+    ``graph_kw`` go to the generator over the published counts: tests
+    shrink the graph with them, everything else runs the shape as
+    published.
 
     The stand-in for the real download wherever there is no network:
     the trainer then runs at the real size through the real disk →
-    ``load_ogbn_arxiv`` → ``prepare`` pipeline (``source: "disk"``)
+    ``load_ogb_csv`` → ``prepare`` pipeline (``source: "disk"``)
     instead of on :func:`load_graph`'s small synthetic hierarchy.
     """
     import shutil
 
-    if root is None:
-        root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
-                            ".cache", "arxiv-synth")
     root = os.path.abspath(root)
     if not os.path.exists(os.path.join(root, "raw", "edge.csv")):
         # write into a temp sibling and rename whole: an interrupted
@@ -690,13 +752,33 @@ def ensure_arxiv_scale_dataset(root: str | None = None, seed: int = 0,
         # edge.csv existence sentinel would treat as complete
         tmp = root + ".tmp"
         shutil.rmtree(tmp, ignore_errors=True)
-        edges, x, labels, _ = community_power_law_graph(seed=seed,
-                                                        **graph_kw)
+        edges, x, labels, _ = community_power_law_graph(
+            seed=seed, **{**OGB_SHAPES[name], **graph_kw})
         write_ogb_csv_layout(tmp, edges, x, labels)
         os.makedirs(os.path.dirname(root), exist_ok=True)
         shutil.rmtree(root, ignore_errors=True)
         os.replace(tmp, root)
     return root
+
+
+def ensure_arxiv_scale_dataset(root: str | None = None, seed: int = 0,
+                               **graph_kw) -> str:
+    """:func:`ensure_ogb_scale_dataset` for ``ogbn-arxiv`` (169,343
+    nodes, 1,166,243 directed edges, 128 features, 40 classes); the
+    default ``root`` is ``<repo>/.cache/arxiv-synth``."""
+    if root is None:
+        root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
+                            ".cache", "arxiv-synth")
+    return ensure_ogb_scale_dataset(root, "ogbn-arxiv", seed, **graph_kw)
+
+
+def ensure_magcite_scale_dataset(root: str, seed: int = 0,
+                                 **graph_kw) -> str:
+    """:func:`ensure_ogb_scale_dataset` for ``ogbn-mag-cites`` (736,389
+    paper nodes, 5,416,271 directed citation edges, 128 features, 349
+    venue classes): 4.35 times ogbn-arxiv's nodes, the graph of
+    ``configs/hgcn_magcite_lp.yaml``, sized for a four-chip host."""
+    return ensure_ogb_scale_dataset(root, "ogbn-mag-cites", seed, **graph_kw)
 
 
 def node_split_masks(num_nodes: int, train_frac=0.6, val_frac=0.2, seed: int = 0):
@@ -716,10 +798,10 @@ def node_split_masks(num_nodes: int, train_frac=0.6, val_frac=0.2, seed: int = 0
 def load_graph(name: str, root: str | None = None, **synth_kw):
     """Dispatch: the dataset's files under ``root`` if they exist, else
     a SMALL synthetic hierarchy that stands in for tests and demos —
-    2,048 nodes for ``cora``, 16,384 for ``ogbn-arxiv`` — same feature
-    and class counts as the named dataset, NOT its size (for the
-    published arxiv shape without a download, write
-    :func:`ensure_arxiv_scale_dataset` and pass its root).
+    2,048 nodes for ``cora``, 16,384 for the OGB datasets
+    (:data:`OGB_SHAPES`) — same feature and class counts as the named
+    dataset, NOT its size (for a published OGB shape without a
+    download, write :func:`ensure_ogb_scale_dataset` and pass its root).
 
     Returns (edges, x, labels, num_classes, source) where source is
     "disk" or "synthetic"; callers record it, with the node and edge
@@ -728,12 +810,14 @@ def load_graph(name: str, root: str | None = None, **synth_kw):
     if root is not None:
         if name == "cora" and os.path.exists(os.path.join(root, "cora.content")):
             return (*load_cora(root), "disk")
-        if name == "ogbn-arxiv" and os.path.exists(
+        if name in OGB_SHAPES and os.path.exists(
             os.path.join(root, "raw", "edge.csv")
         ):
-            return (*load_ogbn_arxiv(root), "disk")
+            return (*load_ogb_csv(root), "disk")
     defaults = {"cora": dict(num_nodes=2048, feat_dim=64, num_classes=7),
-                "ogbn-arxiv": dict(num_nodes=16384, feat_dim=128, num_classes=40)}
+                **{k: dict(num_nodes=16384, feat_dim=v["feat_dim"],
+                           num_classes=v["num_classes"])
+                   for k, v in OGB_SHAPES.items()}}
     kw = {**defaults.get(name, {}), **synth_kw}
     return (*synthetic_hierarchy(**kw), "synthetic")
 

@@ -309,7 +309,14 @@ def init_lp(cfg: HGCNConfig, g: graph_data.Graph, seed: int = 0):
     model = HGCNLinkPred(cfg)
     key = jax.random.PRNGKey(seed)
     k_init, key = jax.random.split(key)
-    dg = _device_graph(g)
+    # flax draws a parameter from its path and its shape, and the only
+    # shape the graph gives is the feature width: initialise over a
+    # two-node graph of that width.  The eager forward of ``model.init``
+    # over ``g`` itself would put the whole graph on one device, which a
+    # graph made for a mesh does not fit
+    dg = _device_graph(graph_data.prepare(
+        np.array([[0, 1]]), 2, np.zeros((2, g.x.shape[1]), np.float32),
+        cache=False))
     dummy_pairs = jnp.zeros((2, 2), jnp.int32)
     params = model.init({"params": k_init}, dg, dummy_pairs)["params"]
     opt = make_optimizer(cfg)

@@ -47,6 +47,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hyperspace_tpu.data import graphs as graph_data
 from hyperspace_tpu.kernels.segment import build_csr_plan, csr_segment_sum
+from hyperspace_tpu.telemetry import registry
+from hyperspace_tpu.telemetry.trace import span
 
 _BN = 128   # node-block rows (must match kernels.segment._BN tiling)
 _BK = 512   # edge-chunk size (must match kernels.segment._BK)
@@ -247,14 +249,15 @@ def partition_graph(g: graph_data.Graph, ndev: int,
     send_idx = None
     halo_dists: tuple = ()
     halo_sizes: tuple = ()
+    # need[k][j]: the rows of shard j that shard k's senders name
+    need = [[np.zeros(0, np.int64)] * ndev for _ in range(ndev)]
+    for k in range(ndev if ndev > 1 else 0):
+        sk = s[bounds[k]:bounds[k + 1]]
+        owner = sk // n_shard
+        for j in np.unique(owner):
+            if int(j) != k:
+                need[k][int(j)] = np.unique(sk[owner == j])
     if halo is not False and ndev > 1:
-        need = [[np.zeros(0, np.int64)] * ndev for _ in range(ndev)]
-        for k in range(ndev):
-            sk = s[bounds[k]:bounds[k + 1]]
-            owner = sk // n_shard
-            for j in np.unique(owner):
-                if int(j) != k:
-                    need[k][int(j)] = np.unique(sk[owner == j])
         # per-distance max receive count: at distance d, shard k
         # receives need[k][(k - d) % ndev] and sends need[(k+d)%ndev][k]
         h_d = {}
@@ -330,9 +333,45 @@ def partition_graph(g: graph_data.Graph, ndev: int,
                     ext[sel] = base + np.searchsorted(need[k][j], sk[sel])
                 senders[k, :hi - lo] = ext
                 senders[k, hi - lo:] = 0       # padding edges carry w = 0
+    _record_partition(counts, e_s, n_shard, need, use_halo, halo_kind,
+                      send_idx, halo_sizes)
     return HostPartition(x, senders, recv, w_fwd, w_bwd, plan, n, n_shard,
                          send_idx, use_halo, halo_kind, halo_dists,
                          halo_sizes)
+
+
+# the exchange schedule as the gauge ``node_shard/schedule`` codes it
+SCHEDULE_CODES = {"all-gather": 0, "a2a": 1, "ppermute": 2}
+
+
+def _record_partition(counts, e_s, n_shard, need, use_halo, halo_kind,
+                      send_idx, halo_sizes) -> None:
+    """The partition's shape as gauges (docs/observability.md): the rows
+    a shard needs from the others (``need[k][j]``), the rows the chosen
+    schedule moves to it, how evenly the edges fell and how much of the
+    edge arrays is padding.  Rows are counted per layer and pass: the
+    forward exchanges ``h``, the backward the same rows of its
+    cotangent."""
+    ndev = len(need)
+    need = [sum(len(rows) for rows in of_k) for of_k in need]
+    if not use_halo:
+        moved = n_shard * (ndev - 1)
+    elif halo_kind == "a2a":
+        moved = send_idx.shape[2] * (ndev - 1)
+    else:
+        moved = int(sum(halo_sizes))
+    for name, value in (
+            ("halo_rows_need_max", max(need)),
+            ("halo_rows_need_sum", sum(need)),
+            # every schedule pads each shard's delivery to the same size
+            ("halo_rows_moved_max", moved),
+            ("halo_rows_moved_sum", moved * ndev),
+            ("edges_max", int(counts.max())),
+            ("edges_min", int(counts.min())),
+            ("edge_pad_share", 1.0 - float(counts.sum()) / (ndev * e_s)),
+            ("schedule", SCHEDULE_CODES[halo_kind if use_halo
+                                        else "all-gather"])):
+        registry.set_gauge("node_shard/" + name, value)
 
 
 def graph_shardings(g: NodeShardedGraph) -> NodeShardedGraph:
@@ -374,7 +413,9 @@ def shard_graph(g: graph_data.Graph, mesh: Mesh,
     """partition_graph + to_device_sharded in one call."""
     axes = data_axes(mesh) if axes is None else axes
     ndev = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
-    return to_device_sharded(partition_graph(g, ndev, halo=halo), mesh, axes)
+    with span("partition"):
+        return to_device_sharded(partition_graph(g, ndev, halo=halo), mesh,
+                                 axes)
 
 
 # --- the sharded aggregation --------------------------------------------------
@@ -419,6 +460,19 @@ def _halo_rows(vals_l, si_l, axes, kind, dists, sizes, ndev):
     return jnp.concatenate(parts, axis=0)
 
 
+def _exchange(vals_l, si_l, axes, kind, dists, sizes, ndev):
+    """The rows this shard's ``senders`` index, from its own block
+    ``vals_l``: the all-gathered table without ``si_l``, else
+    ``concat(vals_l, halo rows)``.  Every schedule's collective and its
+    send gather run under the scope ``halo_exchange``, in the forward
+    and (the involution backward calls this again) in the backward."""
+    with jax.named_scope("halo_exchange"):
+        if si_l is None:
+            return jax.lax.all_gather(vals_l, axes, axis=0, tiled=True)
+        halo = _halo_rows(vals_l, si_l, axes, kind, dists, sizes, ndev)
+        return jnp.concatenate([vals_l, halo], axis=0)
+
+
 def _gather_aggregate(mesh, axes, n_shard, h, w, senders, recv, pb, pc, pf,
                       send_idx=None, kind="a2a", dists=(), sizes=()):
     """Collective + local planned aggregation of this shard's edges.
@@ -433,31 +487,22 @@ def _gather_aggregate(mesh, axes, n_shard, h, w, senders, recv, pb, pc, pf,
     directions.
     """
     spec = P(axes, None)
-    if send_idx is None:
-        def body(h_l, w_l, s_l, r_l, pb_l, pc_l, pf_l):
-            h_full = jax.lax.all_gather(h_l, axes, axis=0, tiled=True)
-            msgs = w_l[0][:, None] * h_full[s_l[0]]
-            return _local_segsum(msgs, r_l[0], pb_l[0], pc_l[0], pf_l[0],
-                                 n_shard)
-
-        return shard_map(
-            body, mesh=mesh,
-            in_specs=(spec,) * 7, out_specs=spec, check_vma=False,
-        )(h, w, senders, recv, pb, pc, pf)
-
     ndev = _mesh_extent(mesh, axes)
 
-    def body_halo(h_l, w_l, s_l, r_l, pb_l, pc_l, pf_l, si_l):
-        halo = _halo_rows(h_l, si_l[0], axes, kind, dists, sizes, ndev)
-        h_ext = jnp.concatenate([h_l, halo], axis=0)
-        msgs = w_l[0][:, None] * h_ext[s_l[0]]
+    def body(h_l, w_l, s_l, r_l, pb_l, pc_l, pf_l, si_l=None):
+        table = _exchange(h_l, None if si_l is None else si_l[0], axes,
+                          kind, dists, sizes, ndev)
+        msgs = w_l[0][:, None] * table[s_l[0]]
         return _local_segsum(msgs, r_l[0], pb_l[0], pc_l[0], pf_l[0],
                              n_shard)
 
+    args = (h, w, senders, recv, pb, pc, pf)
+    if send_idx is not None:
+        args += (send_idx,)
     return shard_map(
-        body_halo, mesh=mesh,
-        in_specs=(spec,) * 8, out_specs=spec, check_vma=False,
-    )(h, w, senders, recv, pb, pc, pf, send_idx)
+        body, mesh=mesh,
+        in_specs=(spec,) * len(args), out_specs=spec, check_vma=False,
+    )(*args)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
@@ -555,8 +600,9 @@ def node_sharded_att_aggregate(
         return (num / jnp.maximum(den, 1e-15)[:, None])
 
     def body(h_l, as_l, ar_l, senders, recv, w_f):
-        h_full = jax.lax.all_gather(h_l, axes, axis=0, tiled=True)
-        as_full = jax.lax.all_gather(as_l, axes, axis=0, tiled=True)
+        with jax.named_scope("halo_exchange"):
+            h_full = jax.lax.all_gather(h_l, axes, axis=0, tiled=True)
+            as_full = jax.lax.all_gather(as_l, axes, axis=0, tiled=True)
         s = senders[0]
         mask = w_f[0] > 0  # static edge-validity mask (padding has w=0)
         return _weights_and_agg(as_full[s], ar_l, recv[0], mask, h_full[s])
@@ -570,11 +616,8 @@ def node_sharded_att_aggregate(
         s = senders[0]
         mask = w_f[0] > 0
         ha_l = jnp.concatenate([h_l, as_l[:, None].astype(h_l.dtype)], 1)
-        halo_rows = _halo_rows(ha_l, si_l[0], axes, g.halo_kind,
-                               g.halo_dists, g.halo_sizes,
-                               _mesh_extent(mesh, axes))
-        ha_ext = jnp.concatenate([ha_l, halo_rows], axis=0)
-        picked = ha_ext[s]
+        picked = _exchange(ha_l, si_l[0], axes, g.halo_kind, g.halo_dists,
+                           g.halo_sizes, _mesh_extent(mesh, axes))[s]
         return _weights_and_agg(picked[:, -1], ar_l, recv[0], mask,
                                 picked[:, :-1])
 

@@ -1,6 +1,6 @@
 """Real-data loader fixture tests (VERDICT r2 next #4).
 
-The on-disk parsers (`load_cora`, `load_ogbn_arxiv`, the WordNet closure
+The on-disk parsers (`load_cora`, `load_ogb_csv`, the WordNet closure
 TSV) had never executed before this file: every quality claim ultimately
 refers to these datasets, so a parse bug would invalidate the story the
 day real data appears.  Each fixture is a hand-written miniature of the
@@ -106,9 +106,9 @@ def arxiv_root(tmp_path):
     return str(tmp_path), edges, feats, labels
 
 
-def test_load_ogbn_arxiv_parses(arxiv_root):
+def test_load_ogb_csv_parses(arxiv_root):
     root, edges_w, feats_w, labels_w = arxiv_root
-    edges, x, labels, ncls = G.load_ogbn_arxiv(root)
+    edges, x, labels, ncls = G.load_ogb_csv(root)
     np.testing.assert_array_equal(edges, edges_w)
     np.testing.assert_allclose(x, feats_w.astype(np.float32), atol=1e-6)
     np.testing.assert_array_equal(labels, labels_w)

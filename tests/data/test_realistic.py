@@ -39,7 +39,7 @@ def test_ogb_csv_roundtrip(tmp_path):
         sub_size=40, seed=1)
     root = str(tmp_path / "ds")
     G.write_ogb_csv_layout(root, edges, x, labels)
-    e2, x2, l2, k2 = G.load_ogbn_arxiv(root)
+    e2, x2, l2, k2 = G.load_ogb_csv(root)
     np.testing.assert_array_equal(e2, edges)
     np.testing.assert_array_equal(l2, labels)
     assert k2 == labels.max() + 1
@@ -103,3 +103,88 @@ def test_apply_locality_order_rejects_unknown_method():
     edges, x, labels, k = _small_graph()
     with pytest.raises(ValueError):
         G.apply_locality_order(edges, x, labels, method="sorted")
+
+
+# --- two OGB shapes, one loader, one generator -----------------------------------
+
+TINY = dict(num_nodes=1500, num_edges=9000, num_classes=6, feat_dim=16,
+            sub_size=60)
+# sha256 of the csv files ensure_arxiv_scale_dataset(seed=0, **TINY) wrote
+# and of the arrays the default generator call made before ogbn-mag-cites
+# came in (PR 28's parent commit): the arxiv cells' data is those bytes
+PARENT_TINY_CSV = {
+    "edge.csv":
+        "0c7d54a06068303cf86563edbe52bf7937b3ad04c86abba0ee52cecd973014da",
+    "node-feat.csv":
+        "189da380abce3ded9a48b29267320cc8ab5f0c1e295bb153f3c330cf79416aae",
+    "node-label.csv":
+        "f1ca793b0ce7eb01735b017b8fdf4520b88f0d7844232483bdde6ceae75dbe82",
+}
+PARENT_DEFAULT_ARRAYS = (
+    "6ff76ee55f554c45349771cb6d28cd92660b6bbd309da8e44108eaa6e8049c39",
+    "5c6d4e9dd2e603c40b516eebe19c83a58419845c41392cd799a5670e41cb68f8",
+    "5b0fc406491f2767e1fc3609962369e29eca445f0f1186bfcde932fd93ef9cbf",
+)
+
+
+def _sha(data: bytes) -> str:
+    import hashlib
+
+    return hashlib.sha256(data).hexdigest()
+
+
+def test_magcite_generator_and_loader_at_tiny_counts(tmp_path):
+    """The second OGB name goes through the one generator and the one csv
+    loader: generated at the counts asked for, loaded as source "disk";
+    without files the stand-in has the published feature and class
+    counts, not the size."""
+    import inspect
+
+    shape = G.OGB_SHAPES["ogbn-mag-cites"]
+    assert (shape["num_nodes"], shape["num_edges"], shape["feat_dim"],
+            shape["num_classes"]) == (736_389, 5_416_271, 128, 349)
+    root = G.ensure_magcite_scale_dataset(str(tmp_path / "mag"), seed=0,
+                                          **TINY)
+    edges, x, labels, ncls, source = G.load_graph("ogbn-mag-cites", root)
+    assert source == "disk" and edges.shape == (9000, 2)
+    assert x.shape == (1500, 16) and labels.shape == (1500,)
+    assert ncls == labels.max() + 1 <= 6
+    _, x_s, _, ncls_s, source = G.load_graph("ogbn-mag-cites", None)
+    assert source == "synthetic" and x_s.shape == (16384, 128)
+    assert ncls_s == 349
+    # the generator's own defaults are still the arxiv shape
+    sig = inspect.signature(G.community_power_law_graph).parameters
+    assert {k: sig[k].default for k in G.OGB_SHAPES["ogbn-arxiv"]} == (
+        G.OGB_SHAPES["ogbn-arxiv"])
+
+
+def test_arxiv_default_is_unchanged_bit_for_bit(tmp_path):
+    import os
+
+    root = G.ensure_arxiv_scale_dataset(str(tmp_path / "arxiv"), seed=0,
+                                        **TINY)
+    for name, want in PARENT_TINY_CSV.items():
+        with open(os.path.join(root, "raw", name), "rb") as f:
+            assert _sha(f.read()) == want, name
+    # and the arrays of the published shape, as the generic entry asks
+    # the generator for them
+    edges, x, labels, _ = G.community_power_law_graph(
+        seed=0, **G.OGB_SHAPES["ogbn-arxiv"])
+    assert (_sha(edges.tobytes()), _sha(x.tobytes()),
+            _sha(labels.tobytes())) == PARENT_DEFAULT_ARRAYS
+
+
+def test_parallel_csv_writer_writes_the_serial_bytes(tmp_path, monkeypatch):
+    """Past ``_PARALLEL_CSV_MIN_VALUES`` the feature matrix is formatted
+    by several processes; the file is the one a single process writes."""
+    import os
+
+    edges, x, labels, _ = G.community_power_law_graph(seed=2, **TINY)
+    G.write_ogb_csv_layout(str(tmp_path / "serial"), edges, x, labels)
+    monkeypatch.setattr(G, "_PARALLEL_CSV_MIN_VALUES", x.size)
+    monkeypatch.setattr(os, "cpu_count", lambda: 3)
+    G.write_ogb_csv_layout(str(tmp_path / "parallel"), edges, x, labels)
+    for name in PARENT_TINY_CSV:
+        with open(tmp_path / "serial" / "raw" / name, "rb") as a, open(
+                tmp_path / "parallel" / "raw" / name, "rb") as b:
+            assert a.read() == b.read(), name

@@ -97,7 +97,12 @@ def interp_kernels(monkeypatch):
 def _mesh_or_skip(axes):
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
-    return make_mesh(axes)
+    n = int(np.prod(list(axes.values())))
+    return make_mesh(axes, devices=jax.devices()[:n])
+
+
+# auto_mesh's pick for a four-chip host (tp=2, cli.train's default)
+HOST4 = {"data": 2, "model": 2}
 
 
 @pytest.mark.parametrize("axes", [
@@ -157,8 +162,29 @@ def test_aggregate_gradient_matches_dense(rng):
 # --- full train-step equivalence ----------------------------------------------
 
 
-@pytest.mark.parametrize("axes", [
-    {"data": 8},
+def _reference_steps(state, split, train_pos, cfg, steps):
+    """The plain reference (benchmark/reference/hgcn.py: one device, the
+    whole graph, no kernels, no shards) through the same steps from the
+    program's initial parameters and key."""
+    from benchmark.drivers.train_fullgraph import _get, program_names
+    from benchmark.reference import hgcn as ref
+
+    names = program_names(state.params)
+    weights = {k: np.asarray(_get(state.params, path))
+               for k, path in names.items()}
+    model = {"hidden_dims": list(cfg.hidden_dims), "use_att": cfg.use_att,
+             "agg_dtype": None, "decoder_dtype": None, "lr": cfg.lr,
+             "weight_decay": cfg.weight_decay, "clip_norm": cfg.clip_norm,
+             "neg_per_pos": cfg.neg_per_pos}
+    n = split.graph.num_nodes
+    want = ref.train_steps(weights, state.key, split.graph.x,
+                           ref.message_graph(split.train_pos, n),
+                           np.asarray(train_pos), model, steps=steps)
+    return names, weights, want
+
+
+@pytest.mark.parametrize("axes,halo", [
+    ({"data": 8}, "auto"),
     # dp×tp: red from PR 3 to PR 8 under an (incorrect) "partitioner
     # reduction-order drift" diagnosis.  PR 9 root-caused the real
     # op-level cause — the jax of that time miscompiled `concatenate`
@@ -166,9 +192,13 @@ def test_aggregate_gradient_matches_dense(rng):
     # test_gspmd_concat_under_subset_constraint below) — and the LP
     # step avoids the pattern (hgcn.split_pair_logits), so dp×tp is
     # exact and gates like every other mesh.
-    {"data": 4, "model": 2},
+    ({"data": 4, "model": 2}, "auto"),
+    # the four-chip host's mesh under every exchange schedule
+    (HOST4, False), (HOST4, "a2a"), (HOST4, "ppermute"),
 ])
-def test_node_sharded_lp_matches_single_device(axes):
+def test_node_sharded_lp_matches_single_device_and_reference(axes, halo):
+    from benchmark.drivers.train_fullgraph import _adam_mu, _get
+
     mesh = _mesh_or_skip(axes)
     cfg, split, _ = _setup(num_nodes=192)
     n = split.graph.num_nodes
@@ -176,6 +206,7 @@ def test_node_sharded_lp_matches_single_device(axes):
     train_pos = jnp.asarray(hgcn.round_up_pairs(split.train_pos, mesh))
 
     model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
+    names, start, want = _reference_steps(state, split, train_pos, cfg, steps)
     ga = G.to_device(split.graph)
     for _ in range(steps):
         state, loss_single = hgcn.train_step_lp(
@@ -183,9 +214,16 @@ def test_node_sharded_lp_matches_single_device(axes):
 
     model2, opt2, state2 = hgcn.init_lp(cfg, split.graph, seed=0)
     step, state2, nsg = hgcn.make_node_sharded_step_lp(
-        model2, opt2, n, mesh, state2, split)
-    for _ in range(steps):
+        model2, opt2, n, mesh, state2, split, halo=halo)
+    if halo != "auto":
+        assert (nsg.halo_kind if nsg.halo else False) == halo
+    losses, first_grad = [], None
+    for i in range(steps):
         state2, loss_sharded = step(state2, nsg, train_pos)
+        losses.append(float(loss_sharded))
+        if i == 0:  # Adam's first moment after one step is 0.1 g
+            first_grad = jax.tree_util.tree_map(
+                lambda m: 10.0 * np.asarray(m), _adam_mu(state2.opt_state))
 
     np.testing.assert_allclose(float(loss_sharded), float(loss_single),
                                rtol=1e-4, atol=1e-5)
@@ -193,6 +231,15 @@ def test_node_sharded_lp_matches_single_device(axes):
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5),
         state.params, state2.params)
+    # the reference: each loss, the first gradient, the three-step change
+    np.testing.assert_allclose(losses, want["losses"], rtol=1e-4)
+    for name, path in names.items():
+        np.testing.assert_allclose(_get(first_grad, path),
+                                   want["grads"][name], rtol=2e-3, atol=2e-6)
+        moved = np.asarray(_get(state2.params, path)) - start[name]
+        np.testing.assert_allclose(
+            np.linalg.norm(moved), want["change_norms"][name], rtol=2e-3,
+            atol=1e-6)
 
 
 def test_gspmd_concat_under_subset_constraint():
@@ -548,3 +595,73 @@ def test_eval_params_are_gathered_onto_one_device():
         assert a.devices() == {jax.local_devices()[0]}
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert _eval_params(state.params, None) is state.params
+
+
+# --- the shares and the whole; the exchange's scope -------------------------------
+
+
+def test_the_shards_aggregates_add_up_to_the_unsharded_aggregate(rng):
+    """On the host partition alone, no device in it: what each shard
+    computes from its own edge list over the whole table (forward with
+    ``w_fwd``; backward, through the involution, the same sum of the
+    cotangent with ``w_bwd``), each node's row taken once from the shard
+    that owns it and the padding rows dropped, is the unsharded mean
+    aggregate and its transpose."""
+    _, split, _ = _setup(num_nodes=192)
+    g = split.graph
+    n, ndev = g.num_nodes, 2
+    hp = NS.partition_graph(g, ndev, halo=False)
+    n_pad = hp.n_shard * ndev
+    h = np.zeros((n_pad, 8))
+    h[:n] = rng.standard_normal((n, 8))
+    cot = np.zeros((n_pad, 8))
+    cot[:n] = rng.standard_normal((n, 8))
+
+    def shares(table, w):
+        out = np.zeros((n_pad, 8))
+        for k in range(ndev):
+            rows = k * hp.n_shard + hp.recv[k]
+            np.add.at(out, rows, w[k][:, None] * table[hp.senders[k]])
+        return out[:n]
+
+    mask = np.asarray(g.edge_mask)
+    s, r = np.asarray(g.senders)[mask], np.asarray(g.receivers)[mask]
+    w = 1.0 / np.maximum(np.asarray(g.deg), 1.0)[r]
+    whole = np.zeros((n, 8))
+    np.add.at(whole, r, w[:, None] * h[s])
+    np.testing.assert_allclose(shares(h, hp.w_fwd), whole, rtol=1e-6)
+    transposed = np.zeros((n, 8))
+    np.add.at(transposed, s, w[:, None] * cot[r])
+    np.testing.assert_allclose(shares(cot, hp.w_bwd), transposed, rtol=1e-6)
+    # every real edge sits in exactly one shard's list
+    assert int((hp.w_fwd > 0).sum()) == int(mask.sum())
+
+
+@pytest.mark.parametrize("halo", [False, "a2a", "ppermute"])
+def test_halo_exchange_scope_reaches_the_backward(halo):
+    """The exchange's collective sits under the scope ``halo_exchange``
+    in the forward and, since the involution backward is the same
+    function called from the ``custom_vjp`` rule, under ``transpose(``
+    too: where ``halo_exchange_time`` reads both."""
+    import re
+
+    mesh = _mesh_or_skip(HOST4)
+    cfg, split, _ = _setup(num_nodes=192)
+    n = split.graph.num_nodes
+    model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
+    step, state, nsg = hgcn.make_node_sharded_step_lp(
+        model, opt, n, mesh, state, split, halo=halo)
+    train_pos = jnp.asarray(hgcn.round_up_pairs(split.train_pos, mesh))
+    # the compiled program's op_name is the path a device profile shows
+    text = step.lower(state, nsg, train_pos).compile().as_text()
+    paths = set(re.findall(r'op_name="jit\([^)]*\)/([^"]*)"', text))
+    collective = {False: "all_gather", "a2a": "all_to_all",
+                  "ppermute": "ppermute"}[halo]
+    inside = [p for p in paths if p.endswith("/" + collective)]
+    assert inside and all(p.endswith("/aggregate/shard_map/halo_exchange/"
+                                     + collective) for p in inside), inside
+    for layer in ("conv0", "conv1"):
+        for backward in (False, True):
+            assert any(p.startswith("transpose(") == backward
+                       and f"/{layer}/aggregate/" in p for p in inside), (
+                layer, backward, inside)
