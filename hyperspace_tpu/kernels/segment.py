@@ -419,6 +419,86 @@ def csr_segment_reduce_1d(
     return red[:num_segments].astype(values.dtype)
 
 
+def _first_visit_of_chunk(pc: jax.Array) -> jax.Array:
+    """1 on the plan item that visits its chunk first: chunk indices are
+    non-decreasing in item order (a block-major plan), so that is where
+    the value changes."""
+    return jnp.concatenate([jnp.ones((1,), jnp.int32),
+                            (pc[1:] > pc[:-1]).astype(jnp.int32)])
+
+
+def _body_expand_1d(bn: int):
+    def body(blk_ref, chk_ref, firstc_ref, recv_ref, vals_ref, o_ref):
+        t = pl.program_id(0)
+        b = blk_ref[t]
+
+        @pl.when(firstc_ref[t] == 1)
+        def _():
+            o_ref[:] = jnp.zeros_like(o_ref)
+
+        recv = recv_ref[0]                        # [bk//128, 128] int32
+        local = recv - b * bn
+        # the block's bn values arrive with the nodes on the lanes; the
+        # select wants them down the sublanes, the edges on the lanes
+        col = jnp.broadcast_to(vals_ref[0], (128, bn)).T  # row n = vals[n]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bn, 128), 0)
+        for j in range(recv.shape[0]):
+            sel = jnp.where(rows == local[j : j + 1, :], col, 0.0)
+            # one row of a lane holds the value and the others 0: the sum
+            # is the selection, exactly.  A chunk that straddles blocks is
+            # visited once per block; foreign lanes add 0
+            o_ref[0, j, :] += jnp.sum(sel, axis=0)
+
+    return body
+
+
+def csr_segment_expand_1d(
+    values: jax.Array,     # [N] per-node scalars
+    receivers: jax.Array,  # [E] int32, sorted ascending
+    plan: tuple,           # CsrPlan device arrays (block, chunk, first)
+    num_segments: int,
+) -> jax.Array:
+    """``values[receivers]``, the transpose of
+    :func:`csr_segment_reduce_1d`, on the same plan: each work item holds
+    its node block's 128 values resident and its chunk's 512 receivers,
+    and selects by the ``rows == local`` comparison the reductions use.
+    XLA's 1-D gather moves such a scalar at 7 ns an edge on v5e; this
+    walk moves it at the rate of the scalar reductions (PERF.md §6,
+    PR 29).  A selection, no arithmetic: the result is the gather's,
+    bit for bit (a ``-0.0`` comes out ``0.0``).  Twin: the gather."""
+    m = S.mode()
+    if m == "xla":
+        return values[receivers]
+    e = receivers.shape[0]
+    bn, bk = _BN, _BK
+    e_pad = S.round_up(e, bk)
+    n_pad = S.round_up(num_segments, bn)
+    v = S.pad_axis(values.astype(jnp.float32), 0, bn).reshape(
+        n_pad // bn, 1, bn)
+    recv2d = S.pad_axis(receivers, 0, bk).reshape(e_pad // bk, bk // 128, 128)
+    pb, pc, _ = tuple(plan)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(pb.shape[0],),
+        in_specs=[
+            pl.BlockSpec((1, bk // 128, 128),
+                         lambda t, blk, chk, fc: (chk[t], 0, 0)),
+            pl.BlockSpec((1, 1, bn), lambda t, blk, chk, fc: (blk[t], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, bk // 128, 128),
+                               lambda t, blk, chk, fc: (chk[t], 0, 0)),
+    )
+    out = pl.pallas_call(
+        _body_expand_1d(bn),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((e_pad // bk, bk // 128, 128),
+                                       jnp.float32),
+        name="csr_segment_expand_1d",
+        interpret=S.interpret_flag(m),
+    )(pb, pc, _first_visit_of_chunk(pc), recv2d, v)
+    return out.reshape(e_pad)[:e].astype(values.dtype)
+
+
 # --- fused attention backward over edges ---------------------------------------
 
 
@@ -516,10 +596,7 @@ def csr_att_bwd_edges(
         e_pad // bk, bk // 128, 128)
     recv2d = S.pad_axis(receivers, 0, bk).reshape(e_pad // bk, bk // 128, 128)
     pb, pc, pf = tuple(plan)
-    # chunk indices are non-decreasing in item order (block-major plan),
-    # so each chunk's first visitor is where the value changes
-    fc = jnp.concatenate([jnp.ones((1,), jnp.int32),
-                          (pc[1:] > pc[:-1]).astype(jnp.int32)])
+    fc = _first_visit_of_chunk(pc)
     t = pb.shape[0]
     n_pad = S.round_up(num_segments, bn)
     grid_spec = pltpu.PrefetchScalarGridSpec(

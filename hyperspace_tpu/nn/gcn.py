@@ -233,11 +233,15 @@ def _aggregate(h: jax.Array, alpha, g, agg_dtype) -> jax.Array:
             # the sender pick rides the message gather as an extra
             # feature column (ONE random [E] gather/layer), bounded-
             # logit softmax needs no max pass, num/den are one CSR
-            # pass each, and the backward re-uses saved residual rows
-            # instead of re-gathering.  (Row gathers cost ~28 ms per
-            # 2.4 M edges on v5e regardless of width — pass count is
-            # the whole game.)  On well-clustered graphs the
-            # clustered edges drop out of the [E] stream entirely:
+            # pass, the receiver-side pick is a walk of the same CSR
+            # plan, and the backward re-uses saved residual rows instead
+            # of re-gathering and permutes its two per-edge scalars with
+            # one key-sort.  (What an [E]-length pass costs on v5e
+            # depends on its form — a 1-D scalar gather 7–10 ns an
+            # edge, a row gather from fast memory under 2, a sort 1.6,
+            # a CSR walk 0.7: PERF.md §6, PRs 27 and 29.)  On
+            # well-clustered graphs the clustered edges drop out of
+            # the [E] stream entirely:
             # their logits, weights, aggregation, and whole backward
             # run in-tile from VMEM-resident blocks
             # (nn/scatter.cluster_att_partial), and only the

@@ -19,7 +19,9 @@ guaranteed by ``data.graphs.prepare``:
    i.e. ``dh = segment_sum(w[π] · ḡ[senders], receivers)`` — sorted
    again.  Only the scalar weights get permuted; the [E, D] tensors
    never do.  Padding edges carry w = 0 and map to themselves under π
-   (both arranged by ``prepare``), keeping π a bijection.
+   (both arranged by ``prepare``), keeping π a bijection.  The
+   permutation has one spelling, :func:`involute`: a key-sort by π,
+   not a gather (below).
 
 2. **Scatter as matmul.** With a CSR work-item plan (also built by
    ``prepare``), each sorted segment-sum dispatches to the block-CSR
@@ -27,6 +29,17 @@ guaranteed by ``data.graphs.prepare``:
    (:func:`hyperspace_tpu.kernels.segment.csr_segment_sum`) instead of
    XLA's serialized scatter — ~2.4× at ogbn-arxiv scale on v5e, in both
    the forward and the re-indexed backward pass.
+
+3. **No per-edge scalar through an XLA gather.**  On v5e a 1-D gather
+   costs 7–10 ns an element whatever its table's size (1.57 M edges:
+   11–15 ms), where a row gather from a table in fast memory costs
+   1.6–1.9 ns a whole row.  The two kinds of scalar move the attention
+   path needs have cheaper forms, both exact: the involution is the
+   payload of a sort by π (1.6 ns an edge for two payloads), and the
+   receiver-side pick ``alpha_r[receivers]`` happens inside the block-CSR
+   walk (:func:`hyperspace_tpu.kernels.segment.csr_segment_expand_1d`,
+   0.7 ns an edge).  Measured: PERF.md §6, PR 29
+   (``scripts/sweep_att_edge_moves.py``).
 """
 
 from __future__ import annotations
@@ -36,7 +49,31 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from hyperspace_tpu.kernels.segment import csr_segment_sum
+from hyperspace_tpu.kernels.segment import (
+    csr_segment_expand_1d,
+    csr_segment_sum,
+)
+
+
+def involute(rev_perm: jax.Array, *xs: jax.Array):
+    """``x[rev_perm]`` of each ``x`` for the edge involution π (a
+    bijection of ``[0, E)``; padding edges map to themselves), as ONE
+    key-sort that carries every ``x`` as a payload: position k of a
+    payload sorted by π holds ``x[e]`` with ``π(e) = k``, which is
+    ``x[π(k)]`` because π is its own inverse.  The same values in the
+    same order, bit for bit, no arithmetic; the keys are distinct, so
+    the sort need not be stable (a stable one carries an iota along).
+    Returns one array for one ``x``, else a tuple.
+
+    One path for every size: on v5e the sort is ahead of XLA's gather
+    from 13 k edges (0.016 against 0.11 ms) to 1.57 M (1.7–1.8 against
+    13.6–15.2 ms; both payloads in one sort 2.5), and telling the gather
+    that its indices are in bounds and unique changes nothing (PERF.md
+    §6, PR 29).  Payloads that share π go into ONE call: XLA merges
+    sorts with a common key by itself, and the merged sort loses the
+    ``op_name`` that the benchmark's scopes read."""
+    out = jax.lax.sort((rev_perm, *xs), num_keys=1, is_stable=False)[1:]
+    return out[0] if len(xs) == 1 else out
 
 
 def _sorted_segsum(vals, receivers, pb, pc, pf, num_segments):
@@ -79,8 +116,8 @@ def _agg_fwd(h, w, senders, receivers, rev_perm, pb, pc, pf,
 def _agg_bwd(num_segments, with_dw, res, g):
     h, w, senders, receivers, rev_perm, pb, pc, pf = res
     g_s = g[senders]                     # cheap unsorted gather, [E, D]
-    dh = _sorted_segsum(w[rev_perm][:, None] * g_s, receivers, pb, pc, pf,
-                        num_segments)
+    dh = _sorted_segsum(involute(rev_perm, w)[:, None] * g_s, receivers,
+                        pb, pc, pf, num_segments)
     dw = (jnp.sum(g[receivers] * h[senders], axis=-1) if with_dw
           else jnp.zeros_like(w))
     return dh, dw, None, None, None, None, None, None
@@ -113,8 +150,8 @@ def _ps_bwd(num_segments, res, g):
     from hyperspace_tpu.kernels.segment import csr_segment_reduce_1d
 
     receivers, rev_perm, pb, pc, pf = res
-    d = csr_segment_reduce_1d(g[rev_perm], receivers, (pb, pc, pf),
-                              num_segments, op="sum")
+    d = csr_segment_reduce_1d(involute(rev_perm, g), receivers,
+                              (pb, pc, pf), num_segments, op="sum")
     return d, None, None, None, None, None, None
 
 
@@ -316,21 +353,27 @@ cluster_sym_aggregate.defvjp(_ca_fwd, _ca_bwd)
 
 # --- fused planned attention aggregation --------------------------------------
 #
-# The attention layer's cost on TPU is dominated by the NUMBER of
-# [E]-length passes, not bytes: a 2.4 M-row gather costs ~28 ms on v5e
-# regardless of width (latency-bound).  This op fuses the whole
-# softmax-aggregate pipeline around ONE random edge gather:
+# The attention layer's cost on TPU is the [E]-length passes round the
+# kernels, and what a pass costs depends on its form, not its bytes (v5e,
+# 1.57 M straggler edges; PERF.md §5/§6): a row gather from a table in
+# fast memory 2–5 ms at 33 bf16 lanes but 19 ms for the float32
+# [E, 129] one, a 1-D scalar gather 11–15 ms, a key-sort 2.5 ms, a walk
+# of the CSR plan 1 ms.  This op fuses the whole softmax-aggregate
+# pipeline around ONE random edge gather:
 #
 # - forward: alpha_s rides as an extra feature column of h, so the
-#   sender pick and the message gather are a single [E, F+1] gather;
-#   logits/exp are one fused elementwise pass (bounded-logit softmax —
-#   no max machinery, see nn.gcn.bounded_att_logits); numerator and
-#   denominator are one block-CSR pass each.
+#   sender pick and the message gather are a single [E, F+1] gather; the
+#   receiver-side pick alpha_r[receivers] is a walk of the CSR plan
+#   (csr_segment_expand_1d), not a gather; logits/exp are one fused
+#   elementwise pass (bounded-logit softmax — no max machinery, see
+#   nn.gcn.bounded_att_logits); numerator and denominator are one
+#   block-CSR pass.
 # - backward: the gathered sender rows are SAVED as residuals (a
-#   sequential [E, F] write+read ≈ 1.6 ms vs a 28 ms random re-gather),
-#   so dw needs no new random gather; the only random backward gather is
-#   d_num[senders] for the involution dh; everything else is static-
-#   permutation gathers, sorted gathers, and CSR scalar reductions.
+#   sequential [E, F] write+read, against a second random gather), so dw
+#   needs no new random gather; the only random backward gather is
+#   d_num[senders] for the involution dh; the two scalars the sender
+#   direction needs of each edge's reverse (w, dpre) ride ONE key-sort
+#   by the involution (involute); everything else is CSR passes.
 #
 # The op is a PARTIAL: it returns the unnormalized [N, F+1] (num | den)
 # sums so a second partial over a different edge subset (the in-tile
@@ -365,7 +408,8 @@ def _att_partial_impl(h, alpha_s, alpha_r, senders, receivers, edge_mask,
     ha = jnp.concatenate([h, alpha_s[:, None].astype(h.dtype)], axis=1)
     hs_a = ha[senders]                       # the ONE random gather
     h_s, a_se = hs_a[:, :f], hs_a[:, f]
-    a_re = alpha_r[receivers]                # sorted gather
+    # the receiver-side pick happens inside the CSR walk, not as a gather
+    a_re = csr_segment_expand_1d(alpha_r, receivers, plan, num_segments)
     lm = bounded_att_logits(a_se + a_re, negative_slope)
     w = jnp.where(edge_mask, jnp.exp(lm), 0.0)
     h_in = h_s if agg_dtype is None else h_s.astype(agg_dtype)
@@ -407,10 +451,6 @@ def _att_partial_bwd(num_segments, agg_dtype, negative_slope, res, g):
     dn_ext = g.astype(jnp.float32)
     dn_dt = dn_ext if agg_dtype is None else dn_ext.astype(agg_dtype)
     dn_s = dn_dt[senders]                # the one random backward gather
-    # dh via the involution: sender-scatter becomes a receiver-scatter
-    # (the extra lane aggregates Σ w·d_den — sliced off)
-    dh = _sorted_segsum(w_in[rev_perm][:, None] * dn_s, receivers,
-                        pb, pc, pf, num_segments)[:, :f].astype(h_dtype)
     # dw + softmax chain + d_alpha_r: ONE fused CSR pass — the receiver-
     # side d_num|d_den rows are picked from the resident node block, the
     # ones-augmented residual rows stream by chunk, and the per-receiver
@@ -424,7 +464,14 @@ def _att_partial_bwd(num_segments, agg_dtype, negative_slope, res, g):
         dn_ext, h1, jnp.where(edge_mask, w_in.astype(jnp.float32), 0.0),
         lm, receivers, (pb, pc, pf), num_segments, float(B),
         negative_slope)
-    d_alpha_s = csr_segment_reduce_1d(dpre[rev_perm], receivers,
+    # the two per-edge scalars the sender direction needs, w and dpre of
+    # each edge's reverse, ride one key-sort (see involute)
+    w_rev, dpre_rev = involute(rev_perm, w_in, dpre)
+    # dh via the involution: sender-scatter becomes a receiver-scatter
+    # (the extra lane aggregates Σ w·d_den — sliced off)
+    dh = _sorted_segsum(w_rev[:, None] * dn_s, receivers,
+                        pb, pc, pf, num_segments)[:, :f].astype(h_dtype)
+    d_alpha_s = csr_segment_reduce_1d(dpre_rev, receivers,
                                       (pb, pc, pf), num_segments, op="sum")
     return (dh, d_alpha_s, d_alpha_r, None, None, None, None, None)
 

@@ -153,6 +153,15 @@ def main():
                        csr_segment_reduce_1d(svals, recv_d, plan, 200,
                                              op="max"), NEG_FILL)))
 
+    # the receiver-side pick inside the CSR walk: a selection, so the
+    # kernel and its twin (the gather) agree to the last bit
+    from hyperspace_tpu.kernels.segment import csr_segment_expand_1d
+
+    nvals = jnp.asarray(rng.normal(size=(200,)).astype(np.float32))
+    oks.append(run("csr_segment_expand_1d",
+                   lambda: csr_segment_expand_1d(nvals, recv_d, plan, 200),
+                   tol=1e-12))
+
     # cluster-pair SpMM kernel (r03): one-hot matmuls over VMEM tiles,
     # f32 and the fast single-pass bf16 mode
     from hyperspace_tpu.kernels.cluster import (

@@ -19,7 +19,8 @@ from tests.tiny_lp import lp_step
 DECODER = {"rows_to_columns": 1, "pair_scatter_sum": 1}
 MEAN = {"cluster_aggregate": 4, "csr_segment_sum": 4, **DECODER}
 ATT = {"cluster_att_fwd": 2, "cluster_att_bwd": 2, "csr_segment_sum": 4,
-       "csr_segment_reduce_1d": 2, "csr_att_bwd_edges": 2, **DECODER}
+       "csr_segment_reduce_1d": 2, "csr_att_bwd_edges": 2,
+       "csr_segment_expand_1d": 2, **DECODER}
 
 
 def _sub_jaxprs(params):

@@ -210,3 +210,81 @@ def test_rows_to_columns_is_the_transpose(rows, dtype, rng, interp):
                                   np.asarray(x.T, np.float32))
     with pytest.raises(ValueError, match="whole"):
         rows_to_columns(x[:500])
+
+
+# --- the receiver-side pick inside the CSR walk --------------------------------
+
+
+def _expand_receivers(case, rng):
+    """(receivers, num_nodes) of the cases the reductions are held to."""
+    if case == "uniform":
+        n, r = 1000, rng.integers(0, 1000, 5000)
+    elif case == "tiny":
+        n, r = 7, rng.integers(0, 7, 3)
+    elif case == "hub_and_empty_segments":  # one node takes 90% of the edges
+        n = 500
+        r = np.where(rng.random(4000) < 0.9, 137, rng.integers(0, n, 4000))
+    elif case == "empty_trailing_blocks":  # E a whole number of chunks
+        n, r = 300, rng.integers(0, 128, 512)
+    elif case == "chunk_straddles_blocks":  # 130 nodes in one 513-edge list
+        n, r = 130, np.concatenate([rng.integers(0, 128, 500),
+                                    np.full(13, 129)])
+    else:  # zero_padding_tail: the layout's padding points at n - 1
+        n = 100
+        r = np.concatenate([rng.integers(0, n, 700), np.full(300, n - 1)])
+    return np.sort(r).astype(np.int32), n
+
+
+EXPAND_CASES = ["uniform", "tiny", "hub_and_empty_segments",
+                "empty_trailing_blocks", "chunk_straddles_blocks",
+                "zero_padding_tail"]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", EXPAND_CASES)
+def test_csr_segment_expand_1d_is_the_gather_bit_for_bit(case, dtype, rng,
+                                                         interp):
+    from hyperspace_tpu.kernels.segment import csr_segment_expand_1d
+
+    r, n = _expand_receivers(case, rng)
+    plan = tuple(jnp.asarray(a) for a in build_csr_plan(r, n))
+    vals = jnp.asarray(rng.standard_normal(n), dtype)
+    got = jax.jit(csr_segment_expand_1d, static_argnums=3)(
+        vals, jnp.asarray(r), plan, n)
+    assert got.shape == r.shape and got.dtype == vals.dtype
+    view = np.uint32 if dtype == "float32" else np.uint16
+    np.testing.assert_array_equal(np.asarray(got).view(view),
+                                  np.asarray(vals[r]).view(view))
+
+
+@pytest.mark.parametrize("case", EXPAND_CASES)
+def test_csr_segment_expand_1d_twin_agrees(case, rng, monkeypatch):
+    from hyperspace_tpu.kernels.segment import csr_segment_expand_1d
+
+    r, n = _expand_receivers(case, rng)
+    plan = tuple(jnp.asarray(a) for a in build_csr_plan(r, n))
+    vals = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    out = {}
+    for mode in ("xla", "interpret"):
+        monkeypatch.setenv("HYPERSPACE_KERNELS", mode)
+        out[mode] = np.asarray(csr_segment_expand_1d(vals, jnp.asarray(r),
+                                                     plan, n))
+    np.testing.assert_array_equal(out["xla"], out["interpret"])
+    np.testing.assert_array_equal(out["xla"], np.asarray(vals)[r])
+
+
+def test_csr_segment_expand_1d_is_the_transpose_of_the_sum(rng, interp):
+    """<expand(v), t> = <v, reduce_sum(t)>: the same plan walked the
+    other way."""
+    from hyperspace_tpu.kernels.segment import (
+        csr_segment_expand_1d,
+        csr_segment_reduce_1d,
+    )
+
+    r, n = _expand_receivers("hub_and_empty_segments", rng)
+    plan = tuple(jnp.asarray(a) for a in build_csr_plan(r, n))
+    v = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    t = jnp.asarray(rng.standard_normal(len(r)), jnp.float32)
+    lhs = jnp.vdot(csr_segment_expand_1d(v, jnp.asarray(r), plan, n), t)
+    rhs = jnp.vdot(v, csr_segment_reduce_1d(t, jnp.asarray(r), plan, n))
+    np.testing.assert_allclose(float(lhs), float(rhs), rtol=1e-4)

@@ -127,6 +127,21 @@ def test_csr_segment_sum_bf16(one_chip, arxiv_split):
              A((e, ARXIV_FEATS), BF16), A((e,), I32), plan, n_kernels=1)
 
 
+def test_csr_segment_expand_1d_at_the_cell_shape(one_chip, arxiv_split):
+    """The attention arm's receiver-side pick over the mean split's
+    straggler layout: the in-kernel 128 x 128 transpose and the (1, 1,
+    128) value blocks are what interpret mode lets through unasked."""
+    from hyperspace_tpu.kernels.segment import csr_segment_expand_1d
+
+    cl = arxiv_split.graph.cluster_split
+    n, e = arxiv_split.graph.num_nodes, cl.s_recv.shape[0]
+    A = _arg(one_chip)
+    plan = _shapes(tuple(jnp.asarray(a) for a in cl.s_plan), one_chip)
+    _, text = _compile(lambda v, r, p: csr_segment_expand_1d(v, r, p, n),
+                       A((n,)), A((e,), I32), plan, n_kernels=1)
+    assert "csr_segment_expand_1d" in text
+
+
 def test_pair_scatter_sum_at_the_cell_shape(one_chip):
     """The LP decoder's backward sum as both benchmark cells run it: the
     cotangent rows of 2 x 1,880,610 pair ends, 33 bf16 lanes, handed over

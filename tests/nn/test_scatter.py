@@ -5,6 +5,7 @@ exact, not approximate)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from hyperspace_tpu.data import graphs as G
 from hyperspace_tpu.nn.scatter import sym_segment_aggregate
@@ -266,3 +267,121 @@ def test_att_aggregate_planned_kernel_path(monkeypatch):
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
+
+
+# --- the involution as a key-sort (involute) ----------------------------------
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32, 8: np.uint64}[a.dtype.itemsize])
+
+
+def _involutions():
+    """{name: rev_perm}: `prepare`'s involution with its padding rows,
+    and the straggler-local one of a cluster split (its own padding
+    tail maps to itself)."""
+    from hyperspace_tpu.kernels.cluster import build_cluster_split
+
+    g = _graph(n=600, seed=7)
+    assert (~g.edge_mask).any()          # padding rows: fixed points of π
+    split = build_cluster_split(g.senders, g.receivers, g.edge_mask, g.deg,
+                                g.num_nodes, min_pair_edges=512,
+                                rev_perm=g.rev_perm)
+    assert (~split.s_mask).any() and split.s_mask.sum() > 100
+    return {"prepare": g.rev_perm, "stragglers": split.s_rev_local}
+
+
+@pytest.mark.parametrize("which", ["prepare", "stragglers"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_involute_is_the_gather_bit_for_bit(dtype, which):
+    from hyperspace_tpu.nn.scatter import involute
+
+    rp = jnp.asarray(_involutions()[which])
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal(rp.shape[0]), dtype)
+    y = jnp.asarray(rng.standard_normal(rp.shape[0]), jnp.float32)
+    got = involute(rp, x)
+    assert got.dtype == x.dtype and got.shape == x.shape
+    np.testing.assert_array_equal(_bits(got), _bits(x[rp]))
+    # several payloads ride one sort, each in its own dtype
+    gx, gy = jax.jit(involute)(rp, x, y)
+    np.testing.assert_array_equal(_bits(gx), _bits(x[rp]))
+    np.testing.assert_array_equal(_bits(gy), _bits(y[rp]))
+
+
+def _gather_spelling(rev_perm, *xs):
+    out = tuple(x[rev_perm] for x in xs)
+    return out[0] if len(xs) == 1 else out
+
+
+def _grads_of(op, dtype, g):
+    """The gradients `op`'s custom VJP hands back on the graph ``g``,
+    with `dtype` the dtype of what the involution permutes."""
+    from hyperspace_tpu.nn import scatter
+
+    n = g.num_nodes
+    rng = np.random.default_rng(9)
+    s, r, rp, m = map(jnp.asarray, (g.senders, g.receivers, g.rev_perm,
+                                    g.edge_mask))
+    plan = tuple(jnp.asarray(p) for p in g.csr_plan)
+    h = jnp.asarray(rng.standard_normal((n, 8)), jnp.float32)
+    a_s = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    a_r = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    probe = jnp.asarray(rng.standard_normal((n, 8)), jnp.float32)
+    if op == "att_aggregate_planned":
+        agg = None if dtype == jnp.float32 else dtype
+        f = lambda h, a_s, a_r: jnp.sum(probe * scatter.att_aggregate_planned(
+            h, a_s, a_r, s, r, rp, m, plan, n, agg, 0.2))
+        return jax.grad(f, argnums=(0, 1, 2))(h, a_s, a_r)
+    if op == "sym_segment_aggregate":
+        w = jnp.where(m, jnp.asarray(rng.random(len(g.senders)), dtype), 0)
+        f = lambda h, w: jnp.sum(probe.astype(dtype) * (
+            scatter.sym_segment_aggregate(h.astype(dtype), w, s, r, rp,
+                                          *plan, n)))
+        return jax.grad(f, argnums=(0, 1))(h, w)
+    t = jnp.asarray(rng.standard_normal(len(g.senders)), dtype)
+    f = lambda a: jnp.sum(
+        scatter.pick_senders(a.astype(dtype), s, r, rp, *plan, n) * t)
+    return (jax.grad(f)(a_s),)
+
+
+@pytest.mark.parametrize("op", ["att_aggregate_planned",
+                                "sym_segment_aggregate", "pick_senders"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_vjps_through_involute_are_the_gather_spellings(dtype, op,
+                                                        monkeypatch):
+    """Every ``[rev_perm]`` site of nn/scatter.py goes through
+    `involute`; with the helper swapped for the plain gather the three
+    custom VJPs hand back the same gradients bit for bit (the oracles
+    above hold them to autodiff of the naive formulation)."""
+    from hyperspace_tpu.nn import scatter
+
+    g = _graph(n=120, seed=6)
+    assert (~g.edge_mask).any()
+    got = _grads_of(op, dtype, g)
+    monkeypatch.setattr(scatter, "involute", _gather_spelling)
+    want = _grads_of(op, dtype, g)
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+        assert float(jnp.max(jnp.abs(a.astype(jnp.float32)))) > 0
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+
+
+def test_no_gather_by_the_involution_is_left_in_the_module():
+    """One spelling: no subscript by ``rev_perm`` in nn/scatter.py's code
+    (its docstrings may write ``x[rev_perm]``)."""
+    import ast
+    import inspect
+
+    from hyperspace_tpu.nn import scatter
+
+    picks = [node.lineno
+             for node in ast.walk(ast.parse(inspect.getsource(scatter)))
+             if isinstance(node, ast.Subscript)
+             and isinstance(node.slice, ast.Name)
+             and node.slice.id == "rev_perm"]
+    assert not picks, picks
