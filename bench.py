@@ -306,16 +306,14 @@ def bench_poincare(repeats: int = 3) -> dict:
 
 def bench_hgcn(repeats: int = 3, dtype: str = "float32",
                agg_dtype: str = "bfloat16", use_att: bool = False,
-               step: str = "pairs", decoder_dtype: str | None = "bfloat16") -> dict:
+               decoder_dtype: str | None = "bfloat16") -> dict:
     """HGCN training throughput (samples/sec/chip) on an arxiv-scale graph.
 
-    Default config (validated quality-neutral at full 169 k-node scale
-    over 3 seeds — docs/benchmarks.md quality-anchor section): f32
-    compute, bf16 *edge messages* and a bf16 decoder pass (everything
-    accumulates f32), with the fully-planned-pairs train step whose
-    decoder scatters are block-CSR.  Measured 987 k samples/s/chip vs
-    812 k for the r01 default on the same chip/session.  ``--step lp
-    --decoder-dtype float32 --agg-dtype float32`` reproduces pure-f32;
+    Times ``train_step_lp``, the step ``cli.train`` runs.  Default config
+    (validated quality-neutral at full 169 k-node scale over 3 seeds —
+    docs/benchmarks.md quality-anchor section): f32 compute, bf16 *edge
+    messages* and a bf16 decoder pass (everything accumulates f32).
+    ``--decoder-dtype float32 --agg-dtype float32`` reproduces pure-f32;
     ``--dtype bfloat16`` runs everything in bf16 (faster, AUC degrades,
     opt-in); ``--use-att`` benches the attention-aggregation model.
     """
@@ -325,7 +323,7 @@ def bench_hgcn(repeats: int = 3, dtype: str = "float32",
 
     return run_hgcn_bench(repeats=repeats, backend=jax.default_backend(),
                           dtype=dtype, agg_dtype=agg_dtype, use_att=use_att,
-                          step=step, decoder_dtype=decoder_dtype)
+                          decoder_dtype=decoder_dtype)
 
 
 def bench_sampled(repeats: int = 2) -> dict:
@@ -2315,7 +2313,6 @@ _COMPACT_FIELDS = (
     ("reorder", ("detail", "reorder")),
     ("source", ("detail", "source")),
     ("dtype", ("detail", "dtype")),
-    ("step", ("detail", "step")),
 )
 
 # hard byte budget for the LAST stdout line.  The driver records only the
@@ -2430,7 +2427,6 @@ def main() -> None:
                    default="bfloat16")
     p.add_argument("--use-att", action="store_true",
                    help="attention aggregation (GAT-style) instead of mean")
-    p.add_argument("--step", choices=["lp", "pairs"], default="pairs")
     p.add_argument("--decoder-dtype", choices=["float32", "bfloat16"],
                    default="bfloat16")
     p.add_argument("--budget-s", type=float,
@@ -2469,7 +2465,7 @@ def main() -> None:
 
     hgcn_fn = functools.partial(bench_hgcn, dtype=args.dtype,
                                 agg_dtype=args.agg_dtype,
-                                use_att=args.use_att, step=args.step,
+                                use_att=args.use_att,
                                 decoder_dtype=args.decoder_dtype)
     primary = {"poincare": bench_poincare,
                "serve": bench_serve,

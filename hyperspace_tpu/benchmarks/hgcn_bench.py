@@ -171,14 +171,11 @@ def run_hgcn_bench(
     dtype: str = "float32",
     agg_dtype: str = "bfloat16",  # precision-policy: ok (CLI flag name)
     use_att: bool = False,
-    step: str = "pairs",  # "lp" | "pairs" (fully-planned decoder scatters)
     decoder_dtype: str | None = "bfloat16",  # precision-policy: ok (flag)
 ) -> dict:
-    """The default config — pairs step, f32 compute, bf16 edge messages
-    and bf16 decoder pass (everything accumulates f32) — is the r02 bench
-    default: measured quality-neutral at full 169 k-node scale over 3
-    seeds (test AUC 0.6196 vs 0.6193 f32 control; docs/benchmarks.md) at
-    987 k samples/s/chip vs 812 k for the r01 lp-step default."""
+    """Times ``train_step_lp``, the step ``cli.train`` runs.  The default
+    config — f32 compute, bf16 edge messages and bf16 decoder pass
+    (everything accumulates f32) — is ``configs/hgcn_arxiv_lp.yaml``'s."""
     import jax
     import jax.numpy as jnp
 
@@ -217,16 +214,9 @@ def run_hgcn_bench(
         cfg = hgcn_mode_defaults(cfg, {"use_att": "true"}, sampled=False)
     model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
     ga = hgcn._device_graph(split.graph)
-    if step == "pairs":
-        pos = hgcn.make_planned_pairs(split.train_pos, num_nodes)
-        neg_u, neg_plan = hgcn.make_static_negatives(
-            num_nodes, int(pos.u.shape[0]) * cfg.neg_per_pos, seed=0)
-        step_fn = lambda st: hgcn.train_step_lp_pairs(
-            model, opt, num_nodes, st, ga, pos, neg_u, neg_plan)
-    else:
-        train_pos = jnp.asarray(split.train_pos)
-        step_fn = lambda st: hgcn.train_step_lp(
-            model, opt, num_nodes, st, ga, train_pos)
+    train_pos = jnp.asarray(split.train_pos)
+    step_fn = lambda st: hgcn.train_step_lp(
+        model, opt, num_nodes, st, ga, train_pos)
 
     times, state, loss = time_steps_all(step_fn, state, steps_per_repeat,
                                         repeats)
@@ -265,10 +255,6 @@ def run_hgcn_bench(
             # the timed step — the artifact must say so
             "lr": cfg.lr,
             "clip_norm": cfg.clip_norm,
-            "step": step,
-            # both steps run the training decoder pass through
-            # cfg.decoder_dtype (HGCNLinkPred casts z whenever
-            # deterministic=False), so the record is the flag as executed
             "decoder_dtype": decoder_dtype,
             # precision mode as executed, so BENCH_r* trajectories stay
             # comparable across precision configs (docs/precision.md)
@@ -313,6 +299,7 @@ def run_realistic_bench(repeats: int = 2, steps_per_repeat: int = 10,
         # above is served from disk — hits > 0 is the observable
         "graph_cache": prep_cache.stats(),
     }
+    train_pos = jnp.asarray(split.train_pos)
     for use_att in (False, True):
         # per-mode cluster threshold (r05 sweep): only the cluster
         # split differs between the legs, so rebuild just that piece
@@ -344,11 +331,8 @@ def run_realistic_bench(repeats: int = 2, steps_per_repeat: int = 10,
                                      sampled=False)
         model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
         ga = hgcn._device_graph(split.graph)
-        pos = hgcn.make_planned_pairs(split.train_pos, num_nodes)
-        neg_u, neg_plan = hgcn.make_static_negatives(
-            num_nodes, int(pos.u.shape[0]) * cfg.neg_per_pos, seed=0)
-        step_fn = lambda st: hgcn.train_step_lp_pairs(
-            model, opt, num_nodes, st, ga, pos, neg_u, neg_plan)
+        step_fn = lambda st: hgcn.train_step_lp(
+            model, opt, num_nodes, st, ga, train_pos)
         best, state, loss = time_steps(step_fn, state, steps_per_repeat,
                                        repeats)
         out[f"{key}_lr"] = cfg.lr            # the config as EXECUTED

@@ -63,9 +63,7 @@ class HGCNConfig:
     # dtype of the LP decoder's pair-distance pass during TRAINING
     # (None = dtype); eval always scores in full precision.  bf16 halves
     # the bytes of the 1.9 M-pair gathers, elementwise passes and
-    # cotangent rows.  (train_step_lp_pairs / _planned are other steps,
-    # not faster forms of this one: nn/edge_dist.py says what each
-    # changes about the sampler and the pair set.)
+    # cotangent rows.
     decoder_dtype: Any = None
     # rematerialize each conv layer in the backward pass (jax.checkpoint):
     # trades an extra forward per layer for not storing its residuals.
@@ -194,72 +192,6 @@ class HGCNLinkPred(nn.Module):
             sq_n = m.sqdist(z[neg[:, 0]], z[neg[:, 1]])
         return (dec(sq_p.astype(self.cfg.dtype)),
                 dec(sq_n.astype(self.cfg.dtype)))
-
-    @nn.compact
-    def pair_logits(self, g: graph_data.DeviceGraph, pos, neg_u, neg_v,
-                    neg_plan, *, deterministic=True):
-        """Logits for one LP step with every *static* scatter planned:
-        positives are the run's train_pos pairs through
-        `pair_sqdist_planned` (both endpoint scatters block-CSR), negatives
-        corrupt only v (u-side planned).  ``pos`` is the bundle from
-        :func:`make_planned_pairs`.  Returns (pos_logits [P], neg_logits [Q])."""
-        from hyperspace_tpu.nn.edge_dist import (
-            pair_sqdist_planned,
-            pair_sqdist_semi_planned,
-        )
-
-        z, m = HGCNEncoder(self.cfg, name="encoder")(
-            g, deterministic=deterministic
-        )
-        ddt = self.cfg.resolved_decoder_dtype()
-        if ddt is not None:
-            z = z.astype(ddt)
-        npb, npc, npf = neg_plan
-        with jax.named_scope("pair_dist"):
-            sq_pos = pair_sqdist_planned(
-                z, m.c, pos.u, pos.v, *pos.u_plan, pos.v_perm, pos.v_sorted,
-                *pos.v_plan, self.cfg.kind)
-            sq_neg = pair_sqdist_semi_planned(z, m.c, neg_u, neg_v,
-                                              npb, npc, npf, self.cfg.kind)
-        dec = FermiDiracDecoder(name="decoder")
-        return (dec(sq_pos.astype(self.cfg.dtype)),
-                dec(sq_neg.astype(self.cfg.dtype)))
-
-    @nn.compact
-    def edge_logits(self, g: graph_data.DeviceGraph, neg_u, neg_v, neg_plan,
-                    *, deterministic=True):
-        """Fast-path logits for one LP train step (same params as __call__):
-        positives scored on the graph's own (sorted, planned) edge list and
-        negatives on (static sorted u, fresh v) pairs, so every decoder
-        gradient scatter is planned (nn/edge_dist.py).  Returns
-        (pos_logits [E], pos_weight [E], neg_logits [P])."""
-        from hyperspace_tpu.nn.edge_dist import (
-            graph_edge_sqdist,
-            pair_sqdist_semi_planned,
-        )
-
-        if g.rev_perm is None:
-            raise ValueError(
-                "edge_logits needs a symmetric edge layout — build the graph "
-                "with graphs.prepare(..., symmetrize=True) (rev_perm is None)")
-        z, m = HGCNEncoder(self.cfg, name="encoder")(
-            g, deterministic=deterministic
-        )
-        ddt = self.cfg.resolved_decoder_dtype()
-        if ddt is not None:
-            z = z.astype(ddt)  # train-only method
-        pb, pc, pf = g.plan if g.plan is not None else (None, None, None)
-        npb, npc, npf = neg_plan
-        with jax.named_scope("pair_dist"):
-            sq_pos = graph_edge_sqdist(z, m.c, g.senders, g.receivers,
-                                       g.rev_perm, pb, pc, pf, self.cfg.kind)
-            sq_neg = pair_sqdist_semi_planned(z, m.c, neg_u, neg_v,
-                                              npb, npc, npf, self.cfg.kind)
-        sq_pos = sq_pos.astype(self.cfg.dtype)
-        # self-loops are degenerate positives (d = 0); weight them out
-        w_pos = (g.edge_mask & (g.senders != g.receivers)).astype(sq_pos.dtype)
-        dec = FermiDiracDecoder(name="decoder")
-        return dec(sq_pos), w_pos, dec(sq_neg.astype(self.cfg.dtype))
 
 
 class HGCNNodeClf(nn.Module):
@@ -419,116 +351,6 @@ def train_step_lp(
     return _lp_step_impl(model, opt, num_nodes, state, g, train_pos)
 
 
-class PlannedPairs(NamedTuple):
-    """Static supervision pairs with both-side CSR scatter plans
-    (see nn/edge_dist.pair_sqdist_planned)."""
-
-    u: jax.Array         # [P] sorted
-    v: jax.Array         # [P] aligned with u
-    u_plan: tuple
-    v_perm: jax.Array    # [P] argsort of v
-    v_sorted: jax.Array  # [P]
-    v_plan: tuple
-
-
-def make_planned_pairs(pairs: np.ndarray, num_nodes: int) -> PlannedPairs:
-    """One-time host-side prep of a static pair set for the fully-planned
-    decoder pass: sort by u and build its CSR plan; keep the static argsort
-    of the aligned v column with its own plan for the backward."""
-    from hyperspace_tpu.kernels.segment import build_csr_plan
-
-    pairs = np.asarray(pairs)
-    order = np.argsort(pairs[:, 0], kind="stable")
-    u = np.ascontiguousarray(pairs[order, 0]).astype(np.int32)
-    v = np.ascontiguousarray(pairs[order, 1]).astype(np.int32)
-    v_perm = np.argsort(v, kind="stable").astype(np.int32)
-    v_sorted = v[v_perm]
-    to_dev = lambda plan: tuple(jnp.asarray(a) for a in plan)
-    return PlannedPairs(
-        u=jnp.asarray(u), v=jnp.asarray(v),
-        u_plan=to_dev(build_csr_plan(u, num_nodes)),
-        v_perm=jnp.asarray(v_perm), v_sorted=jnp.asarray(v_sorted),
-        v_plan=to_dev(build_csr_plan(v_sorted, num_nodes)),
-    )
-
-
-@partial(jax.jit, static_argnames=("model", "opt", "num_nodes"), donate_argnames=("state",))
-def train_step_lp_pairs(
-    model: HGCNLinkPred,
-    opt,
-    num_nodes: int,
-    state: TrainState,
-    g: graph_data.DeviceGraph,
-    pos: "PlannedPairs",
-    neg_u: jax.Array,
-    neg_plan: tuple,
-):
-    """One LP step scoring exactly the train positives with both decoder
-    scatters planned, plus corrupt-one-side negatives (u planned).  Same
-    pair count as `train_step_lp`; the only unsorted scatter left in the
-    decoder backward is the negatives' fresh-random v side, which cannot
-    be pre-planned (VERDICT r1 #6)."""
-    assert neg_u.shape[0] == pos.u.shape[0] * model.cfg.neg_per_pos, (
-        f"neg_u has {neg_u.shape[0]} rows; cfg.neg_per_pos="
-        f"{model.cfg.neg_per_pos} needs {pos.u.shape[0]} * neg_per_pos "
-        "(size the static negatives with make_static_negatives accordingly)")
-    with jax.named_scope("negatives"):
-        key, k_neg, k_drop = jax.random.split(state.key, 3)
-        neg_v = jax.random.randint(k_neg, neg_u.shape, 0, num_nodes)
-
-    def loss_fn(params):
-        pos_logit, neg_logit = model.apply(
-            {"params": params}, g, pos, neg_u, neg_v, neg_plan,
-            deterministic=False, rngs={"dropout": k_drop},
-            method=HGCNLinkPred.pair_logits,
-        )
-        return _bce_pos_neg(pos_logit, neg_logit)
-
-    loss, grads = jax.value_and_grad(loss_fn)(state.params)
-    return _apply_grads(opt, state, grads, key), loss
-
-
-def make_static_negatives(num_nodes: int, n_neg: int, seed: int = 0):
-    """Host-side one-time negative scaffold for the planned LP step: a
-    sorted static u column with its CSR plan; only v re-randomizes on
-    device each step (corrupt-one-side sampling — the u marginal is fixed
-    uniform, drawn once)."""
-    from hyperspace_tpu.kernels.segment import build_csr_plan
-
-    rng = np.random.default_rng(seed)
-    u = np.sort(rng.integers(0, num_nodes, n_neg)).astype(np.int32)
-    plan = tuple(jnp.asarray(a) for a in build_csr_plan(u, num_nodes))
-    return jnp.asarray(u), plan
-
-
-@partial(jax.jit, static_argnames=("model", "opt", "num_nodes"), donate_argnames=("state",))
-def train_step_lp_planned(
-    model: HGCNLinkPred,
-    opt,
-    num_nodes: int,
-    state: TrainState,
-    g: graph_data.DeviceGraph,
-    neg_u: jax.Array,  # [P] sorted static (make_static_negatives)
-    neg_plan: tuple,
-):
-    """One LP step with every decoder gradient scatter planned: positives
-    are the graph's own edge list, negatives corrupt only the v side."""
-    with jax.named_scope("negatives"):
-        key, k_neg, k_drop = jax.random.split(state.key, 3)
-        neg_v = jax.random.randint(k_neg, neg_u.shape, 0, num_nodes)
-
-    def loss_fn(params):
-        pos_logit, w_pos, neg_logit = model.apply(
-            {"params": params}, g, neg_u, neg_v, neg_plan,
-            deterministic=False, rngs={"dropout": k_drop},
-            method=HGCNLinkPred.edge_logits,
-        )
-        return _bce_pos_neg(pos_logit, neg_logit, w_pos)
-
-    loss, grads = jax.value_and_grad(loss_fn)(state.params)
-    return _apply_grads(opt, state, grads, key), loss
-
-
 def _concat_hazard(mesh) -> bool:
     """True when ``mesh`` has a non-trivial axis outside the
     batch-sharding ("host"/"data") set — the mesh shape under which an
@@ -551,50 +373,6 @@ def round_up_pairs(pairs: np.ndarray, mesh) -> np.ndarray:
     return np.resize(np.asarray(pairs), (n, 2))
 
 
-def make_sharded_step_lp(
-    model: HGCNLinkPred,
-    opt,
-    num_nodes: int,
-    mesh,
-    state: TrainState,
-    g: graph_data.DeviceGraph,
-):
-    """Build a dp×tp LP train step jitted over ``mesh`` (SURVEY.md §2 N8).
-
-    Compiles the *same* step body as `train_step_lp` with GSPMD shardings:
-    the supervision batch (positives + sampled negatives) is sharded over
-    the data-like mesh axes, so the gradient all-reduce XLA inserts is the
-    NCCL all-reduce of the reference's trainer riding ICI; 2-D kernels are
-    column-sharded over the ``model`` axis when present
-    (`parallel/tp.tp_param_shardings`); optimizer moments are co-located
-    with their parameter shards; the graph itself is replicated.
-
-    Returns ``(step, placed_state, placed_graph)`` — call as
-    ``state, loss = step(state, g, train_pos)``; ``state`` is donated.
-    """
-    from hyperspace_tpu.parallel.mesh import batch_sharding, replicated
-    from hyperspace_tpu.parallel.tp import replicated_like, state_shardings
-
-    state_sh = state_shardings(state, state.params, mesh)
-    g_sh = replicated_like(g, mesh)
-    bsh = batch_sharding(mesh, ndim=2)
-    constrain = lambda x: jax.lax.with_sharding_constraint(x, bsh)
-
-    # batch enters replicated and is constrained *in-program* (like
-    # product_embed.make_sharded_step): a partitioned in_sharding would
-    # reject process-local arrays on a multi-host mesh.  The per-host
-    # data plane feeds the node-sharded
-    # builder below, which takes pairs batch-sharded.
-    step = jax.jit(
-        partial(_lp_step_impl, model, opt, num_nodes, constrain=constrain,
-                split_pairs=_concat_hazard(mesh)),
-        in_shardings=(state_sh, g_sh, replicated(mesh)),
-        out_shardings=(state_sh, replicated(mesh)),
-        donate_argnums=(0,),
-    )
-    return step, jax.device_put(state, state_sh), jax.device_put(g, g_sh)
-
-
 def make_node_sharded_step_lp(
     model: HGCNLinkPred,
     opt,
@@ -606,23 +384,26 @@ def make_node_sharded_step_lp(
     # that exchange schedule, False forces the all-gather, "auto" picks
     # by estimated compiled bytes — parallel/node_shard.py doc)
 ):
-    """LP train step whose ENCODER work divides across the mesh.
+    """LP train step whose encoder work divides across ``mesh``.
 
-    `make_sharded_step_lp` shards only the supervision pairs — the
-    full-graph encoder (~95% of step time) is replicated per device.
-    This builder instead node-shards the graph (`parallel/node_shard`):
-    the [N, F] activations, every matmul row, and each shard's slice of
-    the edge aggregation live on one device; the only collective in the
-    encoder is an [N, F] all-gather per layer per direction riding ICI.
-    Per-device FLOPs and HBM bytes scale ~1/ndev (asserted by
-    tests/parallel/test_node_sharded.py's compiled-cost check).
+    The graph is node-sharded over the data-like axes
+    (`parallel/node_shard`): the [N, F] activations, every matmul row,
+    and each shard's slice of the edge aggregation live on one device;
+    the only collective in the encoder is an [N, F] exchange per layer
+    per direction riding ICI.  Per-device FLOPs and HBM bytes scale
+    ~1/ndev (asserted by tests/parallel/test_node_sharded.py's
+    compiled-cost check).  The supervision batch (positives + sampled
+    negatives) is sharded over the same axes, so XLA inserts the
+    gradient all-reduce; 2-D kernels are column-sharded over the
+    ``model`` axis when present (`parallel/tp.tp_param_shardings`) and
+    optimizer moments are co-located with their parameter shards.
 
     Mean aggregation uses the involution backward (no cross-shard
     scatter); attention works too — the receiver partition keeps its
     segment softmax shard-local (`parallel.node_shard.
     node_sharded_att_aggregate`, autodiff collectives).  Returns
     ``(step, placed_state, placed_graph)``; call as
-    ``state, loss = step(state, nsg, train_pos)``.
+    ``state, loss = step(state, nsg, train_pos)``; ``state`` is donated.
     """
     from hyperspace_tpu.parallel.mesh import batch_sharding, replicated
     from hyperspace_tpu.parallel.node_shard import graph_shardings, shard_graph
@@ -767,37 +548,6 @@ def train_step_nc(
     train_mask: jax.Array,  # [N] bool
 ):
     return _nc_step_impl(model, opt, state, g, labels, train_mask)
-
-
-def make_sharded_step_nc(
-    model: HGCNNodeClf,
-    opt,
-    mesh,
-    state: TrainState,
-    g: graph_data.DeviceGraph,
-):
-    """dp×tp NC train step over ``mesh`` — the NC twin of
-    `make_sharded_step_lp`: per-node cross-entropy terms shard over the
-    data-like axes (GSPMD partitions the node-dim compute and inserts the
-    gradient all-reduce), 2-D kernels column-shard over ``model``.
-    Returns ``(step, placed_state, placed_graph)``; call as
-    ``state, loss = step(state, g, labels, train_mask)``.
-    """
-    from hyperspace_tpu.parallel.mesh import batch_sharding, replicated
-    from hyperspace_tpu.parallel.tp import replicated_like, state_shardings
-
-    state_sh = state_shardings(state, state.params, mesh)
-    g_sh = replicated_like(g, mesh)
-    nsh = batch_sharding(mesh, ndim=1)
-    constrain = lambda x: jax.lax.with_sharding_constraint(x, nsh)
-
-    step = jax.jit(
-        partial(_nc_step_impl, model, opt, constrain=constrain),
-        in_shardings=(state_sh, g_sh, replicated(mesh), replicated(mesh)),
-        out_shardings=(state_sh, replicated(mesh)),
-        donate_argnums=(0,),
-    )
-    return step, jax.device_put(state, state_sh), jax.device_put(g, g_sh)
 
 
 @partial(jax.jit, static_argnames=("model",))

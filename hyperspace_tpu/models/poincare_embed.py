@@ -349,8 +349,7 @@ def make_train_step(cfg: PoincareEmbedConfig):
 # measured 3.6x slower than the dense step on TPU at WordNet scale, because
 # the table work it saves is smaller than the sort latency it adds.  The
 # planned variant moves ALL index preparation to the host, amortized over a
-# chunk of steps (the `make_planned_pairs` philosophy from the HGCN LP
-# decoder applied to embedding batches):
+# chunk of steps:
 #
 # - batches + negatives are drawn on host (numpy, vectorized over the chunk);
 # - each step's flat index multiset is argsorted ONCE on host, yielding:

@@ -248,6 +248,7 @@ def _aggregate(h: jax.Array, alpha, g, agg_dtype) -> jax.Array:
             # straggler subset pays the planned passes.  The two
             # [N, F+1] (num | den) partials add and divide ONCE.
             from hyperspace_tpu.nn.scatter import (
+                att_aggregate_planned,
                 att_combine,
                 att_partial_planned,
                 cluster_att_partial,
@@ -259,11 +260,10 @@ def _aggregate(h: jax.Array, alpha, g, agg_dtype) -> jax.Array:
                 nd = nd + att_partial_planned(
                     h, alpha_s, alpha_r, cl.s_send, cl.s_recv,
                     cl.s_rev_local, cl.s_mask, cl.s_plan, n, agg_dtype, 0.2)
-            else:
-                nd = att_partial_planned(
-                    h, alpha_s, alpha_r, senders, receivers,
-                    g.rev_perm, edge_mask, g.plan, n, agg_dtype, 0.2)
-            return att_combine(nd, h.dtype)
+                return att_combine(nd, h.dtype)
+            return att_aggregate_planned(
+                h, alpha_s, alpha_r, senders, receivers,
+                g.rev_perm, edge_mask, g.plan, n, agg_dtype, 0.2)
         logits = bounded_att_logits(alpha_s[senders] + alpha_r[receivers])
         w = segment_softmax(logits, receivers, n, mask=edge_mask,
                             indices_are_sorted=sorted_fast)

@@ -126,112 +126,6 @@ def _agg_bwd(num_segments, with_dw, res, g):
 sym_segment_aggregate.defvjp(_agg_fwd, _agg_bwd)
 
 
-# --- per-edge scalar picks with planned-scatter VJPs --------------------------
-#
-# logits_e = α_src[s_e] + α_dst[r_e] (GAT-style attention) backpropagates a
-# per-edge scalar into per-node scalars: a scatter-add that XLA serializes
-# (sorted or not).  Both directions route through the block-CSR scalar
-# reduction instead — the sender direction via the involution π
-# (s∘π = r, same identity as sym_segment_aggregate).
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def pick_senders(alpha, senders, receivers, rev_perm, pb, pc, pf,
-                 num_segments: int):
-    """alpha[senders] with a receiver-sorted planned-scatter VJP."""
-    return alpha[senders]
-
-
-def _ps_fwd(alpha, senders, receivers, rev_perm, pb, pc, pf, num_segments):
-    return alpha[senders], (receivers, rev_perm, pb, pc, pf)
-
-
-def _ps_bwd(num_segments, res, g):
-    from hyperspace_tpu.kernels.segment import csr_segment_reduce_1d
-
-    receivers, rev_perm, pb, pc, pf = res
-    d = csr_segment_reduce_1d(involute(rev_perm, g), receivers,
-                              (pb, pc, pf), num_segments, op="sum")
-    return d, None, None, None, None, None, None
-
-
-pick_senders.defvjp(_ps_fwd, _ps_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def pick_receivers(alpha, receivers, pb, pc, pf, num_segments: int):
-    """alpha[receivers] with a planned-scatter VJP (receivers sorted)."""
-    return alpha[receivers]
-
-
-def _pr_fwd(alpha, receivers, pb, pc, pf, num_segments):
-    return alpha[receivers], (receivers, pb, pc, pf)
-
-
-def _pr_bwd(num_segments, res, g):
-    from hyperspace_tpu.kernels.segment import csr_segment_reduce_1d
-
-    receivers, pb, pc, pf = res
-    d = csr_segment_reduce_1d(g, receivers, (pb, pc, pf),
-                              num_segments, op="sum")
-    return d, None, None, None, None
-
-
-pick_receivers.defvjp(_pr_fwd, _pr_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def planned_segment_sum_1d(vals, receivers, pb, pc, pf, num_segments: int):
-    """Differentiable per-segment scalar sum on the CSR plan.
-
-    Forward: ``kernels.segment.csr_segment_reduce_1d(op="sum")``;
-    VJP: ``d_vals = ḡ[receivers]`` — one row gather, no scatter.
-    """
-    from hyperspace_tpu.kernels.segment import csr_segment_reduce_1d
-
-    return csr_segment_reduce_1d(vals, receivers, (pb, pc, pf),
-                                 num_segments, op="sum")
-
-
-def _pss_fwd(vals, receivers, pb, pc, pf, num_segments):
-    return (planned_segment_sum_1d(vals, receivers, pb, pc, pf, num_segments),
-            receivers)
-
-
-def _pss_bwd(num_segments, receivers, g):
-    return g[receivers], None, None, None, None
-
-
-planned_segment_sum_1d.defvjp(_pss_fwd, _pss_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def planned_segment_max_1d(vals, receivers, pb, pc, pf, num_segments: int):
-    """Per-segment scalar max on the CSR plan, differentiation-safe.
-
-    The cotangent is zero by construction: the only use is the stable-
-    softmax max shift, which the softmax value is invariant to (callers
-    treat it as a constant).  Without this wrapper jax.grad would trace
-    the pallas_call's missing JVP rule even under stop_gradient.
-    """
-    from hyperspace_tpu.kernels.segment import csr_segment_reduce_1d
-
-    return csr_segment_reduce_1d(vals, receivers, (pb, pc, pf),
-                                 num_segments, op="max")
-
-
-def _psm_fwd(vals, receivers, pb, pc, pf, num_segments):
-    return (planned_segment_max_1d(vals, receivers, pb, pc, pf, num_segments),
-            receivers)
-
-
-def _psm_bwd(num_segments, receivers, g):
-    return (jnp.zeros(receivers.shape, g.dtype), None, None, None, None)
-
-
-planned_segment_max_1d.defvjp(_psm_fwd, _psm_bwd)
-
-
 # --- cluster-pair aggregation (kernels/cluster.py) with the same symmetric
 # backward: clustered and straggler subsets are each closed under the edge
 # involution (equal pair/mirror-pair counts), so dh runs the identical

@@ -10,7 +10,7 @@ replicated.  Activations between layers are left to GSPMD, which keeps the
 feature axis sharded through elementwise chains and re-gathers only where a
 contraction needs it.
 
-Used by `models/hgcn.py::make_sharded_step_*` (dp×tp HGCN training) and by
+Used by `models/hgcn.py::make_node_sharded_step_*` (dp×tp HGCN training) and by
 `__graft_entry__.dryrun_multichip`.
 """
 

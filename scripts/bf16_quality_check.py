@@ -20,23 +20,17 @@ import json
 
 
 def configs(hgcn, jnp, feat_dim, which="all"):
-    """(name, cfg, step) triples; step "lp" = train_step_lp (fresh uv
-    negatives), "pairs" = train_step_lp_pairs (fully-planned decoder,
-    corrupt-v negatives)."""
+    """(name, cfg) pairs, all stepped by ``train_step_lp``."""
     base = dict(feat_dim=feat_dim, hidden_dims=(128, 32), kind="lorentz")
     all_ = [
-        ("f32", hgcn.HGCNConfig(**base), "lp"),
-        ("f32_aggbf16", hgcn.HGCNConfig(**base, agg_dtype=jnp.bfloat16),
-         "lp"),
-        ("bf16", hgcn.HGCNConfig(**base, dtype=jnp.bfloat16), "lp"),
-        # the r02 bench candidate: f32 encoder, bf16 messages, bf16
-        # decoder pass, fully-planned pairs step (987 k samples/s/chip)
-        ("pairs_f32_aggbf16_decbf16",
+        ("f32", hgcn.HGCNConfig(**base)),
+        ("f32_aggbf16", hgcn.HGCNConfig(**base, agg_dtype=jnp.bfloat16)),
+        ("bf16", hgcn.HGCNConfig(**base, dtype=jnp.bfloat16)),
+        # configs/hgcn_arxiv_lp.yaml's lanes: f32 encoder, bf16
+        # messages, bf16 decoder pass
+        ("f32_aggbf16_decbf16",
          hgcn.HGCNConfig(**base, agg_dtype=jnp.bfloat16,
-                         decoder_dtype=jnp.bfloat16), "pairs"),
-        # its f32 control through the same step/negative sampler, so the
-        # dtype effect is isolated from the sampler change
-        ("pairs_f32", hgcn.HGCNConfig(**base), "pairs"),
+                         decoder_dtype=jnp.bfloat16)),
     ]
     if which == "all":
         return all_
@@ -68,11 +62,9 @@ def time_phase(which: str = "all"):
     split, x = make_split(HB.ARXIV_NODES)
     n = HB.ARXIV_NODES
     ga = hgcn._device_graph(split.graph)
-    sel = configs(hgcn, jnp, x.shape[1], which)
-    steppers = _steppers(hgcn, split, n, {k for _, _, k in sel})
-    for name, cfg, kind in sel:
+    step = _stepper(hgcn, split, n)
+    for name, cfg in configs(hgcn, jnp, x.shape[1], which):
         model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
-        step = steppers[kind]
         state, loss = step(model, opt, state, ga)
         jax.device_get(loss)
         best = float("inf")
@@ -88,24 +80,12 @@ def time_phase(which: str = "all"):
               flush=True)
 
 
-def _steppers(hgcn, split, n, kinds):
-    """step(model, opt, state, ga) closures, built only for ``kinds``
-    (the pairs prep sorts millions of host-side indices — skip it when no
-    selected config needs it)."""
+def _stepper(hgcn, split, n):
+    """step(model, opt, state, ga) over the split's training positives."""
     import jax.numpy as jnp
 
-    out = {}
-    if "lp" in kinds:
-        train_pos = jnp.asarray(split.train_pos)
-        out["lp"] = lambda m, o, st, g: hgcn.train_step_lp(
-            m, o, n, st, g, train_pos)
-    if "pairs" in kinds:
-        pos = hgcn.make_planned_pairs(split.train_pos, n)
-        neg_u, neg_plan = hgcn.make_static_negatives(
-            n, int(pos.u.shape[0]), seed=0)
-        out["pairs"] = lambda m, o, st, g: hgcn.train_step_lp_pairs(
-            m, o, n, st, g, pos, neg_u, neg_plan)
-    return out
+    train_pos = jnp.asarray(split.train_pos)
+    return lambda m, o, st, g: hgcn.train_step_lp(m, o, n, st, g, train_pos)
 
 
 def quality_phase(quality_nodes: int, steps: int, seeds: int,
@@ -118,10 +98,8 @@ def quality_phase(quality_nodes: int, steps: int, seeds: int,
     split, x = make_split(quality_nodes)
     n = quality_nodes
     ga = hgcn._device_graph(split.graph)
-    sel = configs(hgcn, jnp, x.shape[1], which)
-    steppers = _steppers(hgcn, split, n, {k for _, _, k in sel})
-    for name, cfg, kind in sel:
-        step = steppers[kind]
+    step = _stepper(hgcn, split, n)
+    for name, cfg in configs(hgcn, jnp, x.shape[1], which):
         for seed in range(seeds):
             model, opt, state = hgcn.init_lp(cfg, split.graph, seed=seed)
             for _ in range(steps):

@@ -61,27 +61,22 @@ def test_step_equals_the_step_with_the_wrap_off(use_att, kind, learn_c, mode,
         assert "pair_scatter_sum" not in text(off_step, state, ga, train_pos)
 
 
-@pytest.mark.parametrize("builder", ["pair_sharded", "node_sharded"])
 @pytest.mark.parametrize("axes", [{"data": 8}, {"data": 4, "model": 2}],
                          ids=["dp", "dp_tp"])
-def test_mesh_steps_keep_the_scatter_add(axes, builder, monkeypatch):
+def test_mesh_steps_keep_the_scatter_add(axes, monkeypatch):
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
     from hyperspace_tpu.parallel.mesh import make_mesh
 
     # where kernels are on, a wrapped decoder would show its call
     monkeypatch.setenv("HYPERSPACE_KERNELS", "interpret")
-    model, state, ga, split = _setup(False, "lorentz", False)
+    model, state, _, split = _setup(False, "lorentz", False)
     mesh = make_mesh(axes)
     opt = hgcn.make_optimizer(model.cfg)
     train_pos = jnp.asarray(hgcn.round_up_pairs(split.train_pos, mesh))
     n = split.graph.num_nodes
-    if builder == "pair_sharded":
-        step, state, g = hgcn.make_sharded_step_lp(model, opt, n, mesh,
-                                                   state, ga)
-    else:
-        step, state, g = hgcn.make_node_sharded_step_lp(model, opt, n, mesh,
-                                                        state, split)
+    step, state, g = hgcn.make_node_sharded_step_lp(model, opt, n, mesh,
+                                                    state, split)
     text = step.lower(state, g, train_pos).as_text(debug_info=True)
     assert "pair_scatter_sum" not in text
     assert "stablehlo.scatter" in text and "pair_dist" in text

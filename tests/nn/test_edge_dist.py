@@ -1,4 +1,4 @@
-"""Planned edge-distance ops (nn/edge_dist.py): values and gradients —
+"""The LP decoder's pair distances (nn/edge_dist.py): values and gradients —
 including learned-curvature cotangents — must match the direct
 ``m.sqdist(z[a], z[b])`` formulation exactly (the reorganized scatter is
 algebraically the same sum)."""
@@ -8,104 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hyperspace_tpu.data import graphs as G
-from hyperspace_tpu.nn.edge_dist import graph_edge_sqdist, pair_sqdist_semi_planned
 from hyperspace_tpu.nn.gcn import make_manifold
-from hyperspace_tpu.kernels.segment import build_csr_plan
-
-
-def _graph(n=60, seed=0):
-    edges, x, labels, k = G.synthetic_hierarchy(num_nodes=n, feat_dim=8, seed=seed)
-    return G.prepare(edges, n, x, pad_multiple=64)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("kind", ["lorentz", "poincare"])
-def test_graph_edge_sqdist_matches_direct(kind, rng):
-    g = _graph()
-    m = make_manifold(kind, 1.0)
-    z = m.random_normal(jax.random.PRNGKey(0), (g.num_nodes, m.ambient_dim(6)),
-                        jnp.float64)
-    s, r, rp = map(jnp.asarray, (g.senders, g.receivers, g.rev_perm))
-    pb, pc, pf = (jnp.asarray(a) for a in g.csr_plan)
-    wmask = jnp.asarray((g.edge_mask & (g.senders != g.receivers)), jnp.float64)
-    t = jnp.asarray(rng.standard_normal(len(g.senders)), jnp.float64) * wmask
-
-    def loss_planned(z, c):
-        d2 = graph_edge_sqdist(z, c, s, r, rp, pb, pc, pf, kind)
-        return jnp.sum(d2 * t)
-
-    def loss_direct(z, c):
-        d2 = make_manifold(kind, c).sqdist(z[s], z[r])
-        return jnp.sum(d2 * t)
-
-    c = jnp.asarray(1.0, jnp.float64)
-    np.testing.assert_allclose(loss_planned(z, c), loss_direct(z, c), rtol=1e-12)
-    (gz1, gc1) = jax.grad(loss_planned, argnums=(0, 1))(z, c)
-    (gz2, gc2) = jax.grad(loss_direct, argnums=(0, 1))(z, c)
-    np.testing.assert_allclose(np.asarray(gz1), np.asarray(gz2),
-                               rtol=1e-9, atol=1e-12)
-    np.testing.assert_allclose(float(gc1), float(gc2), rtol=1e-9)
-
-
-@pytest.mark.slow
-def test_pair_sqdist_semi_planned_matches_direct(rng):
-    n, p = 50, 200
-    m = make_manifold("lorentz", 0.7)
-    z = m.random_normal(jax.random.PRNGKey(1), (n, 7), jnp.float64)
-    u = np.sort(rng.integers(0, n, p)).astype(np.int32)
-    v = rng.integers(0, n, p).astype(np.int32)
-    plan = tuple(jnp.asarray(a) for a in build_csr_plan(u, n))
-    uj, vj = jnp.asarray(u), jnp.asarray(v)
-    t = jnp.asarray(rng.standard_normal(p), jnp.float64)
-
-    def loss_planned(z, c):
-        return jnp.sum(pair_sqdist_semi_planned(z, c, uj, vj, *plan, "lorentz") * t)
-
-    def loss_direct(z, c):
-        return jnp.sum(make_manifold("lorentz", c).sqdist(z[uj], z[vj]) * t)
-
-    c = jnp.asarray(0.7, jnp.float64)
-    np.testing.assert_allclose(loss_planned(z, c), loss_direct(z, c), rtol=1e-12)
-    (gz1, gc1) = jax.grad(loss_planned, argnums=(0, 1))(z, c)
-    (gz2, gc2) = jax.grad(loss_direct, argnums=(0, 1))(z, c)
-    np.testing.assert_allclose(np.asarray(gz1), np.asarray(gz2),
-                               rtol=1e-9, atol=1e-12)
-    np.testing.assert_allclose(float(gc1), float(gc2), rtol=1e-9)
-
-
-@pytest.mark.parametrize("kind", ["lorentz"])
-def test_pair_sqdist_planned_matches_direct(kind, rng):
-    """Fully-planned static pairs (both scatters CSR): values and grads —
-    including curvature cotangents — match the direct formulation."""
-    from hyperspace_tpu.models.hgcn import make_planned_pairs
-    from hyperspace_tpu.nn.edge_dist import pair_sqdist_planned
-
-    n = 80
-    m = make_manifold(kind, 1.0)
-    z = m.random_normal(jax.random.PRNGKey(1), (n, m.ambient_dim(6)),
-                        jnp.float64)
-    pairs = rng.integers(0, n, (300, 2))
-    pp = make_planned_pairs(pairs, n)
-    t = jnp.asarray(rng.standard_normal(300), jnp.float64)
-
-    def loss_planned(z, c):
-        d2 = pair_sqdist_planned(z, c, pp.u, pp.v, *pp.u_plan, pp.v_perm,
-                                 pp.v_sorted, *pp.v_plan, kind)
-        return jnp.sum(d2 * t)
-
-    def loss_direct(z, c):
-        d2 = make_manifold(kind, c).sqdist(z[pp.u], z[pp.v])
-        return jnp.sum(d2 * t)
-
-    c = jnp.asarray(1.0, jnp.float64)
-    np.testing.assert_allclose(loss_planned(z, c), loss_direct(z, c),
-                               rtol=1e-12)
-    (gz1, gc1) = jax.grad(loss_planned, argnums=(0, 1))(z, c)
-    (gz2, gc2) = jax.grad(loss_direct, argnums=(0, 1))(z, c)
-    np.testing.assert_allclose(np.asarray(gz1), np.asarray(gz2),
-                               rtol=1e-9, atol=1e-12)
-    np.testing.assert_allclose(float(gc1), float(gc2), rtol=1e-9)
 
 
 # --- pair_sqdist: any pairs, fresh on every step, nothing from the host -------

@@ -127,37 +127,6 @@ def test_sorted_segment_softmax_matches():
     np.testing.assert_allclose(got, want, rtol=1e-12)
 
 
-def test_pick_vjps_match_gather_autodiff():
-    """pick_senders / pick_receivers: values equal plain gathers, grads
-    equal autodiff of the gathers (planned-scatter VJP correctness,
-    including the sender-side involution)."""
-    from hyperspace_tpu.data.graphs import prepare
-    from hyperspace_tpu.nn.scatter import pick_receivers, pick_senders
-
-    rng = np.random.default_rng(11)
-    n = 24
-    edges = rng.integers(0, n, (40, 2)).astype(np.int32)
-    g = prepare(edges, n, np.zeros((n, 3), np.float32))
-    s, r, rp = map(jnp.asarray, (g.senders, g.receivers, g.rev_perm))
-    pb, pc, pf = (jnp.asarray(a) for a in g.csr_plan)
-    alpha = jnp.asarray(rng.normal(size=n), jnp.float64)
-    t = jnp.asarray(rng.normal(size=len(g.senders)), jnp.float64)
-
-    np.testing.assert_array_equal(
-        np.asarray(pick_senders(alpha, s, r, rp, pb, pc, pf, n)),
-        np.asarray(alpha[s]))
-    np.testing.assert_array_equal(
-        np.asarray(pick_receivers(alpha, r, pb, pc, pf, n)),
-        np.asarray(alpha[r]))
-
-    g1 = jax.grad(lambda a: jnp.sum(pick_senders(a, s, r, rp, pb, pc, pf, n) * t))(alpha)
-    g2 = jax.grad(lambda a: jnp.sum(a[s] * t))(alpha)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-12)
-    g3 = jax.grad(lambda a: jnp.sum(pick_receivers(a, r, pb, pc, pf, n) * t))(alpha)
-    g4 = jax.grad(lambda a: jnp.sum(a[r] * t))(alpha)
-    np.testing.assert_allclose(np.asarray(g3), np.asarray(g4), rtol=1e-12)
-
-
 # --- fused planned attention aggregation (att_aggregate_planned) --------------
 
 
@@ -335,26 +304,21 @@ def _grads_of(op, dtype, g):
         f = lambda h, a_s, a_r: jnp.sum(probe * scatter.att_aggregate_planned(
             h, a_s, a_r, s, r, rp, m, plan, n, agg, 0.2))
         return jax.grad(f, argnums=(0, 1, 2))(h, a_s, a_r)
-    if op == "sym_segment_aggregate":
-        w = jnp.where(m, jnp.asarray(rng.random(len(g.senders)), dtype), 0)
-        f = lambda h, w: jnp.sum(probe.astype(dtype) * (
-            scatter.sym_segment_aggregate(h.astype(dtype), w, s, r, rp,
-                                          *plan, n)))
-        return jax.grad(f, argnums=(0, 1))(h, w)
-    t = jnp.asarray(rng.standard_normal(len(g.senders)), dtype)
-    f = lambda a: jnp.sum(
-        scatter.pick_senders(a.astype(dtype), s, r, rp, *plan, n) * t)
-    return (jax.grad(f)(a_s),)
+    w = jnp.where(m, jnp.asarray(rng.random(len(g.senders)), dtype), 0)
+    f = lambda h, w: jnp.sum(probe.astype(dtype) * (
+        scatter.sym_segment_aggregate(h.astype(dtype), w, s, r, rp,
+                                      *plan, n)))
+    return jax.grad(f, argnums=(0, 1))(h, w)
 
 
 @pytest.mark.parametrize("op", ["att_aggregate_planned",
-                                "sym_segment_aggregate", "pick_senders"])
+                                "sym_segment_aggregate"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 def test_vjps_through_involute_are_the_gather_spellings(dtype, op,
                                                         monkeypatch):
     """Every ``[rev_perm]`` site of nn/scatter.py goes through
-    `involute`; with the helper swapped for the plain gather the three
+    `involute`; with the helper swapped for the plain gather the two
     custom VJPs hand back the same gradients bit for bit (the oracles
     above hold them to autodiff of the naive formulation)."""
     from hyperspace_tpu.nn import scatter

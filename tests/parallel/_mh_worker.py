@@ -32,8 +32,9 @@ def main() -> int:
     ap.add_argument("--crash-at", type=int, default=0)  # 0 = never
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--hgcn", action="store_true",
-                    help="train the sharded HGCN LP step instead of the "
-                         "least-squares toy (north-star workload over DCN)")
+                    help="train the node-sharded HGCN LP step instead of "
+                         "the least-squares toy (north-star workload over "
+                         "DCN)")
     args = ap.parse_args()
 
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -119,10 +120,12 @@ def main() -> int:
 
 
 def run_hgcn(args, mh) -> int:
-    """The north-star workload's library dp step over a real host×data
-    mesh: every process builds the same graph deterministically, the
-    supervision batch is sharded over (host, data), and the gradient
-    all-reduce crosses the process boundary inside XLA (SURVEY.md §3.4:
+    """The north-star workload's mesh step over a real host×data mesh,
+    fed as ``cli.train``'s multihost path feeds it: every process builds
+    the same graph deterministically and device_puts its addressable
+    shards of the partitioned graph, the supervision batch is sharded
+    over (host, data), and the encoder's exchange and the gradient
+    all-reduce cross the process boundary inside XLA (SURVEY.md §3.4:
     Python never communicates across hosts, only collectives do)."""
     import jax
     import jax.numpy as jnp
@@ -136,34 +139,21 @@ def run_hgcn(args, mh) -> int:
         num_nodes=128, feat_dim=8, seed=0)
     split = G.split_edges(edges, 128, x, seed=0, pad_multiple=128)
     cfg = hgcn.HGCNConfig(feat_dim=8, hidden_dims=(16, 8))
-    model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
-    ga = G.to_device(split.graph)
+    model, opt, state = hgcn.init_lp(cfg, split.graph, seed=1)
     train_pos = jnp.asarray(hgcn.round_up_pairs(split.train_pos, mesh))
-    step, state, ga = hgcn.make_sharded_step_lp(
-        model, opt, 128, mesh, state, ga)
+    step, state, nsg = hgcn.make_node_sharded_step_lp(
+        model, opt, 128, mesh, state, split)
+    # per-host data plane: the step takes its supervision batch SHARDED,
+    # so each host contributes only its own row slice and the global
+    # [P, 2] batch is assembled across processes
+    train_pos_g = mh.distribute_batch(train_pos, mesh)
     losses = []
     for _ in range(args.steps):
-        state, loss = step(state, ga, train_pos)
+        state, loss = step(state, nsg, train_pos_g)
         losses.append(float(jax.device_get(loss)))
-
-    # node-sharded path across the same real processes: each process
-    # device_puts its addressable shards of the partitioned graph, and
-    # the encoder's all-gather crosses the host boundary inside XLA
-    model2, opt2, state2 = hgcn.init_lp(cfg, split.graph, seed=1)
-    nstep, state2, nsg = hgcn.make_node_sharded_step_lp(
-        model2, opt2, 128, mesh, state2, split)
-    # per-host data plane: the node-sharded step takes its supervision
-    # batch SHARDED, so each host contributes only its own row slice
-    # and the global [P, 2] batch is assembled across processes
-    train_pos_g = mh.distribute_batch(train_pos, mesh)
-    ns_losses = []
-    for _ in range(args.steps):
-        state2, nloss = nstep(state2, nsg, train_pos_g)
-        ns_losses.append(float(jax.device_get(nloss)))
     if args.pid == 0:
         print("RESULT " + json.dumps({
-            "losses": losses, "ns_losses": ns_losses,
-            "devices": jax.device_count(),
+            "losses": losses, "devices": jax.device_count(),
         }), flush=True)
     return 0
 

@@ -1,13 +1,7 @@
-"""HGCN multi-device training must match single-device (VERDICT r1 #2/#9).
-
-The north-star workload (HGCN LP) trains through
-`models/hgcn.make_sharded_step_lp` on dp-only, tp-only and dp×tp meshes
-over the 8 virtual CPU devices; each must agree with the plain
-single-device step — same PRNG stream both ways, so only collective
-reduction order differs (float tolerance, not bitwise).
-"""
-
-from functools import partial
+"""The HGCN mesh step's state: how `parallel/tp.py` shards it, and that
+it round-trips through a checkpoint with its shardings (8 virtual CPU
+devices).  That the step computes the single-device trajectory is
+tests/parallel/test_node_sharded.py's."""
 
 import jax
 import jax.numpy as jnp
@@ -25,75 +19,6 @@ def _setup(seed=0):
     split = G.split_edges(edges, 192, x, seed=seed, pad_multiple=128)
     cfg = hgcn.HGCNConfig(feat_dim=12, hidden_dims=(16, 8))
     return cfg, split
-
-
-def _run_single(cfg, split, steps, train_pos):
-    model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
-    ga = G.to_device(split.graph)
-    # the mesh steps' own program on one device: `train_step_lp`'s body
-    # with the decoder's sorted VJP off (an identity ``constrain``), so
-    # that sharding is all that differs.  The sorted VJP against this
-    # step is tests/models/test_lp_sorted_vjp.py's, at its own tolerance.
-    step = jax.jit(partial(hgcn._lp_step_impl, model, opt,
-                           split.graph.num_nodes, constrain=lambda x: x))
-    for _ in range(steps):
-        state, loss = step(state, ga, train_pos)
-    return state, loss
-
-
-def _run_sharded(cfg, split, steps, axes, train_pos):
-    model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
-    mesh = make_mesh(axes)
-    ga = G.to_device(split.graph)
-    step, state, ga = hgcn.make_sharded_step_lp(
-        model, opt, split.graph.num_nodes, mesh, state, ga)
-    for _ in range(steps):
-        state, loss = step(state, ga, train_pos)
-    return state, loss
-
-
-@pytest.mark.parametrize("axes", [
-    pytest.param({"data": 8}, marks=pytest.mark.slow),
-    pytest.param({"data": 1, "model": 8}, marks=pytest.mark.slow),
-    # dp×tp — the fast-suite representative.  Red from PR 3 to PR 8
-    # under an (incorrect) "partitioner reduction-order drift"
-    # diagnosis; PR 9 bisected the real op-level cause: the jax of that
-    # time MISCOMPILED `concatenate` whose operands/consumers are
-    # sharded over a subset of a multi-axis mesh's axes — values
-    # garbled, not reordered (the reduced program is kept as
-    # tests/parallel/test_node_sharded.py::
-    # test_gspmd_concat_under_subset_constraint and passes on the
-    # installed jax 0.9.0).
-    # The supervision-pair concat instance was fixed for every mesh by
-    # hgcn.split_pair_logits; this legacy pair-sharded path additionally
-    # hit the bug through the Lorentz time-coordinate concatenates when
-    # tp column-sharding put the model axis on the feature dim — bisect
-    # evidence: poincare/euclidean (no time-coord concat) were EXACT on
-    # this config, lorentz alone returned garbage (~59 vs 0.54 loss at
-    # identical params).  GREEN since every Lorentz lift was rewritten
-    # as pad+add (manifolds/lorentz._pad_last / with_time_coordinate,
-    # bitwise-pinned by tests/manifolds/test_lorentz_padadd.py) — the
-    # xfail that sat here from PR 3 is retired.
-    pytest.param({"data": 4, "model": 2}),
-    pytest.param({"host": 2, "data": 4}, marks=pytest.mark.slow),
-])
-def test_sharded_lp_matches_single_device(axes):
-    if len(jax.devices()) < 8:
-        pytest.skip("needs 8 virtual devices")
-    cfg, split = _setup()
-    steps = 5
-    mesh = make_mesh(axes)
-    train_pos = jnp.asarray(hgcn.round_up_pairs(split.train_pos, mesh))
-    state1, loss1 = _run_single(cfg, split, steps, train_pos)
-    stateN, lossN = _run_sharded(cfg, split, steps, axes, train_pos)
-
-    assert np.isfinite(float(loss1)) and np.isfinite(float(lossN))
-    np.testing.assert_allclose(float(lossN), float(loss1), rtol=2e-5)
-    p1 = jax.tree_util.tree_leaves(state1.params)
-    pN = jax.tree_util.tree_leaves(stateN.params)
-    for a, b in zip(p1, pN):
-        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
-                                   rtol=2e-4, atol=2e-6)
 
 
 def test_tp_shards_kernels_and_colocates_moments():
@@ -119,41 +44,9 @@ def test_tp_shards_kernels_and_colocates_moments():
         assert str(s.spec) in mu_specs
 
 
-def test_sharded_nc_matches_single_device():
-    """NC twin of the LP equivalence: dp×tp sharded step == single device."""
-    if len(jax.devices()) < 8:
-        pytest.skip("needs 8 virtual devices")
-    edges, x, labels, ncls = G.synthetic_hierarchy(
-        num_nodes=192, feat_dim=12, num_classes=4, seed=0)
-    tr, va, te = G.node_split_masks(192, seed=0)
-    g = G.prepare(edges, 192, x, labels=labels, num_classes=ncls,
-                  train_mask=tr, val_mask=va, test_mask=te)
-    cfg = hgcn.HGCNConfig(feat_dim=12, hidden_dims=(16, 8), num_classes=ncls)
-    lab = jnp.asarray(g.labels)
-    mask = jnp.asarray(g.train_mask)
-
-    model, opt, state1 = hgcn.init_nc(cfg, g, seed=0)
-    ga1 = G.to_device(g)
-    for _ in range(5):
-        state1, loss1 = hgcn.train_step_nc(model, opt, state1, ga1, lab, mask)
-
-    model, opt, stateN = hgcn.init_nc(cfg, g, seed=0)
-    mesh = make_mesh({"data": 4, "model": 2})
-    step, stateN, gaN = hgcn.make_sharded_step_nc(
-        model, opt, mesh, stateN, G.to_device(g))
-    for _ in range(5):
-        stateN, lossN = step(stateN, gaN, lab, mask)
-
-    np.testing.assert_allclose(float(lossN), float(loss1), rtol=2e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(state1.params),
-                    jax.tree_util.tree_leaves(stateN.params)):
-        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
-                                   rtol=2e-4, atol=2e-6)
-
-
 @pytest.mark.slow
 def test_sharded_training_checkpoint_resume(tmp_path):
-    """Orbax checkpoint/resume of the dp×tp HGCN step: a run interrupted
+    """Orbax checkpoint/resume of the dp×tp HGCN mesh step: a run interrupted
     at step 3 and resumed must match the uninterrupted 6-step run (the
     sharded state round-trips through the checkpoint with its shardings)."""
     if len(jax.devices()) < 8:
@@ -166,9 +59,8 @@ def test_sharded_training_checkpoint_resume(tmp_path):
 
     def fresh():
         model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
-        ga = G.to_device(split.graph)
-        return hgcn.make_sharded_step_lp(
-            model, opt, split.graph.num_nodes, mesh, state, ga)
+        return hgcn.make_node_sharded_step_lp(
+            model, opt, split.graph.num_nodes, mesh, state, split)
 
     # uninterrupted reference
     step, ref_state, ga = fresh()

@@ -157,7 +157,12 @@ def test_int8_sharded_rank_identical(rng):
                          scan_mode=mode)
         i8, d8 = (np.asarray(a) for a in e8.topk_neighbors(q, K))
         assert np.array_equal(i32, i8), mode
-        assert np.allclose(d32, d8, rtol=1e-6, atol=1e-7), mode
+        # two compiled float32 programs of the same rescoring: the
+        # sharded scan + all-gather + rescore reduces in another order
+        # than the one-device program (largest relative difference read
+        # here 1.2e-6, ten float32 epsilons), so the repo's bar for such
+        # a pair (test_engine_precision.py), not 1e-6
+        assert np.allclose(d32, d8, rtol=1e-5, atol=1e-6), mode
 
 
 # --- lane isolation -----------------------------------------------------------

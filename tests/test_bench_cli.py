@@ -202,7 +202,7 @@ def _fat_result():
             "step_time_s": 0.1293, "num_nodes": 169343, "devices": 1,
             "backend": "tpu", "use_att": False, "lr": 0.01, "loss": 0.31,
             "frac_clustered": 0.391, "reorder": "community",
-            "source": "synthetic", "dtype": "float32", "step": "pairs",
+            "source": "synthetic", "dtype": "float32",
             "poincare_embed_epoch_time_s": 0.174,
             "poincare": {("k%d" % i): float(i) for i in range(120)},
             "hgcn_sampled": {"supervised_samples_per_s": 2.7e5,
