@@ -134,7 +134,28 @@ def device_csr_plan(receivers: jax.Array, num_nodes: int) -> tuple:
     return block, chunk, first
 
 
-def _body(bn: int):
+def selection_precision(dtype) -> jax.lax.Precision:
+    """The MXU passes an EXACT one-hot selection of ``dtype`` values takes.
+
+    A 0/1 one-hot times a value is the value or zero, so a selection
+    matmul that accumulates in float32 is exact as soon as the value
+    survives the MXU's rounding of its operand to bfloat16.  A bfloat16
+    value does: ONE pass, ``DEFAULT``.  A float32 value needs its three
+    bfloat16 pieces; ``HIGHEST`` is the spelling that keeps them, and it
+    is six passes (it splits the one-hot too, whose other two pieces are
+    zero), where ``DEFAULT`` would cost ~1e-3 relative.
+    ``kernels/cluster.py`` follows the same rule ("f32 inputs use
+    HIGHEST").  What it is worth: a work item of ``csr_segment_sum`` is
+    four ``[128, 128] @ [128, dp]`` products; at dp = 128 six passes
+    are 4 x 6 x 2 x 128^3 = 101 MFLOP, 0.51 us of a v5e's 197 TFLOP/s,
+    one pass 0.085 us, and the item went 0.69 -> 0.43 us with the
+    same sums, bit for bit (PERF.md §6, PR 31).
+    """
+    return (jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16
+            else jax.lax.Precision.HIGHEST)
+
+
+def _body(bn: int, precision):
     def body(blk_ref, chk_ref, first_ref, recv_ref, vals_ref, o_ref):
         t = pl.program_id(0)
         b = blk_ref[t]
@@ -151,22 +172,25 @@ def _body(bn: int):
         for j in range(recv.shape[0]):
             oh = (rows == local[j : j + 1, :]).astype(jnp.float32)
             vals = vals_ref[j * 128 : (j + 1) * 128, :].astype(jnp.float32)
-            # HIGHEST: 0/1 one-hot times f32 is an exact selection under the
-            # 3-pass bf16 decomposition; default single-pass costs ~1e-3 rel
             acc += jnp.dot(oh, vals, preferred_element_type=jnp.float32,
-                           precision=jax.lax.Precision.HIGHEST)
+                           precision=precision)
         o_ref[:] += acc
 
     return body
 
 
-def _pallas_csr(vals, recv2d, plan_arrays, num_nodes, bn, bk, interpret):
-    t = plan_arrays[0].shape[0]
-    n_pad = S.round_up(num_nodes, bn)
-    dp = vals.shape[-1]
+def _pallas_csr(values, receivers, plan, num_segments, interpret, precision):
+    """The kernel call: float32 ``[num_segments, F]`` sums of ``values``'
+    rows, the selection matmul at ``precision``."""
+    e, f = values.shape
+    bn, bk = _BN, _BK
+    dp = S.round_up(f, 128)
+    e_pad = S.round_up(e, bk)
+    vals = S.pad_axis(S.pad_axis(values, -1, 128), 0, bk)
+    recv2d = S.pad_axis(receivers, 0, bk).reshape(e_pad // bk, bk // 128, 128)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(t,),
+        grid=(plan[0].shape[0],),
         in_specs=[
             pl.BlockSpec((1, bk // 128, 128),
                          lambda t, blk, chk, first: (chk[t], 0, 0)),
@@ -175,13 +199,14 @@ def _pallas_csr(vals, recv2d, plan_arrays, num_nodes, bn, bk, interpret):
         out_specs=pl.BlockSpec((bn, dp), lambda t, blk, chk, first: (blk[t], 0)),
     )
     out = pl.pallas_call(
-        _body(bn),
+        _body(bn, precision),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_pad, dp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((S.round_up(num_segments, bn), dp),
+                                       jnp.float32),
         name="csr_segment_sum",
         interpret=interpret,
-    )(*plan_arrays, recv2d, vals)
-    return out
+    )(*plan, recv2d, vals)
+    return out[:num_segments, :f]
 
 
 def csr_segment_sum(
@@ -203,15 +228,9 @@ def csr_segment_sum(
         acc = jax.ops.segment_sum(values.astype(acc_dt), receivers,
                                   num_segments, indices_are_sorted=True)
         return acc.astype(values.dtype)
-    e, f = values.shape
-    bn, bk = _BN, _BK
-    dp = S.round_up(f, 128)
-    e_pad = S.round_up(e, bk)
-    vals = S.pad_axis(S.pad_axis(values, -1, 128), 0, bk)
-    recv2d = S.pad_axis(receivers, 0, bk).reshape(e_pad // bk, bk // 128, 128)
-    out = _pallas_csr(vals, recv2d, tuple(plan), num_segments, bn, bk,
-                      S.interpret_flag(m))
-    return out[:num_segments, :f].astype(values.dtype)
+    out = _pallas_csr(values, receivers, tuple(plan), num_segments,
+                      S.interpret_flag(m), selection_precision(values.dtype))
+    return out.astype(values.dtype)
 
 
 def _body_t(bn: int, precision):
@@ -264,10 +283,6 @@ def pair_scatter_sum(
     if e % bk:
         raise ValueError(f"pair_scatter_sum needs whole {bk}-edge chunks, "
                          f"got {e} edges")
-    # a 0/1 one-hot times a bfloat16 value is exact in ONE bf16 pass with
-    # float32 accumulation; float32 values need the three-pass split
-    precision = (jax.lax.Precision.DEFAULT if values_t.dtype == jnp.bfloat16
-                 else jax.lax.Precision.HIGHEST)
     plan = device_csr_plan(receivers, num_segments)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -280,7 +295,7 @@ def pair_scatter_sum(
         out_specs=pl.BlockSpec((f, bn), lambda t, blk, chk, first: (0, blk[t])),
     )
     out = pl.pallas_call(
-        _body_t(bn, precision),
+        _body_t(bn, selection_precision(values_t.dtype)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((f, S.round_up(num_segments, bn)),
                                        jnp.float32),

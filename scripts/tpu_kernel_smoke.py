@@ -42,6 +42,31 @@ def run(name, fn, tol=5e-4):
     return ok
 
 
+def one_pass_against_six(f, recv, plan, n, rng):
+    """``csr_segment_sum``'s bfloat16 path (one MXU pass) against the same
+    kernel forced down the float32 values' six passes, and against
+    ``segment_sum`` accumulated in float32: the first difference is 0 by
+    construction (a 0/1 one-hot times a bfloat16 value), the second is a
+    summation order's."""
+    from hyperspace_tpu.kernels.segment import _pallas_csr
+
+    vals = jnp.asarray(rng.standard_normal((recv.shape[0], f)), jnp.bfloat16)
+    one, six = (_pallas_csr(vals, recv, plan, n, False, p)
+                for p in (jax.lax.Precision.DEFAULT, jax.lax.Precision.HIGHEST))
+    want = jax.ops.segment_sum(vals.astype(jnp.float32), recv, n,
+                               indices_are_sorted=True)
+    d_six = float(jnp.max(jnp.abs(one - six)))
+    d_sum = float(jnp.max(jnp.abs(one - want) / jnp.maximum(jnp.abs(want), 1.0)))
+    ok = bool(d_six == 0.0 and d_sum < 5e-5 and jnp.isfinite(one).all())
+    lanes = -(-f // 128) * 128  # the kernel pads its rows to whole lanes
+    print(json.dumps({"kernel": f"csr_segment_sum_one_pass_{lanes}",
+                      "edges": int(recv.shape[0]), "features": f,
+                      "max_abs_diff_vs_six_pass": d_six,
+                      "max_err_vs_f32_segment_sum": d_sum, "ok": ok}),
+          flush=True)
+    return ok
+
+
 def main():
     from hyperspace_tpu import kernels as K
     from hyperspace_tpu.kernels.segment import build_csr_plan, csr_segment_sum
@@ -101,6 +126,24 @@ def main():
     recv_d = jnp.asarray(recv)
     oks.append(run("csr_segment_sum",
                    lambda: csr_segment_sum(vals, recv_d, plan, 200)))
+
+    # the bf16 lanes' one-pass selection on the mean cell's own stragglers
+    # (1,815,552 edges, the layout `cli.train hgcn --yaml
+    # configs/hgcn_arxiv_lp.yaml` prepares), at conv's 128 lanes and at
+    # the attention arm's 129 -> 256
+    from hyperspace_tpu.data import graphs as G
+
+    edges, feats, labels, _ = G.community_power_law_graph(seed=0)
+    edges, feats, labels, _ = G.apply_locality_order(
+        edges, feats, labels, method="bfs", cache=False)
+    cell = G.split_edges(edges, feats.shape[0], feats, seed=0,
+                         cluster_min_pair=G.cluster_min_pair_for(False),
+                         cache=False).graph
+    s_recv = jnp.asarray(cell.cluster_split.s_recv)
+    s_plan = tuple(jnp.asarray(a_) for a_ in cell.cluster_split.s_plan)
+    for f in (128, 129):
+        oks.append(one_pass_against_six(f, s_recv, s_plan, cell.num_nodes,
+                                        rng))
 
     # the LP decoder's call: rows handed over transposed, the plan built
     # on the device from ids no host has seen, bf16 rows in one MXU pass

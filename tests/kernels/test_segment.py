@@ -10,36 +10,153 @@ import pytest
 
 from hyperspace_tpu.kernels.segment import build_csr_plan, csr_segment_sum
 
+HIGHEST, DEFAULT = jax.lax.Precision.HIGHEST, jax.lax.Precision.DEFAULT
+
 
 def _run(receivers, vals, n):
     plan = tuple(jnp.asarray(a) for a in build_csr_plan(receivers, n))
     return csr_segment_sum(jnp.asarray(vals), jnp.asarray(receivers), plan, n)
 
 
+def _accumulator(receivers, vals, n, precision=None):
+    """The kernel's float32 sums before ``csr_segment_sum`` casts them to
+    the values' dtype, with the body built at ``precision`` (default:
+    what the values' dtype gets)."""
+    from hyperspace_tpu.kernels.segment import (
+        _pallas_csr,
+        selection_precision,
+    )
+
+    if precision is None:
+        precision = selection_precision(vals.dtype)
+    plan = tuple(jnp.asarray(a) for a in build_csr_plan(receivers, n))
+    out = _pallas_csr(jnp.asarray(vals), jnp.asarray(receivers), plan, n,
+                      True, precision)
+    assert out.dtype == jnp.float32 and out.shape == (n, vals.shape[1])
+    return out
+
+
+def _check_against_segment_sum(r, vals, n, rtol, atol):
+    """Float32 values: the call against ``segment_sum``.  bfloat16 values:
+    the kernel's float32 accumulator against ``segment_sum`` of the same
+    values accumulated in float32, under the SAME tolerance (a summation
+    order's, not bfloat16's), and the call is that accumulator rounded
+    once."""
+    want = jax.ops.segment_sum(vals.astype(jnp.float32), jnp.asarray(r), n)
+    got = _run(r, vals, n)
+    assert got.dtype == vals.dtype
+    if vals.dtype == jnp.bfloat16:
+        acc = _accumulator(r, vals, n)
+        assert jnp.array_equal(got, acc.astype(jnp.bfloat16))
+        got = acc
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=rtol, atol=atol)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize(
     "n,e,f", [(300, 2000, 17), (50, 64, 128), (1000, 5000, 64), (7, 3, 5)]
 )
-def test_matches_segment_sum(n, e, f, rng, interp):
+def test_matches_segment_sum(n, e, f, dtype, rng, interp):
     r = np.sort(rng.integers(0, n, e)).astype(np.int32)
-    vals = rng.standard_normal((e, f)).astype(np.float32)
-    got = _run(r, vals, n)
-    want = jax.ops.segment_sum(jnp.asarray(vals), jnp.asarray(r), n)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    vals = jnp.asarray(rng.standard_normal((e, f)), dtype)
+    _check_against_segment_sum(r, vals, n, rtol=1e-5, atol=1e-5)
 
 
-def test_hub_node_and_empty_segments(rng, interp):
+def _hub_layout(rng):
     # one node receives 90% of edges; most segments empty
-    n, e, f = 500, 4000, 32
+    n, e = 500, 4000
     r = np.where(rng.random(e) < 0.9, 137, rng.integers(0, n, e))
-    r = np.sort(r).astype(np.int32)
-    vals = rng.standard_normal((e, f)).astype(np.float32)
-    got = _run(r, vals, n)
-    want = jax.ops.segment_sum(jnp.asarray(vals), jnp.asarray(r), n)
+    return np.sort(r).astype(np.int32), n
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_hub_node_and_empty_segments(dtype, rng, interp):
+    r, n = _hub_layout(rng)
+    vals = jnp.asarray(rng.standard_normal((len(r), 32)), dtype)
     # a ~3600-edge hub sums in a different order than segment_sum's chain:
     # tolerance scales with sqrt(deg)·eps
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=5e-4)
+    _check_against_segment_sum(r, vals, n, rtol=1e-4, atol=5e-4)
+
+
+@pytest.mark.parametrize("f", [32, 128, 129])
+@pytest.mark.parametrize("layout", ["hub", "padding_tail"])
+def test_one_pass_is_six_passes_bit_for_bit_on_bf16(layout, f, rng, interp):
+    """A 0/1 one-hot times a bfloat16 value, summed in float32: the body
+    built at DEFAULT gives what the body built at HIGHEST gives.  Here,
+    on the CPU, the interpreter multiplies in float32 under either, so
+    this holds the wiring; the chip's answer is
+    ``scripts/tpu_kernel_smoke.py``'s ``csr_segment_sum_one_pass_*``
+    lines."""
+    if layout == "hub":
+        r, n = _hub_layout(rng)
+        vals = rng.standard_normal((len(r), f))
+    else:  # the layout's padding: receivers n - 1 with zero rows
+        n = 100
+        r = np.concatenate([np.sort(rng.integers(0, n, 700)),
+                            np.full(300, n - 1)]).astype(np.int32)
+        vals = np.concatenate([rng.standard_normal((700, f)),
+                               np.zeros((300, f))])
+    vals = jnp.asarray(vals, jnp.bfloat16)
+    one = _accumulator(r, vals, n, DEFAULT)
+    six = _accumulator(r, vals, n, HIGHEST)
+    assert jnp.array_equal(one, six)
+    assert jnp.array_equal(one, _accumulator(r, vals, n))
+    assert bool(jnp.any(one != 0))
+
+
+def _kernel_dots(fn, *args):
+    """The ``dot_general`` equations inside the Pallas kernel that
+    ``fn(*args)`` traces, whatever ``pl.when`` nests them in."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub)
+
+    calls = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return calls[0].params["name"], list(walk(calls[0].params["jaxpr"]))
+
+
+@pytest.mark.parametrize("kernel", ["csr_segment_sum", "pair_scatter_sum"])
+@pytest.mark.parametrize("dtype,precision", [("bfloat16", DEFAULT),
+                                             ("float32", HIGHEST),
+                                             ("float16", HIGHEST)])
+def test_selection_matmul_takes_the_passes_its_values_need(
+        kernel, dtype, precision, rng, monkeypatch):
+    """The counter that says the one-pass path engaged is static: the
+    traced kernel's matmuls carry DEFAULT for bfloat16 values and HIGHEST
+    for float32 (and anything else), in both kernels that take it from
+    ``selection_precision``; float32 accumulation either way."""
+    from hyperspace_tpu.kernels.segment import (
+        pair_scatter_sum,
+        selection_precision,
+    )
+
+    assert selection_precision(jnp.dtype(dtype)) == precision
+    monkeypatch.setenv("HYPERSPACE_KERNELS", "pallas")
+    n = 300
+    if kernel == "csr_segment_sum":
+        r = np.sort(rng.integers(0, n, 2000)).astype(np.int32)
+        plan = tuple(jnp.asarray(a) for a in build_csr_plan(r, n))
+        name, dots = _kernel_dots(
+            lambda v: csr_segment_sum(v, jnp.asarray(r), plan, n),
+            jnp.zeros((2000, 17), dtype))
+    else:
+        ids = jnp.asarray(_sorted_ids("uniform", n, 1024, rng))
+        name, dots = _kernel_dots(lambda v: pair_scatter_sum(v, ids, n),
+                                  jnp.zeros((33, ids.shape[0]), dtype))
+    assert name == kernel
+    assert len(dots) == 4  # one per 128-edge sub-chunk of a 512-edge chunk
+    for eqn in dots:
+        assert eqn.params["precision"] == (precision, precision)
+        assert eqn.params["preferred_element_type"] == jnp.float32
 
 
 def test_zero_padding_tail_is_inert(rng, interp):

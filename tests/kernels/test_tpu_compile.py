@@ -116,15 +116,28 @@ def _arg(one_chip):
 # --- the graph kernels, on the real plan shapes -------------------------------
 
 
-def test_csr_segment_sum_bf16(one_chip, arxiv_split):
+@pytest.mark.parametrize("shape", ["arxiv_128", "att_stragglers_129",
+                                   "mag_shard_128"])
+def test_csr_segment_sum_bf16(one_chip, arxiv_split, shape):
+    """The one-pass bf16 kernel at the three cells' shapes: the mean
+    cell's whole edge list at 128 lanes; the attention cell's 1,571,840
+    stragglers at conv0's 129 ``[num | den]`` lanes (padded to 256); the
+    four-chip cell's shard, 6,393,344 padded edges into 368,256 rows.
+    The last two plans have their greatest length, chunks + node blocks
+    (a compile reads a plan's shape only)."""
     from hyperspace_tpu.kernels.segment import csr_segment_sum
 
     g = arxiv_split.graph
-    n, e = g.num_nodes, g.senders.shape[0]
     A = _arg(one_chip)
-    plan = _shapes(tuple(jnp.asarray(a) for a in g.csr_plan), one_chip)
+    if shape == "arxiv_128":
+        n, e, f = g.num_nodes, g.senders.shape[0], ARXIV_FEATS
+        plan = _shapes(tuple(jnp.asarray(a) for a in g.csr_plan), one_chip)
+    else:
+        n, e, f = {"att_stragglers_129": (g.num_nodes, 1_571_840, 129),
+                   "mag_shard_128": (368_256, 6_393_344, 128)}[shape]
+        plan = (A((e // 512 + -(-n // 128),), I32),) * 3
     _compile(lambda v, r, p: csr_segment_sum(v, r, p, n),
-             A((e, ARXIV_FEATS), BF16), A((e,), I32), plan, n_kernels=1)
+             A((e, f), BF16), A((e,), I32), plan, n_kernels=1)
 
 
 def test_csr_segment_expand_1d_at_the_cell_shape(one_chip, arxiv_split):
