@@ -10,6 +10,7 @@ optimizer — no hidden globals.
     python -m hyperspace_tpu.cli.train hybonet --yaml exp.yaml
     python -m hyperspace_tpu.cli.train hvae steps=200
     python -m hyperspace_tpu.cli.train product multihost=true
+    python -m hyperspace_tpu.cli.train looplm --yaml configs/looplm_ouro_2p6b.yaml
 
 Each run writes JSONL metrics (``--log``), optional orbax checkpoints
 (``--ckpt-dir``), and prints one final JSON line of results.
@@ -750,7 +751,111 @@ def run_product(run: RunConfig, overrides: dict):
             "curvatures": pme.curvatures(cfg, state.params)}
 
 
+# keys of a published Ouro ``config.json`` that this trainer holds fixed:
+# any other value is a model this code does not run (as the CLI's yaml
+# loader prints them)
+_LOOPLM_FIXED = {"model_type": "ouro", "rope_scaling": "None",
+                 "sliding_window": "None", "use_sliding_window": "False"}
+# ... and keys that say nothing to a training step (inference's exit
+# threshold, the window layers of a sliding window that is off)
+_LOOPLM_UNUSED = ("max_window_layers", "early_exit_threshold")
+
+
+def _looplm_config(run: RunConfig, overrides: dict):
+    """(LoopLMConfig, stream arguments) from a published config's keys
+    plus the job's."""
+    from hyperspace_tpu.models import looplm
+
+    overrides = dict(overrides)
+    for key, want in _LOOPLM_FIXED.items():
+        got = str(overrides.pop(key, want))
+        if got != want:
+            raise SystemExit(f"looplm: {key}={got!r} is not supported "
+                             f"(this trainer runs {key}={want})")
+    for key in _LOOPLM_UNUSED:
+        overrides.pop(key, None)
+    kinds = set(json.loads(overrides.pop("layer_types", "[]")))
+    if kinds - {"full_attention"}:
+        raise SystemExit(f"looplm: layer_types {sorted(kinds)}: only "
+                         "full_attention layers are supported")
+    max_pos = int(overrides.pop("max_position_embeddings", 0))
+    stream_kw = {"num_tokens": int(overrides.pop("stream_tokens", 1 << 16))}
+    try:
+        cfg = apply_overrides(looplm.LoopLMConfig(),
+                              _precision_default(run, overrides))
+    except ValueError as e:  # LoopLMConfig's own checks
+        raise SystemExit(f"looplm: {e}") from None
+    if max_pos and cfg.sequence_length > max_pos:
+        raise SystemExit(f"looplm: sequence_length={cfg.sequence_length} "
+                         f"exceeds max_position_embeddings={max_pos}")
+    return cfg, stream_kw
+
+
+def _looplm_gauges(cfg, stepper, spc: int, every: int):
+    """``stepper`` with the registry's looplm/* gauges set from the
+    state's stats vector whenever a call crosses a log boundary (the
+    cadence ``run_loop`` logs at; one small host fetch there)."""
+    from hyperspace_tpu.models import looplm
+    from hyperspace_tpu.telemetry import registry as telem
+
+    t = cfg.total_ut_steps
+    telem.set_gauge("looplm/ut_steps", t)
+    telem.set_gauge("looplm/tokens_per_step",
+                    cfg.sequence_length * cfg.sequences_per_step)
+    done = [0]
+
+    def stepped(state):
+        state, loss = stepper(state)
+        prev, done[0] = done[0], done[0] + spc
+        if done[0] // every > prev // every:
+            st = looplm.read_stats(cfg, state.stats)  # hyperlint: disable=host-sync-in-hot-path — once a log boundary, beside the loop's own loss fetch
+            for i in range(t):
+                telem.set_gauge(f"looplm/exit_prob_t{i + 1}",  # telemetry-catalog: looplm/exit_prob_t<t>
+                                st["exit_prob"][i])
+                telem.set_gauge(f"looplm/ce_t{i + 1}",  # telemetry-catalog: looplm/ce_t<t>
+                                st["ce"][i])
+            telem.set_gauge("looplm/expected_exit_step", sum(
+                (i + 1) * p for i, p in enumerate(st["exit_prob"])))
+        return state, loss
+
+    return stepped
+
+
+def run_looplm(run: RunConfig, overrides: dict):
+    _reject_accum(run, "looplm")
+    from hyperspace_tpu.data import text as T
+    from hyperspace_tpu.models import looplm
+
+    cfg, stream_kw = _looplm_config(run, overrides)
+    tokens, source = T.load_token_stream(
+        run.data_root, vocab_size=cfg.vocab_size, **stream_kw)
+    need = cfg.sequence_length * cfg.sequences_per_step + 1
+    if tokens.size < need or int(tokens.max()) >= cfg.vocab_size:
+        raise SystemExit(
+            f"looplm: the token stream ({tokens.size} tokens, largest id "
+            f"{int(tokens.max())}) does not fit a step of {need} tokens "
+            f"over a vocabulary of {cfg.vocab_size}")
+    data = {"dataset": "token_stream", "source": source,
+            "num_tokens": int(tokens.size),
+            "tokens_per_step": need - 1}
+    opt, state = looplm.init_state(cfg, seed=run.seed)
+    stream = jnp.asarray(tokens, jnp.int32)
+    if run.scan_chunk > 1:
+        run = _chunk_run(run)
+    stepper, spc = _chunked(
+        run, lambda st: looplm.train_step(cfg, opt, st, stream))
+    stepper = _looplm_gauges(cfg, stepper, spc, run.eval_every or 50)
+    state, loss = _train_loop(run, state, stepper, steps_per_call=spc,
+                              data=data)
+    stats = looplm.read_stats(cfg, state.stats)
+    return {"workload": "looplm", **data, "steps": int(state.step),
+            "loss": float(loss), "ce": stats["ce"],
+            "exit_prob": stats["exit_prob"],
+            "grad_norm": stats["grad_norm"]}
+
+
 WORKLOADS = {
+    "looplm": run_looplm,
     "poincare": run_poincare,
     "hgcn": run_hgcn,
     "hybonet": run_hybonet,

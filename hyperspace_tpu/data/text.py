@@ -101,3 +101,67 @@ def load_text(name: str, root: str | None = None, **synth_kw) -> tuple[TextDatas
         if os.path.exists(path):
             return load_tsv(path), "disk"
     return synthetic_text(**synth_kw), "synthetic"
+
+
+# --- a packed token stream for language-model pretraining (models/looplm.py) --
+
+EOD_ID = 0
+
+
+def synthetic_token_stream(
+    num_tokens: int,
+    vocab_size: int,
+    zipf_exponent: float = 1.0,
+    doc_len_median: float = 512.0,
+    doc_len_sigma: float = 1.2,
+    doc_len_min: int = 16,
+    doc_len_max: int = 4096,
+    seed: int = 0,
+) -> np.ndarray:
+    """``num_tokens`` int32 ids, packed without padding: documents of
+    log-normal length (clipped to [min, max]) whose ids are Zipf-
+    distributed over 1 … vocab_size-1 (rank r with weight r^-exponent),
+    each closed by ``EOD_ID``.  No network here, so the stream is
+    generated; its shape (a long-tailed unigram law, documents far
+    shorter and a few longer than the context) is what a packer feeds a
+    pretraining job."""
+    rng = np.random.default_rng(seed)
+    ranks = np.arange(1, vocab_size, dtype=np.float64)
+    cdf = np.cumsum(ranks ** -float(zipf_exponent))
+    ids = 1 + np.searchsorted(cdf, rng.random(num_tokens) * cdf[-1])
+    out = np.minimum(ids, vocab_size - 1).astype(np.int32)
+    # document ends: draw lengths until they cover the stream
+    mean_len = doc_len_median * np.exp(0.5 * doc_len_sigma ** 2)
+    draw = int(2 * num_tokens / max(min(mean_len, doc_len_max), 1)) + 16
+    ends = np.empty(0, np.int64)
+    while ends.size == 0 or ends[-1] < num_tokens:
+        lens = np.clip(np.rint(rng.lognormal(
+            np.log(doc_len_median), doc_len_sigma, draw)),
+            doc_len_min, doc_len_max).astype(np.int64)
+        start = 0 if ends.size == 0 else ends[-1] + 1
+        ends = np.concatenate([ends, start + np.cumsum(lens + 1) - 1])
+    out[ends[ends < num_tokens]] = EOD_ID
+    return out
+
+
+def ensure_token_stream(root: str, seed: int = 0, **stream_kw) -> str:
+    """``<root>/tokens.npy`` holding :func:`synthetic_token_stream` of
+    ``seed`` and ``stream_kw``, written once (atomically: a reader never
+    sees half a file); returns its path."""
+    path = os.path.join(root, "tokens.npy")
+    if not os.path.exists(path):
+        os.makedirs(root, exist_ok=True)
+        tmp = f"{path}.{os.getpid()}.tmp.npy"
+        np.save(tmp, synthetic_token_stream(seed=seed, **stream_kw))
+        os.replace(tmp, path)
+    return path
+
+
+def load_token_stream(root: str | None = None, **synth_kw) -> tuple[np.ndarray, str]:
+    """(tokens, source): ``<root>/tokens.npy`` when it is there ("disk"),
+    else a stream synthesized from ``synth_kw`` ("synthetic")."""
+    if root is not None:
+        path = os.path.join(root, "tokens.npy")
+        if os.path.exists(path):
+            return np.load(path), "disk"
+    return synthetic_token_stream(**synth_kw), "synthetic"

@@ -13,7 +13,8 @@ Every op in this package:
 
 from hyperspace_tpu.kernels._support import mode
 from hyperspace_tpu.kernels.distmat import lorentz_pdist, poincare_pdist
-from hyperspace_tpu.kernels.attention import flash_attention
+from hyperspace_tpu.kernels.attention import (flash_attention,
+                                              flash_dot_attention)
 from hyperspace_tpu.kernels.hyplinear import hyp_linear
 from hyperspace_tpu.kernels.mlr import hyp_mlr
 # the fused scan-top-k lives at hyperspace_tpu.kernels.scan_topk
@@ -45,5 +46,6 @@ __all__ = [
     "hyp_mlr",
     "hyp_linear",
     "flash_attention",
+    "flash_dot_attention",
     "scan_topk",
 ]

@@ -29,9 +29,24 @@ never materialized in EITHER direction: backward peak memory is
 O(N·D + blocks), not O(N²).  dβ/dτ/dc fold out of per-Q-block partial
 sums the dq kernel also emits.  The XLA twin (CPU / per-position β,τ)
 keeps plain autodiff.
+
+**Two score forms, one recurrence:** the three kernel bodies take a
+static ``_Form``.  ``lorentz`` is everything above, bit for bit what it
+was before the second form came.  ``dot`` (:func:`flash_dot_attention`,
+the looped language model's attention) is the scaled dot product: score
+``q·k × scale``, no epilogue, no c/β/τ, matmuls on the operands' dtype
+(bf16: one MXU pass) with float32 scores and accumulators, and a
+``causal`` structure built into the block ranges of all three kernels —
+a block wholly above the diagonal is neither fetched nor computed, the
+diagonal's blocks are masked from positions — where this form takes no
+dense mask operand at all.  Its calls are named ``flash_dot_fwd``,
+``flash_dot_dq``, ``flash_dot_dkv``.
 """
 
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -67,9 +82,102 @@ def _t_flash_attention(q, k, v, c, beta, tau, maskf):
     return s / (smath.sqrt_c(cc) * nrm)
 
 
-def _attn_body(c_ref, nk_ref, beta_ref, tau_ref, q_ref, k_ref, v_ref, o_ref,
-               res_ref, m_scr, l_scr, acc_scr, *, bk: int,
-               masked: bool, mask_ref=None):
+class _Form(NamedTuple):
+    """The static score form of one launch: ``lorentz`` (module doc) or
+    ``dot`` (score q·k × ``scale``, no epilogue, no c/β/τ), and whether
+    the causal structure (key position ≤ query position) is built into
+    the kernel's block ranges."""
+
+    kind: str = "lorentz"
+    causal: bool = False
+    scale: float = 1.0
+
+    @property
+    def n_smem(self) -> int:
+        # lorentz: c, nk, beta, tau; dot: nk
+        return 4 if self.kind == "lorentz" else 1
+
+
+_LORENTZ = _Form()
+_NN = ((1,), (0,))
+_NT = ((1,), (1,))
+_TN = ((0,), (0,))
+
+
+def _mm(a, b, contract):
+    """One MXU matmul with a float32 product: float32 operands (the
+    lorentz form casts every operand up) at HIGHEST, since they feed
+    arcosh-amplified quantities; narrower operands (the dot form's bf16
+    lane) in one pass."""
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(contract, ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST
+                   if a.dtype == jnp.float32 else None))
+
+
+def _operands(form, refs):
+    """The tile's VMEM operands as the form computes on them."""
+    vals = [r[0] for r in refs]
+    if form.kind == "lorentz":
+        vals = [x.astype(jnp.float32) for x in vals]
+    return vals
+
+
+def _score_tile(form, sm, q, k, iq, ik, bq, bk, mask_ref):
+    """One [bq, bk] score tile, its validity, and the coefficient and the
+    two operands of dσ's pull-back (shared by the forward and both
+    backward kernels)."""
+    if form.kind == "lorentz":
+        c_ref, nk_ref, beta_ref, tau_ref = sm
+        c = c_ref[0, 0]
+        beta = beta_ref[pl.program_id(0)]
+        tau = tau_ref[pl.program_id(0)]
+        lane = jax.lax.broadcasted_iota(jnp.int32, k.shape, dimension=1)
+        k_side = jnp.where(lane == 0, -k, k)
+        gram = S.dotT(q, k_side)       # ⟨q, k⟩_L — MXU matmul 1, [bq, bk]
+        sigma = (2.0 / c + 2.0 * gram + beta) / tau
+        coef = 2.0 / tau
+    else:
+        (nk_ref,) = sm
+        k_side = k
+        sigma = _mm(q, k, _NT) * form.scale
+        coef = form.scale
+    nk = nk_ref[0, 0]
+    col = jax.lax.broadcasted_iota(jnp.int32, sigma.shape, dimension=1) + ik * bk
+    valid = col < nk
+    if mask_ref is not None:
+        valid = jnp.logical_and(valid, mask_ref[0] > 0.0)
+    if form.causal:
+        row = jax.lax.broadcasted_iota(
+            jnp.int32, sigma.shape, dimension=0) + iq * bq
+        valid = jnp.logical_and(valid, col <= row)
+    return sigma, valid, coef, k_side
+
+
+def _q_side(form, q):
+    """dσ/dk's operand: J q for the Minkowski Gram, q for the dot."""
+    if form.kind != "lorentz":
+        return q
+    lane_q = jax.lax.broadcasted_iota(jnp.int32, q.shape, dimension=1)
+    return jnp.where(lane_q == 0, -q, q)
+
+
+def _when_needed(form, iq, ik, bq, bk, tile):
+    """Run ``tile`` unless the causal form puts the whole block above the
+    diagonal (its first key after the block's last query)."""
+    if form.causal:
+        pl.when(ik * bk <= iq * bq + (bq - 1))(tile)
+    else:
+        tile()
+
+
+def _attn_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
+    sm, refs = refs[:form.n_smem], refs[form.n_smem:]
+    q_ref, k_ref, v_ref = refs[:3]
+    mask_ref = refs[3] if masked else None
+    o_ref, res_ref, m_scr, l_scr, acc_scr = refs[3 + masked:]
+    iq = pl.program_id(1)
     ik = pl.program_id(2)
     nk_blocks = pl.num_programs(2)
 
@@ -79,69 +187,104 @@ def _attn_body(c_ref, nk_ref, beta_ref, tau_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    c = c_ref[0, 0]
-    beta = beta_ref[pl.program_id(0)]
-    tau = tau_ref[pl.program_id(0)]
-    nk = nk_ref[0, 0]
-    q = q_ref[0].astype(jnp.float32)   # [bq, dp]
-    k = k_ref[0].astype(jnp.float32)   # [bk, dp]
-    v = v_ref[0].astype(jnp.float32)
+    def _tile():
+        q, k, v = _operands(form, (q_ref, k_ref, v_ref))
+        logits, valid, _, _ = _score_tile(form, sm, q, k, iq, ik, bq, bk,
+                                          mask_ref)
+        logits = jnp.where(valid, logits, _NEG)
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, k.shape, dimension=1)
-    k_flip = jnp.where(lane == 0, -k, k)
-    gram = S.dotT(q, k_flip)           # ⟨q, k⟩_L — MXU matmul 1, [bq, bk]
-    logits = (2.0 / c + 2.0 * gram + beta) / tau
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new)
+        p = jnp.where(valid, p, 0.0)   # exp(_NEG - m) underflows to 0 anyway
+        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = alpha * acc_scr[:] + _mm(p.astype(v.dtype), v, _NN)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[:] = acc_new
 
-    col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, dimension=1) + ik * bk
-    valid = col < nk
-    if masked:
-        valid = jnp.logical_and(valid, mask_ref[0] > 0.0)
-    logits = jnp.where(valid, logits, _NEG)
-
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)
-    p = jnp.where(valid, p, 0.0)       # exp(_NEG - m) underflows to 0 anyway
-    l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_new = alpha * acc_scr[:] + jax.lax.dot_general(
-        p, v, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )                                   # MXU matmul 2
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-    acc_scr[:] = acc_new
+    _when_needed(form, iq, ik, bq, bk, _tile)
 
     @pl.when(ik == nk_blocks - 1)
     def _epilogue():
         s = acc_scr[:] / jnp.maximum(l_scr[:, :1], S.MIN_NORM_F32)
+        # backward-pass residual: log-sum-exp of the score rows (big
+        # positive on fully-masked/padded rows so recomputed weights
+        # underflow to 0)
+        l_row = l_scr[:, :1]
+        lse = jnp.where(l_row > 0.0,
+                        m_scr[:, :1] + jnp.log(jnp.maximum(l_row, 1e-38)),
+                        1e30)
+        if form.kind != "lorentz":
+            o_ref[0] = s.astype(o_ref.dtype)
+            res_ref[0] = jnp.broadcast_to(lse, res_ref.shape[1:])
+            return
+        c = sm[0][0, 0]
         lane_o = jax.lax.broadcasted_iota(jnp.int32, s.shape, dimension=1)
         sp = jnp.sum(jnp.where(lane_o == 0, -s * s, s * s), axis=-1, keepdims=True)
         nrm = S.ksafe_sqrt(jnp.maximum(-sp, S.EPS_F32))
         sc = jnp.maximum(S.ksafe_sqrt(c), S.MIN_NORM_F32)
         o_ref[0] = (s / (sc * nrm)).astype(o_ref.dtype)
-        # backward-pass residuals: log-sum-exp of the score rows (big
-        # positive on fully-masked/padded rows so recomputed weights
-        # underflow to 0) and the pre-normalization Minkowski norm —
-        # PACKED into one [bq, 128] tile (lane 0 = lse, lanes 1+ = nrm)
-        # so the per-row scalars cost one output stream, not two
-        l_row = l_scr[:, :1]
-        lse = jnp.where(l_row > 0.0,
-                        m_scr[:, :1] + jnp.log(jnp.maximum(l_row, 1e-38)),
-                        1e30)
+        # ... and the pre-normalization Minkowski norm — PACKED into one
+        # [bq, 128] tile (lane 0 = lse, lanes 1+ = nrm) so the per-row
+        # scalars cost one output stream, not two
         lane_r = jax.lax.broadcasted_iota(jnp.int32, res_ref.shape[1:],
                                           dimension=1)
         res_ref[0] = jnp.where(lane_r == 0, lse, nrm)
 
 
-def _launch(q, k, v, c, beta_b, tau_b, maskf, mode_):
-    """q [B, Nq, D], k/v [B, Nk, D], beta_b/tau_b [B], maskf [B, Nq, Nk]|None."""
+def _smem_operands(form, b, nk, c=None, beta_b=None, tau_b=None):
+    """(block specs, arrays) of the form's scalars.  β/τ ride whole in
+    SMEM as flat 1-D [B] arrays (4 B per entry; the body picks its entry
+    with program_id).  A 2-D [B, 1] SMEM window pads every row to a 512 B
+    sublane and blows the 1 MB SMEM budget once B ≈ 1k (B = batch×heads
+    at eval); Mosaic only allows rank-1 blocks that span the whole array,
+    which is exactly what we want."""
+    one = lambda: pl.BlockSpec((1, 1), lambda ib, i1, i2: (0, 0),
+                               memory_space=pltpu.SMEM)
+    nk_arr = jnp.asarray(nk, jnp.int32).reshape(1, 1)
+    if form.kind != "lorentz":
+        return [one()], [nk_arr]
+    per_b = lambda: pl.BlockSpec((b,), lambda ib, i1, i2: (0,),
+                                 memory_space=pltpu.SMEM)
+    return ([one(), one(), per_b(), per_b()],
+            [S.c_smem(c), nk_arr, beta_b.reshape(b), tau_b.reshape(b)])
+
+
+def _block_caps(form):
+    """(query rows, key rows) a block may hold at most.  The dot form's
+    operands are half as wide and its K/V stream is what a short query
+    block re-reads, so its query block may be twice as tall."""
+    return (256, 512) if form.kind == "lorentz" else (512, 512)
+
+
+def _kv_index(form, bq, bk):
+    """Block index of K/V for grid point (iq, ik), KV innermost: a block
+    above the diagonal is never computed on, so it names the last one
+    needed and the pipeline fetches nothing new."""
+    if not form.causal:
+        return lambda iq, ik: ik
+    return lambda iq, ik: jnp.minimum(ik, (iq * bq + (bq - 1)) // bk)
+
+
+def _q_index(form, bq, bk):
+    """Block index of the Q-side operands for grid point (ik, iq), Q
+    innermost (the dk/dv kernel): blocks before the diagonal are skipped."""
+    if not form.causal:
+        return lambda ik, iq: iq
+    return lambda ik, iq: jnp.maximum(iq, (ik * bk) // bq)
+
+
+def _launch(q, k, v, form, scalars, maskf, mode_):
+    """q [B, Nq, D], k/v [B, Nk, D], ``scalars`` the form's (c, beta_b [B],
+    tau_b [B]) or (), maskf [B, Nq, Nk]|None."""
     b, nq, d = q.shape
     nk = k.shape[1]
     dp = S.round_up(d, 128)
-    bq = min(S.round_up(nq, 8), 256)
-    bk = min(S.round_up(nk, 128), 512)
+    cap_q, cap_k = _block_caps(form)
+    bq = min(S.round_up(nq, 8), cap_q)
+    bk = min(S.round_up(nk, 128), cap_k)
     # q + k + v + out + acc blocks (+ mask + logits) under the VMEM budget
     while 4 * (3 * bq * dp + 2 * bk * dp + 2 * bq * bk) > S.VMEM_BUDGET and (bq > 8 or bk > 128):
         if bk > 128 and bk >= bq:
@@ -156,25 +299,14 @@ def _launch(q, k, v, c, beta_b, tau_b, maskf, mode_):
     nq_p, nk_p = qp.shape[1], kp.shape[1]
     grid = (b, nq_p // bq, nk_p // bk)
 
-    smem = lambda idx: pl.BlockSpec((1, 1), idx, memory_space=pltpu.SMEM)
-    # β/τ ride whole in SMEM as flat 1-D [B] arrays (4 B per entry; the
-    # body picks its entry with program_id).  A 2-D [B, 1] SMEM window
-    # pads every row to a 512 B sublane and blows the 1 MB SMEM budget
-    # once B ≈ 1k (B = batch×heads at eval); Mosaic only allows rank-1
-    # blocks that span the whole array, which is exactly what we want.
-    per_b = pl.BlockSpec((b,), lambda ib, iq, ik: (0,),
-                         memory_space=pltpu.SMEM)
-    in_specs = [
-        smem(lambda ib, iq, ik: (0, 0)),                   # c
-        smem(lambda ib, iq, ik: (0, 0)),                   # nk
-        per_b,                                             # beta
-        per_b,                                             # tau
+    kv = _kv_index(form, bq, bk)
+    in_specs, args = _smem_operands(form, b, nk, *scalars)
+    in_specs += [
         pl.BlockSpec((1, bq, dp), lambda ib, iq, ik: (ib, iq, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, ik, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, ik, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, kv(iq, ik), 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, kv(iq, ik), 0), memory_space=pltpu.VMEM),
     ]
-    args = [S.c_smem(c), jnp.asarray(nk, jnp.int32).reshape(1, 1),
-            beta_b.reshape(b), tau_b.reshape(b), qp, kp, vp]
+    args += [qp, kp, vp]
     masked = maskf is not None
     if masked:
         mp = S.pad_axis(S.pad_axis(maskf.astype(jnp.float32), -1, bk), -2, bq)
@@ -182,22 +314,10 @@ def _launch(q, k, v, c, beta_b, tau_b, maskf, mode_):
                                      memory_space=pltpu.VMEM))
         args.append(mp)
 
-    def body(*refs):
-        # layout: 4 smem + 3 vmem inputs (+ mask), 2 outs, 3 scratch
-        if masked:
-            (c_r, nk_r, be_r, ta_r, q_r, k_r, v_r, mk_r, o_r, rs_r,
-             m_s, l_s, a_s) = refs
-        else:
-            (c_r, nk_r, be_r, ta_r, q_r, k_r, v_r, o_r, rs_r,
-             m_s, l_s, a_s) = refs
-            mk_r = None
-        _attn_body(c_r, nk_r, be_r, ta_r, q_r, k_r, v_r, o_r, rs_r,
-                   m_s, l_s, a_s, bk=bk, masked=masked, mask_ref=mk_r)
-
     row_spec = pl.BlockSpec((1, bq, 128), lambda ib, iq, ik: (ib, iq, 0),
                             memory_space=pltpu.VMEM)
     out, res = pl.pallas_call(
-        body,
+        functools.partial(_attn_body, form=form, bq=bq, bk=bk, masked=masked),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -217,8 +337,16 @@ def _launch(q, k, v, c, beta_b, tau_b, maskf, mode_):
         compiler_params=S.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=S.interpret_flag(mode_),
+        name=_call_name(form, "fwd"),
     )(*args)
     return out[:, :nq, :d], res[:, :, 0], res[:, :, 1]
+
+
+def _call_name(form, which: str):
+    """The dot form's calls carry names of their own (XLA makes a call's
+    name the instruction's, which a device trace shows): flash_dot_fwd,
+    flash_dot_dq, flash_dot_dkv; the lorentz form's stay unnamed."""
+    return None if form.kind == "lorentz" else f"flash_dot_{which}"
 
 
 def _scalar_per_batch(x, lead, dtype):
@@ -230,65 +358,58 @@ def _scalar_per_batch(x, lead, dtype):
 # --- recomputing flash backward (module doc) ----------------------------------
 
 
-def _score_tile(c, beta, tau, q, k, nk, ik, bk, masked, mask_ref):
-    """Recompute one [bq, bk] score tile + validity (shared by both
-    backward kernels; identical math to the forward body)."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, k.shape, dimension=1)
-    k_flip = jnp.where(lane == 0, -k, k)
-    gram = S.dotT(q, k_flip)
-    sigma = (2.0 / c + 2.0 * gram + beta) / tau
-    col = jax.lax.broadcasted_iota(jnp.int32, sigma.shape, dimension=1) + ik * bk
-    valid = col < nk
-    if masked:
-        valid = jnp.logical_and(valid, mask_ref[0] > 0.0)
-    return sigma, valid, k_flip
-
-
-def _dq_body(c_ref, nk_ref, beta_ref, tau_ref, q_ref, k_ref, v_ref, dsp_ref,
-             ld_ref, dq_ref, dst_ref, dq_scr, part_scr,
-             *, bk: int, masked: bool, mask_ref=None):
+def _dq_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
+    sm, refs = refs[:form.n_smem], refs[form.n_smem:]
+    q_ref, k_ref, v_ref, dsp_ref, ld_ref = refs[:5]
+    mask_ref = refs[5] if masked else None
+    lorentz = form.kind == "lorentz"
+    if lorentz:
+        dq_ref, dst_ref, dq_scr, part_scr = refs[5 + masked:]
+    else:
+        dq_ref, dq_scr = refs[5 + masked:]
+    iq = pl.program_id(1)
     ik = pl.program_id(2)
     nk_blocks = pl.num_programs(2)
 
     @pl.when(ik == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
-        part_scr[:] = jnp.zeros_like(part_scr)
+        if lorentz:
+            part_scr[:] = jnp.zeros_like(part_scr)
 
-    c = c_ref[0, 0]
-    beta = beta_ref[pl.program_id(0)]
-    tau = tau_ref[pl.program_id(0)]
-    nk = nk_ref[0, 0]
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    dsp = dsp_ref[0].astype(jnp.float32)
-    lse = ld_ref[0][:, :1]       # packed per-row scalars: lane 0 = lse,
-    di = ld_ref[0][:, 1:2]       # lane 1 = di (one stream, not two)
+    def _tile():
+        q, k, v, dsp = _operands(form, (q_ref, k_ref, v_ref, dsp_ref))
+        lse = ld_ref[0][:, :1]   # packed per-row scalars: lane 0 = lse,
+        di = ld_ref[0][:, 1:2]   # lane 1 = di (one stream, not two)
 
-    sigma, valid, k_flip = _score_tile(c, beta, tau, q, k, nk, ik, bk,
-                                       masked, mask_ref)
-    p = jnp.where(valid, jnp.exp(sigma - lse), 0.0)
-    dv_dot = S.dotT(dsp, v)                       # ⟨dsp_i, v_j⟩, MXU
-    dsig = jnp.where(valid, p * (dv_dot - di), 0.0)
-    dq_scr[:] += (2.0 / tau) * jax.lax.dot_general(
-        dsig, k_flip, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
-    # dτ partial Σ dσ·σ accumulates as a (8, 128)-tiled broadcast (a
-    # scalar-shaped output block fails the Mosaic (8, 128) tiling rule).
-    # dβ needs no partial: Σ_j dσ_ij = 0 exactly (softmax shift
-    # invariance), so dβ ≡ 0 and the score-offset dc term vanishes too.
-    part_scr[:] += jnp.sum(jnp.where(valid, dsig * sigma, 0.0))
+        sigma, valid, coef, k_side = _score_tile(form, sm, q, k, iq, ik,
+                                                 bq, bk, mask_ref)
+        p = jnp.where(valid, jnp.exp(sigma - lse), 0.0)
+        dv_dot = _mm(dsp, v, _NT)                     # ⟨dsp_i, v_j⟩, MXU
+        dsig = jnp.where(valid, p * (dv_dot - di), 0.0)
+        dq_scr[:] += coef * _mm(dsig.astype(k_side.dtype), k_side, _NN)
+        if lorentz:
+            # dτ partial Σ dσ·σ accumulates as a (8, 128)-tiled broadcast
+            # (a scalar-shaped output block fails the Mosaic (8, 128)
+            # tiling rule).  dβ needs no partial: Σ_j dσ_ij = 0 exactly
+            # (softmax shift invariance), so dβ ≡ 0 and the score-offset
+            # dc term vanishes too.
+            part_scr[:] += jnp.sum(jnp.where(valid, dsig * sigma, 0.0))
+
+    _when_needed(form, iq, ik, bq, bk, _tile)
 
     @pl.when(ik == nk_blocks - 1)
     def _write():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
-        dst_ref[0, 0] = part_scr[:]
+        if lorentz:
+            dst_ref[0, 0] = part_scr[:]
 
 
-def _dkv_body(c_ref, nk_ref, beta_ref, tau_ref, q_ref, k_ref, v_ref, dsp_ref,
-              ld_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-              *, bk: int, masked: bool, mask_ref=None):
+def _dkv_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
+    sm, refs = refs[:form.n_smem], refs[form.n_smem:]
+    q_ref, k_ref, v_ref, dsp_ref, ld_ref = refs[:5]
+    mask_ref = refs[5] if masked else None
+    dk_ref, dv_ref, dk_scr, dv_scr = refs[5 + masked:]
     iq = pl.program_id(2)
     nq_blocks = pl.num_programs(2)
     ik = pl.program_id(1)          # KV block index is the OUTER grid dim
@@ -298,30 +419,21 @@ def _dkv_body(c_ref, nk_ref, beta_ref, tau_ref, q_ref, k_ref, v_ref, dsp_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    c = c_ref[0, 0]
-    beta = beta_ref[pl.program_id(0)]
-    tau = tau_ref[pl.program_id(0)]
-    nk = nk_ref[0, 0]
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    dsp = dsp_ref[0].astype(jnp.float32)
-    lse = ld_ref[0][:, :1]       # packed: lane 0 = lse, lane 1 = di
-    di = ld_ref[0][:, 1:2]
+    def _tile():
+        q, k, v, dsp = _operands(form, (q_ref, k_ref, v_ref, dsp_ref))
+        lse = ld_ref[0][:, :1]   # packed: lane 0 = lse, lane 1 = di
+        di = ld_ref[0][:, 1:2]
 
-    sigma, valid, _ = _score_tile(c, beta, tau, q, k, nk, ik, bk,
-                                  masked, mask_ref)
-    p = jnp.where(valid, jnp.exp(sigma - lse), 0.0)
-    dv_scr[:] += jax.lax.dot_general(                 # pᵀ @ dsp
-        p, dsp, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
-    dv_dot = S.dotT(dsp, v)
-    dsig = jnp.where(valid, p * (dv_dot - di), 0.0)
-    lane_q = jax.lax.broadcasted_iota(jnp.int32, q.shape, dimension=1)
-    q_flip = jnp.where(lane_q == 0, -q, q)
-    dk_scr[:] += (2.0 / tau) * jax.lax.dot_general(   # dsigᵀ @ (J q)
-        dsig, q_flip, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
+        sigma, valid, coef, _ = _score_tile(form, sm, q, k, iq, ik, bq, bk,
+                                            mask_ref)
+        p = jnp.where(valid, jnp.exp(sigma - lse), 0.0)
+        dv_scr[:] += _mm(p.astype(dsp.dtype), dsp, _TN)      # pᵀ @ dsp
+        dv_dot = _mm(dsp, v, _NT)
+        dsig = jnp.where(valid, p * (dv_dot - di), 0.0)
+        q_side = _q_side(form, q)
+        dk_scr[:] += coef * _mm(dsig.astype(q_side.dtype), q_side, _TN)
+
+    _when_needed(form, iq, ik, bq, bk, _tile)
 
     @pl.when(iq == nq_blocks - 1)
     def _write():
@@ -329,9 +441,10 @@ def _dkv_body(c_ref, nk_ref, beta_ref, tau_ref, q_ref, k_ref, v_ref, dsp_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd_blocks(nq, nk, dp):
-    bq = min(S.round_up(nq, 8), 256)
-    bk = min(S.round_up(nk, 128), 512)
+def _bwd_blocks(form, nq, nk, dp):
+    cap_q, cap_k = _block_caps(form)
+    bq = min(S.round_up(nq, 8), cap_q)
+    bk = min(S.round_up(nk, 128), cap_k)
     # q + k + v + dsp + dq/dkv scratch + lse/di + score tiles
     while 4 * (6 * bq * dp + 4 * bk * dp + 3 * bq * bk) > S.VMEM_BUDGET and (
             bq > 8 or bk > 128):
@@ -342,12 +455,16 @@ def _bwd_blocks(nq, nk, dp):
     return bq, bk
 
 
-def _bwd_launch(q, k, v, c, beta_b, tau_b, maskf, dsp, lse, di, mode_):
-    """Run both backward kernels; returns (dq, dk, dv, dst [B])."""
+def _bwd_launch(q, k, v, form, scalars, maskf, dsp, lse, di, mode_):
+    """Run both backward kernels; returns (dq, dk, dv, dst [B] | None)."""
     b, nq, d = q.shape
     nk = k.shape[1]
     dp = S.round_up(d, 128)
-    bq, bk = _bwd_blocks(nq, nk, dp)
+    bq, bk = _bwd_blocks(form, nq, nk, dp)
+    lorentz = form.kind == "lorentz"
+    # the lorentz form's gradients leave in float32; the dot form's in
+    # its operands' dtype (the accumulators are float32 either way)
+    grad_dtype = jnp.float32 if lorentz else q.dtype
     pad3 = lambda a, rows: S.pad_axis(S.pad_axis(a, -1, 128), -2, rows)
     qp, kp, vp = pad3(q, bq), pad3(k, bk), pad3(v, bk)
     dspp = pad3(dsp, bq)
@@ -366,25 +483,18 @@ def _bwd_launch(q, k, v, c, beta_b, tau_b, maskf, dsp, lse, di, mode_):
     lane128 = jnp.arange(128)[None, None, :]
     ld_b = jnp.where(lane128 == 0, lse_p[..., None], di_p[..., None])
 
-    smem = lambda idx: pl.BlockSpec((1, 1), idx, memory_space=pltpu.SMEM)
-    per_b = lambda: pl.BlockSpec((b,), lambda ib, i1, i2: (0,),
-                                 memory_space=pltpu.SMEM)
-    base_args = [S.c_smem(c), jnp.asarray(nk, jnp.int32).reshape(1, 1),
-                 beta_b.reshape(b), tau_b.reshape(b)]
+    smem_specs, base_args = _smem_operands(form, b, nk, *scalars)
     masked = maskf is not None
     mp = None
     if masked:
         mp = S.pad_axis(S.pad_axis(maskf.astype(jnp.float32), -1, bk), -2, bq)
 
     # dq kernel: grid (B, Qb, KVb), KV inner
-    in_specs = [
-        smem(lambda ib, iq, ik: (0, 0)),
-        smem(lambda ib, iq, ik: (0, 0)),
-        per_b(),
-        per_b(),
+    kv = _kv_index(form, bq, bk)
+    in_specs = smem_specs + [
         pl.BlockSpec((1, bq, dp), lambda ib, iq, ik: (ib, iq, 0)),
-        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, ik, 0)),
-        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, ik, 0)),
+        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, kv(iq, ik), 0)),
+        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, kv(iq, ik), 0)),
         pl.BlockSpec((1, bq, dp), lambda ib, iq, ik: (ib, iq, 0)),
         pl.BlockSpec((1, bq, 128), lambda ib, iq, ik: (ib, iq, 0)),
     ]
@@ -394,51 +504,37 @@ def _bwd_launch(q, k, v, c, beta_b, tau_b, maskf, dsp, lse, di, mode_):
                                      lambda ib, iq, ik: (ib, iq, ik)))
         args.append(mp)
 
-    def dq_kernel(*refs):
-        if masked:
-            (c_r, nk_r, be_r, ta_r, q_r, k_r, v_r, ds_r, ld_r, mk_r,
-             dq_r, st_r, dq_s, pt_s) = refs
-        else:
-            (c_r, nk_r, be_r, ta_r, q_r, k_r, v_r, ds_r, ld_r,
-             dq_r, st_r, dq_s, pt_s) = refs
-            mk_r = None
-        _dq_body(c_r, nk_r, be_r, ta_r, q_r, k_r, v_r, ds_r, ld_r,
-                 dq_r, st_r, dq_s, pt_s, bk=bk, masked=masked,
-                 mask_ref=mk_r)
-
     nqb, nkb = nq_p // bq, nk_p // bk
-    dq, dst = pl.pallas_call(
-        dq_kernel,
+    out_specs = [pl.BlockSpec((1, bq, dp), lambda ib, iq, ik: (ib, iq, 0))]
+    out_shape = [jax.ShapeDtypeStruct((b, nq_p, dp), grad_dtype)]
+    scratch = [pltpu.VMEM((bq, dp), jnp.float32)]
+    if lorentz:
+        out_specs.append(pl.BlockSpec((1, 1, 8, 128),
+                                      lambda ib, iq, ik: (ib, iq, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, nqb, 8, 128), jnp.float32))
+        scratch.append(pltpu.VMEM((8, 128), jnp.float32))
+    dq, *dst = pl.pallas_call(
+        functools.partial(_dq_body, form=form, bq=bq, bk=bk, masked=masked),
         grid=(b, nqb, nkb),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bq, dp), lambda ib, iq, ik: (ib, iq, 0)),
-            pl.BlockSpec((1, 1, 8, 128), lambda ib, iq, ik: (ib, iq, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, nq_p, dp), jnp.float32),
-            jax.ShapeDtypeStruct((b, nqb, 8, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, dp), jnp.float32),
-            pltpu.VMEM((8, 128), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=S.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=S.interpret_flag(mode_),
+        name=_call_name(form, "dq"),
     )(*args)
 
     # dkv kernel: grid (B, KVb, Qb), Q inner
-    in_specs2 = [
-        smem(lambda ib, ik, iq: (0, 0)),
-        smem(lambda ib, ik, iq: (0, 0)),
-        per_b(),
-        per_b(),
-        pl.BlockSpec((1, bq, dp), lambda ib, ik, iq: (ib, iq, 0)),
+    qi = _q_index(form, bq, bk)
+    smem_specs2, _ = _smem_operands(form, b, nk, *scalars)
+    in_specs2 = smem_specs2 + [
+        pl.BlockSpec((1, bq, dp), lambda ib, ik, iq: (ib, qi(ik, iq), 0)),
         pl.BlockSpec((1, bk, dp), lambda ib, ik, iq: (ib, ik, 0)),
         pl.BlockSpec((1, bk, dp), lambda ib, ik, iq: (ib, ik, 0)),
-        pl.BlockSpec((1, bq, dp), lambda ib, ik, iq: (ib, iq, 0)),
-        pl.BlockSpec((1, bq, 128), lambda ib, ik, iq: (ib, iq, 0)),
+        pl.BlockSpec((1, bq, dp), lambda ib, ik, iq: (ib, qi(ik, iq), 0)),
+        pl.BlockSpec((1, bq, 128), lambda ib, ik, iq: (ib, qi(ik, iq), 0)),
     ]
     args2 = base_args + [qp, kp, vp, dspp, ld_b]
     if masked:
@@ -446,20 +542,8 @@ def _bwd_launch(q, k, v, c, beta_b, tau_b, maskf, dsp, lse, di, mode_):
                                       lambda ib, ik, iq: (ib, iq, ik)))
         args2.append(mp)
 
-    def dkv_kernel(*refs):
-        if masked:
-            (c_r, nk_r, be_r, ta_r, q_r, k_r, v_r, ds_r, ld_r, mk_r,
-             dk_r, dv_r, dk_s, dv_s) = refs
-        else:
-            (c_r, nk_r, be_r, ta_r, q_r, k_r, v_r, ds_r, ld_r,
-             dk_r, dv_r, dk_s, dv_s) = refs
-            mk_r = None
-        _dkv_body(c_r, nk_r, be_r, ta_r, q_r, k_r, v_r, ds_r, ld_r,
-                  dk_r, dv_r, dk_s, dv_s, bk=bk, masked=masked,
-                  mask_ref=mk_r)
-
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        functools.partial(_dkv_body, form=form, bq=bq, bk=bk, masked=masked),
         grid=(b, nkb, nqb),
         in_specs=in_specs2,
         out_specs=[
@@ -467,8 +551,8 @@ def _bwd_launch(q, k, v, c, beta_b, tau_b, maskf, dsp, lse, di, mode_):
             pl.BlockSpec((1, bk, dp), lambda ib, ik, iq: (ib, ik, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, nk_p, dp), jnp.float32),
-            jax.ShapeDtypeStruct((b, nk_p, dp), jnp.float32),
+            jax.ShapeDtypeStruct((b, nk_p, dp), grad_dtype),
+            jax.ShapeDtypeStruct((b, nk_p, dp), grad_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, dp), jnp.float32),
@@ -477,9 +561,10 @@ def _bwd_launch(q, k, v, c, beta_b, tau_b, maskf, dsp, lse, di, mode_):
         compiler_params=S.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=S.interpret_flag(mode_),
+        name=_call_name(form, "dkv"),
     )(*args2)
     return (dq[:, :nq, :d], dk[:, :nk, :d], dv[:, :nk, :d],
-            jnp.sum(dst[:, :, 0, 0], axis=1))
+            jnp.sum(dst[0][:, :, 0, 0], axis=1) if lorentz else None)
 
 
 def _epilogue_jax(s, c):
@@ -494,14 +579,15 @@ def _epilogue_jax(s, c):
 
 @jax.custom_vjp
 def _flash3(q3, k3, v3, c, beta_b, tau_b, maskf, mode_s):
-    out, _, _ = _launch(q3, k3, v3, c, beta_b, tau_b, maskf,
+    out, _, _ = _launch(q3, k3, v3, _LORENTZ, (c, beta_b, tau_b), maskf,
                         "interpret" if mode_s.shape[0] else "pallas")
     return out
 
 
 def _fa3_fwd(q3, k3, v3, c, beta_b, tau_b, maskf, mode_s):
     mode_ = "interpret" if mode_s.shape[0] else "pallas"
-    out, lse, nrm = _launch(q3, k3, v3, c, beta_b, tau_b, maskf, mode_)
+    out, lse, nrm = _launch(q3, k3, v3, _LORENTZ, (c, beta_b, tau_b),
+                            maskf, mode_)
     return out, (q3, k3, v3, c, beta_b, tau_b, maskf, out, lse, nrm, mode_s)
 
 
@@ -516,8 +602,8 @@ def _fa3_bwd(res, g):
     _, epi_vjp = jax.vjp(_epilogue_jax, s_pre, c32)
     dsp, dc_epi = epi_vjp(g.astype(jnp.float32))
     di = jnp.sum(dsp * s_pre, axis=-1)                      # [B, nq]
-    dq, dk, dv, dst = _bwd_launch(q3, k3, v3, c, beta_b, tau_b, maskf,
-                                  dsp, lse, di, mode_)
+    dq, dk, dv, dst = _bwd_launch(q3, k3, v3, _LORENTZ, (c, beta_b, tau_b),
+                                  maskf, dsp, lse, di, mode_)
     # β shifts every logit of a softmax row uniformly → dβ ≡ 0 exactly,
     # and the same row-sum identity kills the score-offset dc term; the
     # only c gradient is the epilogue's
@@ -570,4 +656,75 @@ def flash_attention(q, k, v, c, *, beta=0.0, tau=1.0, mask=None):
     # is static under jit — and int dtype means a None cotangent is valid)
     mode_s = jnp.zeros((1 if mode_ == "interpret" else 0,), jnp.int32)
     out = _flash3(q3, k3, v3, c, beta_b, tau_b, maskf, mode_s)
+    return out.reshape(lead + out.shape[-2:])
+
+
+# --- the dot-product form (models/looplm.py) ----------------------------------
+
+
+def _t_flash_dot(q, k, v, scale, causal):
+    """XLA twin of the dot form: dense softmax(q kᵀ · scale [+ causal]) v,
+    float32 scores and accumulation whatever the operands' dtype."""
+    hi = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None)
+    logits = jnp.einsum("...qd,...kd->...qk", q, k, precision=hi,
+                        preferred_element_type=jnp.float32) * scale
+    if causal:
+        nq, nk = q.shape[-2], k.shape[-2]
+        keep = jnp.arange(nk)[None, :] <= jnp.arange(nq)[:, None]
+        logits = jnp.where(keep, logits, -jnp.inf)
+    w = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("...qk,...kd->...qd", w.astype(v.dtype), v,
+                     precision=hi, preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_dot3(q3, k3, v3, form, mode_):
+    out, _, _ = _launch(q3, k3, v3, form, (), None, mode_)
+    return out
+
+
+def _fd3_fwd(q3, k3, v3, form, mode_):
+    out, lse, _ = _launch(q3, k3, v3, form, (), None, mode_)
+    return out, (q3, k3, v3, out, lse)
+
+
+def _fd3_bwd(form, mode_, res, g):
+    q3, k3, v3, out, lse = res
+    # no epilogue: the cotangent of the pre-epilogue sum is g itself
+    di = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    dq, dk, dv, _ = _bwd_launch(q3, k3, v3, form, (), None,
+                                g.astype(q3.dtype), lse, di, mode_)
+    return dq, dk, dv
+
+
+_flash_dot3.defvjp(_fd3_fwd, _fd3_bwd)
+
+
+def flash_dot_attention(q, k, v, *, causal=False):
+    """Flash attention in the scaled dot-product form: the recurrence and
+    the recomputing backward of :func:`flash_attention` with the score
+    q·k / √D and no epilogue.
+
+    q: [..., Nq, D], k/v: [..., Nk, D], one dtype; the matmuls run on the
+    operands' dtype (bf16: one MXU pass; float32: HIGHEST) with float32
+    scores, softmax and accumulators.  ``causal`` (Nq == Nk) keeps key
+    position ≤ query position *inside* the kernels: blocks above the
+    diagonal are neither fetched nor computed, the diagonal's blocks are
+    masked from positions, forward and both backward kernels alike.  No
+    dense mask operand exists in this form.  The XLA twin serves the CPU.
+    """
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    if causal and q.shape[-2] != k.shape[-2]:
+        raise ValueError("causal attention needs Nq == Nk, got "
+                         f"{q.shape[-2]} and {k.shape[-2]}")
+    mode_ = S.mode()
+    if mode_ == "xla":
+        return _t_flash_dot(q, k, v, scale, causal)
+    lead = q.shape[:-2]
+    flat = lambda a: a.reshape((-1,) + a.shape[-2:])
+    form = _Form("dot", bool(causal), scale)
+    out = _flash_dot3(flat(q), flat(jnp.broadcast_to(k, lead + k.shape[-2:])),
+                      flat(jnp.broadcast_to(v, lead + v.shape[-2:])),
+                      form, mode_)
     return out.reshape(lead + out.shape[-2:])

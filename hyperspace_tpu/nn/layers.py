@@ -122,3 +122,46 @@ class HypAct(nn.Module):
         v = m_in.origin_coords_from_tangent(m_in.logmap0(x))
         v = self.activation(v)
         return m_out.expmap0(m_out.tangent_from_origin_coords(v))
+
+
+# --- Euclidean transformer parts (models/looplm.py) ---------------------------
+# The curvature-zero case: a drawn architecture runs by its own published
+# equations.  Functions, not modules: the looped model stacks its weights
+# [L, ...] and scans over them, which flax's per-module scopes do not fit.
+# The precision policy's lanes: norms, rotary angles and the gate of the
+# feed-forward in float32 (``boundary``/``accum``); the matmuls' operands
+# in ``compute`` (precision.compute_matmul).
+
+
+def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
+    """x / sqrt(mean(x²) + eps) ⊙ gain, in float32 whatever x's dtype."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(
+        jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+
+
+def rotary_tables(length: int, head_dim: int, theta: float):
+    """(cos, sin), each [length, 1, head_dim] float32, for positions
+    0 … length-1 in the rotate-half form (the angle of lane i and of lane
+    i + head_dim/2 is position · theta^(-2i/head_dim))."""
+    inv = 1.0 / (theta ** (
+        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    ang = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)[:, None, :]
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """x [S, H, D] rotated by its position's angles, in float32."""
+    x = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * cos + rot * sin
+
+
+def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+           w_down: jax.Array, matmul: Callable = jnp.matmul) -> jax.Array:
+    """(silu(x W_gate) ⊙ (x W_up)) W_down; ``matmul`` is the policy's
+    (``precision.Policy.matmul``: operands on the compute lane, the
+    product float32), so the gate's product runs in float32."""
+    return matmul(jax.nn.silu(matmul(x, w_gate)) * matmul(x, w_up), w_down)

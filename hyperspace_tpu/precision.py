@@ -50,6 +50,7 @@ Wiring map (docs/precision.md has the full table):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Union
 
 import jax
@@ -116,6 +117,16 @@ class Policy:
             return tree
         return jax.tree_util.tree_map(self.cast_compute, tree)
 
+    def matmul(self, x, w):
+        """``x @ w`` (x [..., k], w [k, n]) with both operands on the
+        ``compute`` lane and the product in ``accum`` (the MXU's float32
+        accumulator kept, not rounded back to the operands' dtype); see
+        :func:`lane_matmul` for the backward.  Plain ``x @ w`` for the
+        f32 preset."""
+        if not self.mixed:
+            return x @ w
+        return lane_matmul(x, w, self.compute, self.accum)
+
     def module_dtype(self):
         """The ``dtype=`` to hand a flax module: ``compute`` when mixed,
         ``None`` (flax's promote-inputs default) otherwise — passing an
@@ -155,11 +166,54 @@ def compute_matmul(x, w, compute_dtype=None):
     lane) runs full-precision.  ``None`` is the plain matmul, untouched.
     The ONE home of this pattern — layer modules (``nn/layers.py``,
     ``nn/attention.py``) call it instead of hand-rolling the casts, so
-    the contract can't drift between sites."""
+    the contract can't drift between sites.
+
+    :func:`lane_matmul` is the other contract, not a second home of this
+    one: its product stays in the accumulator's float32 (here it is
+    rounded to ``compute_dtype`` by the matmul and cast back), and its
+    backward is written out so that a float32 master weight is cast at
+    its use and its gradient never rounded.  This one's callers hold
+    flax parameters whose gradients autodiff may round as it likes, and
+    their steps' lowered programs are pinned by sha256 (PERF.md), which
+    an ``accum`` argument here would move."""
     if compute_dtype is None:
         return x @ w
     return (x.astype(compute_dtype) @ w.astype(compute_dtype)).astype(
         x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def lane_matmul(x, w, compute, accum):
+    """``x @ w`` on the ``compute`` lane with an ``accum`` product, and a
+    backward that stays on the lane too: the cotangent is cast to
+    ``compute`` once, both transposed products accumulate in ``accum``,
+    and the weight's gradient leaves in the weight's OWN dtype.  So a
+    float32 master weight is cast at its use (no second copy of the
+    weights is held), its gradient is never rounded to ``compute``, and a
+    weight used several times in one step (models/looplm.py) sums its
+    gradient in float32."""
+    return jnp.matmul(x.astype(compute), w.astype(compute),
+                      preferred_element_type=accum)
+
+
+def _lane_matmul_fwd(x, w, compute, accum):
+    xc = x.astype(compute)
+    y = jnp.matmul(xc, w.astype(compute), preferred_element_type=accum)
+    # x's dtype rides as an empty array (a dtype is no valid residual)
+    return y, (xc, w, jnp.zeros((0,), x.dtype))
+
+
+def _lane_matmul_bwd(compute, accum, res, g):
+    xc, w, x_like = res
+    gc = g.astype(compute)
+    dx = jnp.matmul(gc, w.astype(compute).T, preferred_element_type=accum)
+    k = xc.shape[-1]
+    dw = jnp.matmul(xc.reshape(-1, k).T, gc.reshape(-1, gc.shape[-1]),
+                    preferred_element_type=accum)
+    return dx.astype(x_like.dtype), dw.astype(w.dtype)
+
+
+lane_matmul.defvjp(_lane_matmul_fwd, _lane_matmul_bwd)
 
 
 def parse_dtype(name: Union[str, Any, None], default: Any = None):

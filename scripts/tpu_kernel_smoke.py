@@ -13,6 +13,7 @@ The kernel/twin switch is the HYPERSPACE_KERNELS env var read at trace time
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -67,12 +68,78 @@ def one_pass_against_six(f, recv, plan, n, rng):
     return ok
 
 
+def flash_dot_checks(oks: list) -> None:
+    """The flash kernel's dot form at the looped model's own shape (16
+    heads, 4,096 positions, head width 128, bf16, causal): output and the
+    three gradients against the dense XLA twin; the output against jax's
+    own Pallas TPU flash attention (an independent program, not this
+    repo's path); and how long a forward and a forward + backward take."""
+    import time
+
+    from hyperspace_tpu.kernels.attention import flash_dot_attention
+
+    ks = jax.random.split(jax.random.PRNGKey(33), 4)
+    shape = (16, 4096, 128)
+    q, k, v = (jax.random.normal(ks[i], shape, jnp.float32)
+               .astype(jnp.bfloat16) for i in range(3))
+    w = jax.random.normal(ks[3], shape, jnp.float32)
+
+    def loss(q, k, v):
+        out = flash_dot_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32) * w)
+
+    # bf16 operands: p and d-sigma are rounded to bf16 for their matmuls
+    # on both sides, in another summation order
+    oks.append(run("flash_dot_causal_bf16", lambda: flash_dot_attention(
+        q, k, v, causal=True).astype(jnp.float32), tol=2e-2))
+    for i, name in enumerate(("dq", "dk", "dv")):
+        oks.append(run(f"flash_dot_causal_bf16_{name}", lambda: jax.grad(
+            loss, argnums=i)(q, k, v).astype(jnp.float32), tol=5e-2))
+    qf, kf, vf = (a[:2, :1024].astype(jnp.float32) for a in (q, k, v))
+    oks.append(run("flash_dot_full_f32", lambda: flash_dot_attention(
+        qf, kf, vf, causal=False), tol=1e-4))
+
+    os.environ["HYPERSPACE_KERNELS"] = "pallas"
+    try:
+        from jax.experimental.pallas.ops.tpu.flash_attention import (
+            flash_attention as jax_flash)
+
+        theirs = jax_flash(q[None], k[None], v[None], causal=True,
+                           sm_scale=128 ** -0.5)[0]
+        ours = flash_dot_attention(q, k, v, causal=True)
+        err = float(jnp.max(jnp.abs(ours.astype(jnp.float32)
+                                    - theirs.astype(jnp.float32))))
+        print(json.dumps({"kernel": "flash_dot_vs_jax_pallas_flash",
+                          "max_err": err, "ok": err < 2e-2}), flush=True)
+        oks.append(err < 2e-2)
+    except Exception as e:  # noqa: BLE001 — an optional, independent check
+        print(json.dumps({"kernel": "flash_dot_vs_jax_pallas_flash",
+                          "skipped": repr(e)[:200]}), flush=True)
+    fwd = jax.jit(functools.partial(flash_dot_attention, causal=True))
+    both = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    for name, fn, matmuls in (("fwd", fwd, 2), ("fwd_bwd", both, 6)):
+        jax.block_until_ready(fn(q, k, v))
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = fn(q, k, v)
+        jax.block_until_ready(out)
+        dt = (time.perf_counter() - t0) / 10
+        flops = matmuls * 2.0 * 16 * 128 * 4096 * 4097 / 2
+        print(json.dumps({"kernel": f"flash_dot_{name}_time", "ms": dt * 1e3,
+                          "required_tflops_per_s": flops / dt / 1e12}),
+              flush=True)
+
+
 def main():
     from hyperspace_tpu import kernels as K
     from hyperspace_tpu.kernels.segment import build_csr_plan, csr_segment_sum
     from hyperspace_tpu.manifolds import Lorentz, PoincareBall
 
     assert jax.default_backend() != "cpu", "smoke needs the TPU backend"
+    if sys.argv[1:] == ["flash_dot"]:  # that family alone
+        oks = []
+        flash_dot_checks(oks)
+        sys.exit(0 if all(oks) else 1)
     key = jax.random.PRNGKey(0)
     ks = list(jax.random.split(key, 16))
     ball, lor = PoincareBall(1.0), Lorentz(1.0)
@@ -256,6 +323,7 @@ def main():
                                              spec=("poincare", 1.0),
                                              k=5)[0]))
 
+    flash_dot_checks(oks)
     print(json.dumps({"all_ok": all(oks), "backend": jax.default_backend()}),
           flush=True)
     sys.exit(0 if all(oks) else 1)

@@ -280,6 +280,23 @@ def test_flash_attention_fwd_bwd(one_chip):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q, n_kernels=3)
 
 
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_flash_dot_attention_fwd_bwd_at_the_looped_models_shape(one_chip,
+                                                                dtype):
+    """The dot form, causal, 16 heads of 128 over 4,096 positions
+    (ouro_2p6b.pretrain4k's call): the three kernels under their own
+    names, bf16 operands with a transposed-operand matmul in dk/dv."""
+    from hyperspace_tpu.kernels.attention import flash_dot_attention
+
+    q = _arg(one_chip)((16, 4096, 128), dtype)
+    loss = lambda q, k, v: flash_dot_attention(
+        q, k, v, causal=True).astype(F32).sum()
+    _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q,
+                       n_kernels=3)
+    for name in ("flash_dot_fwd", "flash_dot_dq", "flash_dot_dkv"):
+        assert name in text, name
+
+
 def test_hyp_linear(one_chip):
     from hyperspace_tpu.kernels import hyp_linear
 
@@ -337,4 +354,48 @@ def test_train_step_lp_at_arxiv_width(one_chip, arxiv_split):
     assert held < 12 * 2**30, f"{held / 2**30:.2f} GiB of a 16 GB chip"
     # the graph arrays are the arguments' bulk: ~127 MB at this size
     assert 100e6 < mem.argument_size_in_bytes < 160e6
+    assert took < 300, f"compile took {took:.0f}s"
+
+
+def test_looplm_train_step_at_the_published_widths(one_chip):
+    """``looplm.train_step`` as ``cli.train looplm --yaml
+    configs/looplm_ouro_2p6b.yaml num_hidden_layers=8`` builds it (the
+    benchmark's ouro_2p6b.pretrain4k): Ouro-2.6B's widths, 8 layers, 4
+    passes, one 4,096-token sequence.  It must compile for one chip with
+    its three flash kernels and hold under the chip's 16.9 GB
+    ``bytes_limit``: 7.35 GB of parameters and moments, and under 8.7 GB
+    of temporaries (the gradient, 32 saved layer inputs, a block of
+    logits) — the T-loops form of the same step took 13.6 GB of them."""
+    import yaml
+
+    from hyperspace_tpu.cli import train as T
+    from hyperspace_tpu.models import looplm
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), "configs",
+            "looplm_ouro_2p6b.yaml")) as f:
+        doc = yaml.safe_load(f)
+    import json
+
+    pairs = [f"{k}={json.dumps(v) if isinstance(v, list) else v}"
+             for k, v in doc.items()] + ["num_hidden_layers=8"]
+    run, overrides = T.split_overrides(pairs, T.RunConfig())
+    cfg, _ = T._looplm_config(run, overrides)
+    assert (cfg.hidden_size, cfg.head_dim, cfg.sequence_length,
+            cfg.precision) == (2048, 128, 4096, "bf16")
+    opt = looplm.make_optimizer(cfg)
+    state = jax.eval_shape(lambda: looplm.init_state(cfg, 0)[1])
+    args = _shapes((state, jax.ShapeDtypeStruct((1 << 24,), I32)), one_chip)
+    t0 = time.perf_counter()
+    compiled = looplm.train_step.lower(cfg, opt, *args).compile()
+    took = time.perf_counter() - t0
+    text = compiled.as_text()
+    for name in ("flash_dot_fwd", "flash_dot_dq", "flash_dot_dkv"):
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    assert 7.3e9 < mem.alias_size_in_bytes < 7.4e9   # the state, donated
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 8.7e9, mem.temp_size_in_bytes
+    assert held < 16.3e9, f"{held / 1e9:.2f} GB of a 16.9 GB bytes_limit"
     assert took < 300, f"compile took {took:.0f}s"

@@ -1,0 +1,300 @@
+"""LoopLM (models/looplm.py) against its plain reference
+(benchmark/reference/looplm.py) on seeded weights at a tiny size: loss,
+per-pass cross-entropies, exit probabilities, every gradient leaf, three
+AdamW steps; what the loop means for the gradient; the exit
+distribution; the trainer through ``cli.train``."""
+
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.drivers import train_lm as drv
+from benchmark.reference import looplm as ref
+from hyperspace_tpu.models import looplm as M
+
+MODEL_KEYS = ("hidden_size", "intermediate_size", "vocab_size",
+              "num_hidden_layers", "num_attention_heads", "head_dim",
+              "total_ut_steps", "rms_norm_eps", "rope_theta")
+
+
+def _setup(seed=5, **cfg_kw):
+    cfg = M.LoopLMConfig(**cfg_kw)
+    model = {k: getattr(cfg, k) for k in MODEL_KEYS}
+    recipe = {"lr": cfg.lr, "b1": cfg.adam_b1, "b2": cfg.adam_b2,
+              "eps": cfg.adam_eps, "weight_decay": cfg.weight_decay,
+              "clip_norm": cfg.clip_norm, "beta": cfg.entropy_beta}
+    weights = ref.init_weights(seed, model)
+    stream = jax.random.randint(jax.random.PRNGKey(1), (1000,), 0,
+                                cfg.vocab_size, dtype=jnp.int32)
+    return cfg, model, recipe, weights, stream
+
+
+def _program_steps(cfg, weights, stream, steps):
+    """(losses, first step's stats, first gradient by the reference's
+    names, final parameters by the reference's names)."""
+    tree = drv.to_program_tree(weights, cfg.num_hidden_layers)
+    opt, state = M.init_state(cfg, 0, params=tree)
+    tokens = M.batch_at(stream, jnp.int32(0), cfg)
+    grads = jax.grad(lambda p: M.loss_fn(cfg, p, tokens)[0])(state.params)
+    losses, first = [], None
+    for i in range(steps):
+        state, loss = M.train_step(cfg, opt, state, stream)
+        losses.append(float(loss))
+        if i == 0:
+            first = M.read_stats(cfg, state.stats)
+    return (losses, first, drv.from_program_tree(grads),
+            drv.from_program_tree(state.params))
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+# float32 lanes: both sides at float32 (on the CPU a float32 matmul is
+# float32), so only the order of sums differs.  The bf16 lane rounds each
+# matmul operand to 8 bits of mantissa: 2^-9 a value, shrunk by the sums
+# over 64 or more products, grown by 8 layer applications in a row.
+@pytest.mark.parametrize("precision,tol_loss,tol_grad,tol_step", [
+    ("f32", 1e-6, 2e-5, 1e-5), ("bf16", 5e-4, 3e-2, 2e-2)])
+def test_program_follows_the_reference(precision, tol_loss, tol_grad,
+                                       tol_step):
+    cfg, model, recipe, weights, stream = _setup(precision=precision)
+    batches = [np.asarray(M.batch_at(stream, jnp.int32(i), cfg))
+               for i in range(3)]
+    want = ref.train_steps(weights, batches, model, recipe)
+    losses, first, grads, params = _program_steps(cfg, weights, stream, 3)
+
+    for a, b in zip(losses, want["losses"]):
+        assert a == pytest.approx(b, rel=tol_loss)
+    assert first["loss"] == pytest.approx(want["losses"][0], rel=tol_loss)
+    assert first["ce"] == pytest.approx(want["ce"], rel=tol_loss)
+    assert first["exit_prob"] == pytest.approx(want["exit_prob"],
+                                               rel=10 * tol_loss)
+    assert first["grad_norm"] == pytest.approx(want["grad_norm"],
+                                               rel=tol_grad)
+    assert sorted(grads) == sorted(want["grads"])
+    for name in sorted(grads):  # every leaf
+        assert _rel(grads[name], want["grads"][name]) < tol_grad, name
+    # three optimizer steps: each leaf's change against the reference's
+    start = {k: np.asarray(v) for k, v in weights.items()}
+    for name in sorted(params):
+        moved = float(np.linalg.norm(params[name] - start[name]))
+        assert moved == pytest.approx(want["change_norms"][name],
+                                      rel=tol_step, abs=1e-9), name
+
+
+def test_shared_weight_gradient_is_the_sum_over_its_four_uses():
+    """With each pass given its own copy of the layers' weights (the
+    reference's parts, unshared by hand), the gradients of the T copies
+    add up to the program's gradient of the one shared stack."""
+    cfg, model, recipe, weights, stream = _setup()
+    tokens = np.asarray(M.batch_at(stream, jnp.int32(0), cfg))[0]
+    passes, n = cfg.total_ut_steps, cfg.num_hidden_layers
+    kw = dict(heads=cfg.num_attention_heads, head_dim=cfg.head_dim,
+              eps=cfg.rms_norm_eps, theta=cfg.rope_theta)
+
+    def unshared_loss(copies, shared):
+        h = shared["embed"][tokens[:-1]]
+        ces, lams = [], []
+        for t in range(passes):
+            for i in range(n):
+                h = ref.layer(h, copies[t][i], **kw)
+            h = ref.rms_norm(h, shared["final_norm"], cfg.rms_norm_eps)
+            ces.append(ref.token_ce(h, shared["head"], tokens[1:]))
+            lams.append(jax.nn.sigmoid(h @ shared["gate_w"]
+                                       + shared["gate_b"]))
+        p = ref.exit_distribution(jnp.stack(lams))
+        return ref.loss_terms(jnp.stack(ces), p, cfg.entropy_beta)[0]
+
+    copies = [[ref.layer_of(weights, i) for i in range(n)]
+              for _ in range(passes)]
+    with jax.default_matmul_precision("highest"):
+        per_use = jax.grad(unshared_loss)(copies, weights)
+    _, _, grads, _ = _program_steps(cfg, weights, stream, 1)
+    for i in range(n):
+        for leaf in ref.LAYER_MATS + ref.LAYER_GAINS:
+            uses = [np.asarray(per_use[t][i][leaf]) for t in range(passes)]
+            assert _rel(grads[f"l{i}.{leaf}"], sum(uses)) < 2e-5, (i, leaf)
+            # and no single use is the whole of it
+            assert _rel(grads[f"l{i}.{leaf}"], uses[-1]) > 1e-2, (i, leaf)
+
+
+def test_exit_distribution_sums_to_one_and_the_last_pass_takes_the_rest():
+    logits = jnp.asarray(np.random.default_rng(0).normal(size=(4, 50)) * 3,
+                         jnp.float32)
+    p = np.exp(np.asarray(M.exit_log_probs(logits), np.float64))
+    lam = 1.0 / (1.0 + np.exp(-np.asarray(logits, np.float64)))
+    np.testing.assert_allclose(p.sum(axis=0), 1.0, rtol=1e-6)
+    np.testing.assert_allclose(p[0], lam[0], rtol=1e-6)
+    np.testing.assert_allclose(p[2], lam[2] * (1 - lam[0]) * (1 - lam[1]),
+                               rtol=1e-5)
+    np.testing.assert_allclose(p[3], 1.0 - p[:3].sum(axis=0), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(
+        p, np.asarray(ref.exit_distribution(jnp.asarray(lam, jnp.float32))),
+        rtol=1e-5, atol=1e-8)
+    # extreme gates stay finite in log space
+    far = M.exit_log_probs(jnp.asarray([[80.0], [-80.0], [0.0]], jnp.float32))
+    assert np.isfinite(np.asarray(far)).all()
+
+
+def test_one_pass_is_a_plain_transformer():
+    """T = 1: p = 1, no entropy, the loss is the mean cross-entropy of a
+    causal transformer's one forward pass."""
+    cfg, model, recipe, weights, stream = _setup(total_ut_steps=1)
+    tokens = M.batch_at(stream, jnp.int32(0), cfg)
+    tree = drv.to_program_tree(weights, cfg.num_hidden_layers)
+    loss, (ce, p) = M.loss_fn(cfg, tree, tokens)
+    assert np.asarray(p) == pytest.approx([1.0])
+    assert float(loss) == pytest.approx(float(ce[0]), rel=1e-6)
+    with jax.default_matmul_precision("highest"):
+        want_ce, want_p = ref.forward(weights, np.asarray(tokens)[0], model)
+    assert float(loss) == pytest.approx(float(jnp.mean(want_ce)), rel=1e-6)
+    assert np.asarray(want_p) == pytest.approx(1.0)
+
+
+def test_recomputation_changes_no_gradient():
+    cfg, _, _, weights, stream = _setup(precision="bf16")
+    off = M.LoopLMConfig(precision="bf16", remat="none")
+    tokens = M.batch_at(stream, jnp.int32(0), cfg)
+    tree = drv.to_program_tree(weights, cfg.num_hidden_layers)
+    g_on = jax.grad(lambda p: M.loss_fn(cfg, p, tokens)[0])(tree)
+    g_off = jax.grad(lambda p: M.loss_fn(off, p, tokens)[0])(tree)
+    for a, b in zip(jax.tree_util.tree_leaves(g_on),
+                    jax.tree_util.tree_leaves(g_off)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_long_head_is_computed_in_row_blocks(monkeypatch):
+    """Past HEAD_BLOCK_ROWS rows the head's cross-entropy runs a block at
+    a time; the values and the gradients are those of the whole."""
+    cfg, _, _, weights, stream = _setup()
+    tokens = M.batch_at(stream, jnp.int32(0), cfg)
+    tree = drv.to_program_tree(weights, cfg.num_hidden_layers)
+    whole = jax.value_and_grad(lambda p: M.loss_fn(cfg, p, tokens)[0])(tree)
+    monkeypatch.setattr(M, "HEAD_BLOCK_ROWS", 16)
+    blocked = jax.value_and_grad(
+        lambda p: M.loss_fn(cfg, p, tokens)[0])(tree)
+    assert float(blocked[0]) == pytest.approx(float(whole[0]), rel=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(blocked[1]),
+                    jax.tree_util.tree_leaves(whole[1])):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-8)
+
+
+def test_batches_follow_the_stream_in_order_and_wrap():
+    cfg = M.LoopLMConfig(sequence_length=64, sequences_per_step=2)
+    stream = jnp.arange(1000, dtype=jnp.int32)
+    host = drv.host_batches(np.arange(1000), 9, 64, 2)
+    for i in (0, 1, 7, 8):   # step 7 wraps past the stream's end
+        got = np.asarray(M.batch_at(stream, jnp.int32(i), cfg))
+        np.testing.assert_array_equal(got, host[i])
+        assert got[0, 0] == (i * 2 * 64) % 1000 and got.shape == (2, 65)
+    assert np.asarray(M.batch_at(stream, jnp.int32(7), cfg)).min() == 0
+
+
+def test_token_stream_is_a_packed_zipf_stream(tmp_path):
+    from hyperspace_tpu.data import text
+
+    kw = dict(num_tokens=1 << 16, vocab_size=512, doc_len_median=24.0,
+              doc_len_min=4, doc_len_max=64)
+    a = text.synthetic_token_stream(seed=3, **kw)
+    assert a.dtype == np.int32 and a.shape == (1 << 16,)
+    assert a.min() == text.EOD_ID == 0 and a.max() <= 511
+    np.testing.assert_array_equal(a, text.synthetic_token_stream(seed=3, **kw))
+    assert (a != text.synthetic_token_stream(seed=4, **kw)).any()
+    counts = np.bincount(a, minlength=512)
+    # a long-tailed unigram law: rank 1 about twice rank 2, ten times rank 10
+    assert 1.6 < counts[1] / counts[2] < 2.4
+    assert 7 < counts[1] / counts[10] < 13
+    gaps = np.diff(np.flatnonzero(a == 0)) - 1   # document lengths
+    assert gaps.min() >= 4 and gaps.max() <= 64
+    assert 18 < np.median(gaps) < 30
+    # written once, read back, and found by the CLI's loader
+    path = text.ensure_token_stream(str(tmp_path / "s"), seed=3, **kw)
+    stamp = (tmp_path / "s" / "tokens.npy").stat().st_mtime_ns
+    assert text.ensure_token_stream(str(tmp_path / "s"), seed=3,
+                                    **kw) == path
+    assert (tmp_path / "s" / "tokens.npy").stat().st_mtime_ns == stamp
+    got, source = text.load_token_stream(str(tmp_path / "s"))
+    assert source == "disk"
+    np.testing.assert_array_equal(got, a)
+
+
+def test_lane_matmul_keeps_the_weights_gradient_in_float32():
+    from hyperspace_tpu import precision
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 24, 32), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), (32, 16), jnp.float32)
+    y = precision.BF16.matmul(x, w)
+    assert y.dtype == jnp.float32 and y.shape == (3, 24, 16)
+    low = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+    np.testing.assert_allclose(y, low(x) @ low(w), rtol=1e-5, atol=1e-5)
+    dx, dw = jax.grad(lambda x, w: jnp.sum(
+        precision.BF16.matmul(x, w) ** 2), argnums=(0, 1))(x, w)
+    assert dx.dtype == jnp.float32 and dw.dtype == jnp.float32
+    g = low(2 * y)
+    np.testing.assert_allclose(dx, g @ low(w).T, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(
+        dw, low(x).reshape(-1, 32).T @ g.reshape(-1, 16), rtol=1e-5,
+        atol=1e-3)
+    # the f32 preset is the plain product
+    np.testing.assert_array_equal(precision.F32.matmul(x, w), x @ w)
+
+
+# --- through cli.train -------------------------------------------------------
+
+TINY = ["hidden_size=32", "intermediate_size=48", "num_attention_heads=2",
+        "num_key_value_heads=2", "head_dim=16", "vocab_size=128",
+        "num_hidden_layers=2", "sequence_length=32", "stream_tokens=4096"]
+
+
+def test_cli_trains_from_the_published_yaml(capsys, tmp_path):
+    """The repo's yaml is the published config.json whole; cut to a test
+    size by overrides, it trains through run_loop, logs, and sets the
+    looplm/* gauges at the log boundary."""
+    import os
+
+    from hyperspace_tpu.cli import train as T
+    from hyperspace_tpu.telemetry import registry
+
+    yaml_path = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), "configs", "looplm_ouro_2p6b.yaml")
+    log = tmp_path / "run.jsonl"
+    assert T.main(["looplm", "--yaml", yaml_path, *TINY, "steps=6",
+                   "eval_every=3", f"log={log}", "precision=f32"]) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["workload"] == "looplm" and out["steps"] == 6
+    assert out["source"] == "synthetic" and out["tokens_per_step"] == 32
+    assert len(out["ce"]) == 4 and sum(out["exit_prob"]) == pytest.approx(1.0)
+    assert np.isfinite(out["loss"])
+    records = [json.loads(ln) for ln in log.read_text().splitlines()]
+    assert [r["step"] for r in records if "loss" in r] == [3, 6]
+    snap = registry.default_registry().snapshot()
+    assert snap["looplm/ut_steps"] == 4
+    assert snap["looplm/tokens_per_step"] == 32
+    assert [snap[f"looplm/exit_prob_t{t}"] for t in (1, 2, 3, 4)] == \
+        pytest.approx(out["exit_prob"])
+    assert [snap[f"looplm/ce_t{t}"] for t in (1, 2, 3, 4)] == \
+        pytest.approx(out["ce"])
+    assert 1.0 <= snap["looplm/expected_exit_step"] <= 4.0
+
+
+@pytest.mark.parametrize("override,says", [
+    ("model_type=llama", "model_type"),
+    ("use_sliding_window=True", "use_sliding_window"),
+    ('layer_types=["full_attention", "sliding_attention"]', "layer_types"),
+    ("num_key_value_heads=1", "multi-head"),
+    ("max_position_embeddings=16", "max_position_embeddings"),
+    ("tie_word_embeddings=true", "untied"),
+    ("no_such_key=1", "unknown option"),
+])
+def test_cli_refuses_what_the_trainer_does_not_run(override, says):
+    from hyperspace_tpu.cli import train as T
+
+    with pytest.raises(SystemExit) as e:
+        T.main(["looplm", *TINY, override, "steps=1"])
+    assert says in str(e.value)
