@@ -29,6 +29,14 @@ def minkowski_dot(x: jax.Array, y: jax.Array, keepdims: bool = True) -> jax.Arra
     return res if keepdims else res[..., 0]
 
 
+def minkowski_flip(x: jax.Array) -> jax.Array:
+    """J·x, J = diag(-1, 1, …, 1): ⟨x, y⟩_L = x·(J·y), so J·y is the
+    gradient of the Minkowski dot in its other argument."""
+    # x - 2·pad(x₀): lane 0 is x₀ - 2x₀ = -x₀ (Sterbenz: exact),
+    # space lanes subtract an exact 0 — bitwise the concat form
+    return x - 2.0 * _pad_last(x[..., :1], 0, x.shape[-1] - 1)
+
+
 def _pad_last(x: jax.Array, lo: int, hi: int) -> jax.Array:
     """Zero-pad the last axis by (lo, hi) — the time-coordinate
     assembly primitive.  Every Lorentz lift/split used to be a
@@ -114,18 +122,25 @@ class Lorentz(Manifold):
 
     # --- distance -------------------------------------------------------------
 
-    def _neg_cdot(self, x: jax.Array, y: jax.Array) -> jax.Array:
-        """u = -c⟨x,y⟩_L - 1 ≥ 0; dist = arcosh(1+u)/√c (stable form)."""
-        c = self._c(x.dtype)
-        return -c * minkowski_dot(x, y) - 1.0
+    # The distance is a scalar map of the Minkowski dot alone, every clamp
+    # inside it.  Whoever needs the derivative's structure reads it off
+    # that: ∂sqdist/∂x = s·J·y with s = ∂sqdist_of_dot/∂ip one scalar a
+    # pair (nn/edge_dist.py asks for `sqdist_of_dot` by name).
+
+    def dist_of_dot(self, ip: jax.Array) -> jax.Array:
+        """dist(x, y) from ip = ⟨x,y⟩_L: u = -c·ip - 1 ≥ 0;
+        dist = arcosh(1+u)/√c (stable form)."""
+        c = self._c(ip.dtype)
+        return smath.arcosh1p(-c * ip - 1.0) / smath.sqrt_c(c)
+
+    def sqdist_of_dot(self, ip: jax.Array) -> jax.Array:
+        return self.dist_of_dot(ip) ** 2
 
     def dist(self, x: jax.Array, y: jax.Array) -> jax.Array:
-        c = self._c(x.dtype)
-        u = self._neg_cdot(x, y)[..., 0]
-        return smath.arcosh1p(u) / smath.sqrt_c(c)
+        return self.dist_of_dot(minkowski_dot(x, y, keepdims=False))
 
     def sqdist(self, x: jax.Array, y: jax.Array) -> jax.Array:
-        return self.dist(x, y) ** 2
+        return self.sqdist_of_dot(minkowski_dot(x, y, keepdims=False))
 
     # --- exp / log ------------------------------------------------------------
 
@@ -165,10 +180,7 @@ class Lorentz(Manifold):
 
     def egrad2rgrad(self, x: jax.Array, g: jax.Array) -> jax.Array:
         """Flip the time component (Minkowski metric inverse), then proju."""
-        # g - 2·pad(g₀): lane 0 is g₀ - 2g₀ = -g₀ (Sterbenz: exact),
-        # space lanes subtract an exact 0 — bitwise the concat form
-        gl = g - 2.0 * _pad_last(g[..., :1], 0, g.shape[-1] - 1)
-        return self.proju(x, gl)
+        return self.proju(x, minkowski_flip(g))
 
     def retr(self, x: jax.Array, v: jax.Array) -> jax.Array:
         return self.proj(x + v)

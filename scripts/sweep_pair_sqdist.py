@@ -1,7 +1,9 @@
 """The LP decoder's distances, forward + backward, over table sizes:
-`nn.edge_dist.pair_sqdist` (sorted VJP) against ``m.sqdist(z[u], z[v])``
-(XLA's scatter-adds).  Run on the machine with the chip, from the repo
-root (it refuses the CPU); PERF.md §6, PR 27 has a v5e's readings:
+`nn.edge_dist.pair_sqdist` (sorted VJP; Lorentz, so the backward that
+lists one scalar a pair and gathers the other end only) against
+``m.sqdist(z[u], z[v])`` (XLA's scatter-adds).  Run on the machine with
+the chip, from the repo root (it refuses the CPU); PERF.md §6, PR 34 has
+a v5e's readings (PR 27: the backward that gathered both ends):
 
     python scripts/sweep_pair_sqdist.py [N:P ...]
 
@@ -23,9 +25,11 @@ import jax.numpy as jnp
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+# 368,256 rows: a shard of the four-chip cell's table, with the arxiv
+# step's pairs and with its own (8.66 M pairs a step over `data=2`)
 SHAPES = [(2708, 8976), (19717, 75000), (169343, 1880610), (338686, 1880610),
-          (677372, 1880610), (1354744, 1880610), (2449029, 1880610),
-          (2449029, 7522440)]
+          (368256, 1880610), (368256, 4330000), (677372, 1880610),
+          (1354744, 1880610), (2449029, 1880610), (2449029, 7522440)]
 
 
 def main(argv):

@@ -71,3 +71,46 @@ def test_egrad2rgrad_tangency(c):
     np.testing.assert_allclose(
         np.asarray(minkowski_dot(x, rg, keepdims=False)), 0.0, atol=1e-9
     )
+
+
+@pytest.mark.parametrize("jit", [False, True])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("fn", ["dist", "sqdist"])
+def test_distance_split_is_the_inline_formula_bitwise(fn, dtype, jit):
+    """`Lorentz.dist` / `sqdist` are the Minkowski dot followed by a
+    scalar map (`dist_of_dot`, `sqdist_of_dot`: one definition, the LP
+    decoder's backward differentiates the map alone).  The split is a
+    refactor: values, both arguments' gradients and the curvature's are
+    bit for bit those of the formula as it stood in one piece, coincident
+    points (u = 0, where the clamps decide) included."""
+    from hyperspace_tpu.manifolds import smath
+
+    def inline(x, y, c):
+        c = jnp.asarray(c, x.dtype)
+        u = (-c * minkowski_dot(x, y) - 1.0)[..., 0]
+        d = smath.arcosh1p(u) / smath.sqrt_c(c)
+        return d if fn == "dist" else d ** 2
+
+    def split(x, y, c):
+        return getattr(Lorentz(c), fn)(x, y)
+
+    kx, ky, kt = jax.random.split(jax.random.PRNGKey(11), 3)
+    m = Lorentz(0.7)
+    x = m.random_normal(kx, (64, 9), jnp.float32, std=0.8).astype(dtype)
+    y = m.random_normal(ky, (64, 9), jnp.float32, std=0.8).astype(dtype)
+    y = y.at[:16].set(x[:16])  # coincident
+    t = jax.random.normal(kt, (64,), jnp.float32).astype(dtype)
+    c = jnp.asarray(0.7, jnp.float32)
+    outs = []
+    for f in (split, inline):
+        vg = jax.value_and_grad(
+            lambda x, y, c: jnp.sum((f(x, y, c) * t).astype(jnp.float32)),
+            argnums=(0, 1, 2))
+        outs.append(jax.tree.leaves(
+            ((jax.jit(f) if jit else f)(x, y, c),
+             (jax.jit(vg) if jit else vg)(x, y, c))))
+    for a, b in zip(*outs):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.all(np.isfinite(a.astype(np.float32)))
+        assert a.tobytes() == b.tobytes()
