@@ -33,10 +33,15 @@ looplm.py`` is the plain form of the same arithmetic):
   carried accumulator (:func:`forward` says why not T loops);
 - ``remat="layer"`` wraps an application in ``jax.checkpoint``: the
   backward keeps each application's input and recomputes the rest;
-- the head and its cross-entropy run at the end of each pass (a
-  ``lax.cond`` on the layer index), ``HEAD_BLOCK_ROWS`` rows at a time
-  under ``jax.checkpoint``, so one block's ``[rows, V]`` logits live at
-  a time (never a pass's ``[S, V]``, let alone all T);
+- the last layer of a pass is followed by the final norm alone (a
+  ``lax.cond`` on the layer index; its output starts the next pass), and
+  the loop hands out the T normed streams ``[T, S, d]``;
+- the head, its cross-entropy and the gate's logits run ONCE, after the
+  loop, over the T·S rows of all passes: ``HEAD_BLOCK_ROWS`` rows at a
+  time under ``jax.checkpoint``, so one block's ``[rows, V]`` logits live
+  at a time (never a pass's ``[S, V]``, let alone all T), and the loop
+  over the layers never holds an array of the head's shape
+  (:func:`forward` says what that saves);
 - precision lanes (``precision.Policy``): float32 parameters, gradients
   and moments; matmul operands and the attention kernel's q/k/v on the
   ``compute`` lane, cast at their use (``precision.lane_matmul``: no
@@ -220,18 +225,24 @@ def _token_ce(policy, h, head, targets):
 
 
 def _blocked_token_ce(policy, h, head, targets):
-    """:func:`_token_ce` a block of ``HEAD_BLOCK_ROWS`` rows at a time,
-    each block recomputed in the backward: a block's ``[rows, V]`` logits
-    and their cotangent are all that lives of the ``[S, V]`` ones."""
+    """:func:`_token_ce` a block of ``HEAD_BLOCK_ROWS`` rows at a time
+    (ONE ``lax.map``), each block recomputed in the backward: a block's
+    ``[rows, V]`` logits and their cotangent are all that lives of the
+    whole ``[rows of all passes, V]``, and the head's gradient sums in the
+    map's own carry.  Rows that do not fill the last block are padded with
+    zeros and their results dropped, so no size computes more than a block
+    at once."""
     block = jax.checkpoint(functools.partial(_token_ce, policy))
-    s = h.shape[0]
-    if s <= HEAD_BLOCK_ROWS or s % HEAD_BLOCK_ROWS:
+    rows = h.shape[0]
+    n = -(-rows // HEAD_BLOCK_ROWS)
+    if n == 1:
         return block(h, head, targets)
-    n = s // HEAD_BLOCK_ROWS
+    pad = n * HEAD_BLOCK_ROWS - rows
     return jax.lax.map(
         lambda xs: block(xs[0], head, xs[1]),
-        (h.reshape(n, HEAD_BLOCK_ROWS, -1),
-         targets.reshape(n, HEAD_BLOCK_ROWS))).reshape(s)
+        (jnp.pad(h, ((0, pad), (0, 0))).reshape(n, HEAD_BLOCK_ROWS, -1),
+         jnp.pad(targets, (0, pad)).reshape(n, HEAD_BLOCK_ROWS))
+    ).reshape(-1)[:rows]
 
 
 def exit_log_probs(gate_logits):
@@ -249,53 +260,70 @@ def forward(cfg: LoopLMConfig, params, tokens):
     ONE ``lax.scan`` over all T·L layer applications: application j runs
     layer j mod L (the stacked weights are closed over and indexed, so
     every pass reads the same ones), and the last layer of a pass is
-    followed, under ``lax.cond``, by the final norm, the head's
-    cross-entropy and the gate's logit.  One loop and not T of them
-    because of where the gradient lives: the cotangent of what a scan
-    closes over is one carried accumulator, summed over all T uses as
-    the backward goes, where T loops leave T gradients of the stacked
-    weights alive until the optimizer adds them (PERF.md: at the 2.6B
-    widths the step compiled to 21.0 GB with T loops, 17.2 GB with a
-    scan over the passes round a scan over the layers, 15.8 GB so)."""
+    followed, under ``lax.cond``, by the final norm, whose output starts
+    the next pass.  The loop hands out ``hs``, the T normed streams
+    ``[T, S, d]``: it rides the carry beside ``h`` and takes every
+    application's output at slot j // L, outside the checkpoint and under
+    no cond, so XLA updates it in place and a pass's last write is its
+    normed stream.  The head's cross-entropy and the gate's logits then
+    run ONCE, over all T·S rows, after the loop.
+
+    Why one flat loop, and why the head is not in it — where the gradient
+    of what a loop closes over lives (each form compiled for a described
+    v5e at Ouro-2.6B's widths, 8 layers, S = 4096; temporaries):
+    a scan's backward carries one accumulator for each closed-over array.
+    The stacked layers' are indexed, and XLA adds a slice's gradient in
+    place (``dynamic-update-slice`` on the carry).  What a ``lax.cond``
+    inside the body closes over is added WHOLE at every application, its
+    untaken branch first writing the zeros: with the head under the
+    end-of-pass cond that was an ``f32[2048, 49152]`` add 32 times a step
+    and 8.34 GB; only the final norm's ``[d]`` gain is left there, and
+    this form takes 6.84 GB.  A scan over the passes round a scan over
+    the layers (no cond at all) takes 10.9 GB: the inner loop's gradient
+    is a fresh ``[L, …]`` stack a pass that the outer loop adds whole.  T
+    loops in Python leave T such stacks alive until the optimizer adds
+    them (21.0 GB with the head inside)."""
     policy = precision_mod.get_policy(cfg.precision)
     inputs, targets = tokens[:-1], tokens[1:]
-    n_layers, seq = cfg.num_hidden_layers, inputs.shape[0]
+    n_layers, passes, seq = (cfg.num_hidden_layers, cfg.total_ut_steps,
+                             inputs.shape[0])
     with jax.named_scope("embed"):
         rope = rotary_tables(seq, cfg.head_dim, cfg.rope_theta)
         h = params["embed"][inputs]
 
-    def end_of_pass(h):
+    def final_norm(h):
         with jax.named_scope("final_norm"):
-            h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("head"):  # its ``loss`` scope lies inside
-            ce = _blocked_token_ce(policy, h, params["head"], targets)
-        with jax.named_scope("exit_gate"):
-            # a float32 reduction on the vector unit, not an MXU pass
-            gate = jnp.sum(h * params["gate_w"], axis=-1) + params["gate_b"]
-        return h, ce, gate
+            return rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
 
     def application(h, j):
         i = j % n_layers
         h = _layer(cfg, policy, rope, h, jax.tree_util.tree_map(
             lambda a: a[i], params["layers"]))
-        nothing = jnp.zeros((seq,), jnp.float32)
-        return jax.lax.cond(i == n_layers - 1, end_of_pass,
-                            lambda h: (h, nothing, nothing), h)
+        return jax.lax.cond(i == n_layers - 1, final_norm, lambda h: h, h)
 
     if cfg.remat == "layer":
         application = jax.checkpoint(application)
 
-    def body(h, j):
-        h, ce, gate = application(h, j)
-        return h, (ce, gate)
+    def body(carry, j):
+        h, hs = carry
+        h = application(h, j)
+        return (h, jax.lax.dynamic_update_index_in_dim(
+            hs, h, j // n_layers, 0)), None
 
     with jax.named_scope("ut_step"):
-        _, (ce, gate) = jax.lax.scan(
-            body, h, jnp.arange(cfg.total_ut_steps * n_layers))
-    last = slice(n_layers - 1, None, n_layers)   # each pass's last layer
+        (_, hs), _ = jax.lax.scan(
+            body, (h, jnp.zeros((passes,) + h.shape, h.dtype)),
+            jnp.arange(passes * n_layers))
+    with jax.named_scope("head"):  # its ``loss`` scope lies inside
+        # a row's cross-entropy does not know its pass: all T·S rows as one
+        ce = _blocked_token_ce(
+            policy, hs.reshape(passes * seq, -1), params["head"],
+            jnp.tile(targets, passes)).reshape(passes, seq)
     with jax.named_scope("exit_gate"):
-        log_p = exit_log_probs(gate[last])
-    return ce[last], log_p
+        # a float32 reduction on the vector unit, not an MXU pass
+        gate = jnp.sum(hs * params["gate_w"], axis=-1) + params["gate_b"]
+        log_p = exit_log_probs(gate)
+    return ce, log_p
 
 
 def loss_fn(cfg: LoopLMConfig, params, tokens):

@@ -17,6 +17,7 @@ executable compiled for a described device cannot be read back here.
 """
 
 import os
+import re
 import time
 
 import jax
@@ -363,9 +364,14 @@ def test_looplm_train_step_at_the_published_widths(one_chip):
     benchmark's ouro_2p6b.pretrain4k): Ouro-2.6B's widths, 8 layers, 4
     passes, one 4,096-token sequence.  It must compile for one chip with
     its three flash kernels and hold under the chip's 16.9 GB
-    ``bytes_limit``: 7.35 GB of parameters and moments, and under 8.7 GB
-    of temporaries (the gradient, 32 saved layer inputs, a block of
-    logits) — the T-loops form of the same step took 13.6 GB of them."""
+    ``bytes_limit``: 7.35 GB of parameters and moments, and 6.84 GB of
+    temporaries (the gradient, 32 saved layer inputs, the four passes'
+    normed streams, a block of logits) — 8.34 with the head under the
+    end-of-pass cond, 10.9 with a scan over the passes round a scan over
+    the layers.  The loop over the layer applications holds no array of
+    the head's shape, forward or backward: what a cond in the loop's body
+    closes over is added whole to a carry at each of the 32 applications
+    (``models/looplm.py`` ``forward``)."""
     import yaml
 
     from hyperspace_tpu.cli import train as T
@@ -396,6 +402,11 @@ def test_looplm_train_step_at_the_published_widths(one_chip):
     assert 7.3e9 < mem.alias_size_in_bytes < 7.4e9   # the state, donated
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
-    assert mem.temp_size_in_bytes < 8.7e9, mem.temp_size_in_bytes
+    # what the program reads (6,838,532,096) plus 5 %
+    assert mem.temp_size_in_bytes < 7.18e9, mem.temp_size_in_bytes
+    in_the_loop = [ln.strip()[:200] for ln in text.splitlines() if re.match(
+        r"\s*(?:ROOT )?%\S+ = f32\[2048,49152\]", ln) and re.search(
+        r'op_name="[^"]*ut_step', ln)]
+    assert not in_the_loop, in_the_loop
     assert held < 16.3e9, f"{held / 1e9:.2f} GB of a 16.9 GB bytes_limit"
     assert took < 300, f"compile took {took:.0f}s"

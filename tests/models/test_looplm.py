@@ -5,6 +5,7 @@ AdamW steps; what the loop means for the gradient; the exit
 distribution; the trainer through ``cli.train``."""
 
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -169,20 +170,99 @@ def test_recomputation_changes_no_gradient():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_long_head_is_computed_in_row_blocks(monkeypatch):
-    """Past HEAD_BLOCK_ROWS rows the head's cross-entropy runs a block at
-    a time; the values and the gradients are those of the whole."""
-    cfg, _, _, weights, stream = _setup()
+# the head runs once over the T·S rows of all four passes, ``block`` rows
+# at a time; (S, block): what the rows do to the blocks
+HEAD_CASES = {
+    "S a multiple of the block": (64, 16),
+    "S not, T*S is: a block straddles two passes": (40, 16),
+    "neither: the last block is padded": (25, 16),
+    "S under one block, T*S two blocks": (8, 16),
+    "all T*S rows under one block": (3, 16),
+}
+
+
+@pytest.mark.parametrize("seq,block", HEAD_CASES.values(),
+                         ids=HEAD_CASES.keys())
+def test_long_head_is_computed_in_row_blocks(monkeypatch, seq, block):
+    """The head's cross-entropy runs over all passes' rows a block at a
+    time; each pass's per-token cross-entropy, the exit probabilities and
+    every leaf's gradient are the plain reference's at float32."""
+    cfg, model, recipe, weights, stream = _setup(sequence_length=seq)
+    monkeypatch.setattr(M, "HEAD_BLOCK_ROWS", block)
     tokens = M.batch_at(stream, jnp.int32(0), cfg)
     tree = drv.to_program_tree(weights, cfg.num_hidden_layers)
-    whole = jax.value_and_grad(lambda p: M.loss_fn(cfg, p, tokens)[0])(tree)
-    monkeypatch.setattr(M, "HEAD_BLOCK_ROWS", 16)
-    blocked = jax.value_and_grad(
+    ce, log_p = M.forward(cfg, tree, tokens[0])
+    assert ce.shape == log_p.shape == (cfg.total_ut_steps, seq)
+    host = np.asarray(tokens)
+    with jax.default_matmul_precision("highest"):   # jitted: one compile
+        want_ce, want_p = jax.jit(
+            lambda w: ref.forward(w, host[0], model))(weights)
+    np.testing.assert_allclose(ce, want_ce, rtol=1e-5)
+    np.testing.assert_allclose(np.exp(log_p), want_p, rtol=1e-5, atol=1e-8)
+    loss, grads = jax.value_and_grad(
         lambda p: M.loss_fn(cfg, p, tokens)[0])(tree)
-    assert float(blocked[0]) == pytest.approx(float(whole[0]), rel=1e-6)
-    for a, b in zip(jax.tree_util.tree_leaves(blocked[1]),
-                    jax.tree_util.tree_leaves(whole[1])):
-        np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-8)
+    want_loss, _, _, want = jax.jit(lambda w: ref.loss_and_grads(
+        w, host, model, recipe["beta"]))(weights)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    grads = drv.from_program_tree(grads)
+    assert sorted(grads) == sorted(want)
+    for name in sorted(grads):  # every leaf
+        assert _rel(grads[name], want[name]) < 2e-5, name
+
+
+def _computations(hlo_text):
+    """{computation: [instruction lines]} of an HLO module's text."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            cur = comps.setdefault(
+                re.search(r"%?([\w.\-]+) \(", line).group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _under(comps, name, seen):
+    """``name`` and every computation that an instruction under it names
+    (``body=``, ``calls=``, ``branch_computations={…}`` and the like)."""
+    if name not in seen:
+        seen.add(name)
+        for line in comps[name]:
+            for called in set(re.findall(r"[\w.\-]+", line)) & comps.keys():
+                _under(comps, called, seen)
+    return seen
+
+
+def test_layer_loop_holds_no_array_of_the_heads_shape(monkeypatch):
+    """Compiled, the loop over the layer applications (forward and
+    backward ``while``) has no ``[d, V]`` result anywhere in its body:
+    what a ``lax.cond`` inside the loop closes over is added whole to a
+    carry at every application, so the head stays out of the loop."""
+    # sizes at which [d, V] is the head's shape alone: a block of logits
+    # is [16, 320], the streams [S, d] = [48, 64]
+    cfg, _, _, weights, stream = _setup(vocab_size=320, sequence_length=48)
+    monkeypatch.setattr(M, "HEAD_BLOCK_ROWS", 16)
+    head_shape = f"f32[{cfg.hidden_size},{cfg.vocab_size}]"
+    tree = drv.to_program_tree(weights, cfg.num_hidden_layers)
+    opt, state = M.init_state(cfg, 0, params=tree)
+    text = M.train_step.lower(cfg, opt, state, stream).compile().as_text()
+    assert head_shape in text   # the head's gradient, somewhere
+    comps = _computations(text)
+    loops = [line for lines in comps.values() for line in lines
+             if re.search(r" while\(", line)
+             and re.search(r'op_name="[^"]*ut_step\)*/while"', line)]
+    assert len(loops) == 2, [ln[:200] for ln in loops]   # forward, backward
+    for loop in loops:
+        inside = _under(comps, re.search(r"body=%?([\w.\-]+)",
+                                         loop).group(1), set())
+        found = [line.strip()[:160] for name in inside
+                 for line in comps[name]
+                 if re.match(rf"\s*(?:ROOT )?%?[\w.\-]+ = "
+                             rf"{re.escape(head_shape)}", line)]
+        assert len(inside) > 3, inside   # the walk reached the nested calls
+        assert not found, found
 
 
 def test_batches_follow_the_stream_in_order_and_wrap():
