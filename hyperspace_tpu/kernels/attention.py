@@ -40,7 +40,10 @@ the looped language model's attention) is the scaled dot product: score
 a block wholly above the diagonal is neither fetched nor computed, the
 diagonal's blocks are masked from positions — where this form takes no
 dense mask operand at all.  Its calls are named ``flash_dot_fwd``,
-``flash_dot_dq``, ``flash_dot_dkv``.
+``flash_dot_dq``, ``flash_dot_dkv``, and the two residuals that only the
+forward call can produce carry ``jax.ad_checkpoint`` names
+(:data:`FLASH_DOT_OUT`, :data:`FLASH_DOT_LSE`), so a ``jax.checkpoint``
+round a caller can keep them by name and not run the call a second time.
 """
 
 from __future__ import annotations
@@ -50,6 +53,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -684,8 +688,20 @@ def _flash_dot3(q3, k3, v3, form, mode_):
     return out
 
 
+# the names of what only the forward call can produce, on the values the
+# backward reads: the output [B, Nq, D] in q's dtype and the rows'
+# log-sum-exp, float32 [B, Nq padded to the q block] (never the kernel's
+# raw [B, Nq, 128] statistics tile).  ``checkpoint_name`` is the identity
+# but under a ``jax.checkpoint`` whose policy saves these names
+# (``models/looplm.py``); q, k and v are the caller's to recompute.
+FLASH_DOT_OUT = "flash_dot_out"
+FLASH_DOT_LSE = "flash_dot_lse"
+
+
 def _fd3_fwd(q3, k3, v3, form, mode_):
     out, lse, _ = _launch(q3, k3, v3, form, (), None, mode_)
+    out = checkpoint_name(out, FLASH_DOT_OUT)  # primal result and residual
+    lse = checkpoint_name(lse, FLASH_DOT_LSE)
     return out, (q3, k3, v3, out, lse)
 
 
@@ -712,7 +728,8 @@ def flash_dot_attention(q, k, v, *, causal=False):
     position ≤ query position *inside* the kernels: blocks above the
     diagonal are neither fetched nor computed, the diagonal's blocks are
     masked from positions, forward and both backward kernels alike.  No
-    dense mask operand exists in this form.  The XLA twin serves the CPU.
+    dense mask operand exists in this form.  The XLA twin serves the CPU
+    (and names no residual: under a checkpoint it is recomputed whole).
     """
     scale = 1.0 / (q.shape[-1] ** 0.5)
     if causal and q.shape[-2] != k.shape[-2]:

@@ -32,7 +32,11 @@ looplm.py`` is the plain form of the same arithmetic):
   and autodiff sums each weight's gradient over its T uses in one
   carried accumulator (:func:`forward` says why not T loops);
 - ``remat="layer"`` wraps an application in ``jax.checkpoint``: the
-  backward keeps each application's input and recomputes the rest;
+  backward keeps each application's input and, by name, the flash
+  forward call's output and row statistics (what only the kernel can
+  produce: half the input's bytes), and recomputes the rest — one path
+  at every size and precision, the same algorithm with one more thing
+  kept (:func:`_keep_flash_results`);
 - the last layer of a pass is followed by the final norm alone (a
   ``lax.cond`` on the layer index; its output starts the next pass), and
   the loop hands out the T normed streams ``[T, S, d]``;
@@ -67,9 +71,11 @@ import jax.numpy as jnp
 import optax
 
 from hyperspace_tpu import precision as precision_mod
-from hyperspace_tpu.kernels.attention import flash_dot_attention
+from hyperspace_tpu.kernels.attention import (FLASH_DOT_LSE, FLASH_DOT_OUT,
+                                              flash_dot_attention)
 from hyperspace_tpu.nn.layers import (apply_rotary, rms_norm, rotary_tables,
                                       swiglu)
+from hyperspace_tpu.telemetry import registry
 
 LAYER_MATS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 LAYER_GAINS = ("n1", "n2", "n3", "n4")
@@ -254,6 +260,32 @@ def exit_log_probs(gate_logits):
     return jnp.concatenate([(log_lam + before)[:-1], before[-1:]], axis=0)
 
 
+def _keep_flash_results():
+    """The policy of ``remat="layer"``: of an application, beyond its
+    input, keep what the flash forward call alone can produce — its
+    output ``[H, S, D]`` on the compute lane and the rows' float32
+    log-sum-exp ``[H, S]``, named in ``kernels/attention.py`` — so the
+    backward never runs that call a second time; q, k, v and everything
+    else are recomputed.  jax asks the policy about every equation while
+    it splits an application into what runs forward and what backward;
+    the bytes it grants are the gauge ``looplm/remat_kept_bytes``, set at
+    trace time: 0 where no kernel ran (the XLA twin names nothing and is
+    recomputed whole) and until a gradient is taken."""
+    named = jax.checkpoint_policies.save_only_these_names(FLASH_DOT_OUT,
+                                                          FLASH_DOT_LSE)
+    kept = {}
+    registry.set_gauge("looplm/remat_kept_bytes", 0)
+
+    def policy(prim, *avals, **params):
+        keep = named(prim, *avals, **params)
+        if keep:
+            kept[params["name"]] = avals[0].size * avals[0].dtype.itemsize
+            registry.set_gauge("looplm/remat_kept_bytes", sum(kept.values()))
+        return keep
+
+    return policy
+
+
 def forward(cfg: LoopLMConfig, params, tokens):
     """tokens [S + 1] int32 -> (ce [T, S], log p [T, S]).
 
@@ -261,7 +293,12 @@ def forward(cfg: LoopLMConfig, params, tokens):
     layer j mod L (the stacked weights are closed over and indexed, so
     every pass reads the same ones), and the last layer of a pass is
     followed, under ``lax.cond``, by the final norm, whose output starts
-    the next pass.  The loop hands out ``hs``, the T normed streams
+    the next pass.  Under ``remat="layer"`` an application is checkpointed
+    with :func:`_keep_flash_results`: its input, the flash call's output
+    and row statistics are kept, the rest recomputed (the kernel runs
+    T·L times forward and never again in the backward; 17 MB an
+    application at Ouro's widths beside the 33.5 MB input).  The loop
+    hands out ``hs``, the T normed streams
     ``[T, S, d]``: it rides the carry beside ``h`` and takes every
     application's output at slot j // L, outside the checkpoint and under
     no cond, so XLA updates it in place and a pass's last write is its
@@ -302,7 +339,8 @@ def forward(cfg: LoopLMConfig, params, tokens):
         return jax.lax.cond(i == n_layers - 1, final_norm, lambda h: h, h)
 
     if cfg.remat == "layer":
-        application = jax.checkpoint(application)
+        application = jax.checkpoint(application,
+                                     policy=_keep_flash_results())
 
     def body(carry, j):
         h, hs = carry

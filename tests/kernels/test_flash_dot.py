@@ -1,9 +1,11 @@
 """The flash kernel's dot-product form (``flash_dot_attention``): the
 score q·k × scale with no epilogue and the causal structure inside the
 kernel, forward and both backward kernels, in interpret mode against
-the dense twin at block-edge and non-multiple lengths; the calls' own
-names; and the lorentz form's outputs and gradients unchanged to the
-bit by the refactor that let one recurrence carry two forms."""
+the dense twin at block-edge and non-multiple lengths, alone and under a
+``jax.checkpoint`` that keeps the forward call's named results; the
+calls' and the residuals' own names; and the lorentz form's outputs and
+gradients unchanged to the bit by the refactor that let one recurrence
+carry two forms."""
 
 import hashlib
 
@@ -30,17 +32,32 @@ def _value_and_grads(fn, q, k, v, w):
     return (out,) + jax.grad(weighted, argnums=(0, 1, 2))(q, k, v)
 
 
+# what stands round the call: nothing; a checkpoint that keeps its inputs
+# alone (the names are the identity, the forward call runs again in the
+# backward); one whose policy keeps the forward call's two named results
+AROUND = {
+    "alone": lambda f: f,
+    "checkpoint": jax.checkpoint,
+    "checkpoint keeping the names": lambda f: jax.checkpoint(
+        f, policy=jax.checkpoint_policies.save_only_these_names(
+            katt.FLASH_DOT_OUT, katt.FLASH_DOT_LSE)),
+}
+
+
 # 512 = one block of each kernel; 520 and 1100 leave a ragged last block
 # in q and kv; 1024 is a whole number of blocks with a diagonal inside
+@pytest.mark.parametrize("around", AROUND)
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
 @pytest.mark.parametrize("n,d", [(64, 16), (512, 128), (520, 64),
                                  (1024, 128), (1100, 128)])
-def test_dot_form_matches_dense_twin(interp, n, d, causal):
+def test_dot_form_matches_dense_twin(interp, n, d, causal, around):
     q, k, v, w = _qkv(n, (2,), n, d)
     scale = 1.0 / d ** 0.5
-    got = _value_and_grads(
-        lambda q, k, v: katt.flash_dot_attention(q, k, v, causal=causal),
-        q, k, v, w)
+    attend = lambda q, k, v: katt.flash_dot_attention(q, k, v, causal=causal)
+    got = _value_and_grads(AROUND[around](attend), q, k, v, w)
+    if around != "alone":   # the names change no bit of value or gradient
+        for a, b in zip(got, _value_and_grads(attend, q, k, v, w)):
+            np.testing.assert_array_equal(a, b)
     want = _value_and_grads(
         lambda q, k, v: katt._t_flash_dot(q, k, v, scale, causal),
         q, k, v, w)
@@ -108,6 +125,36 @@ def test_the_calls_carry_their_names(interp):
         q, k, v).jaxpr, [])
     assert sorted(names) == ["flash_dot_dkv", "flash_dot_dq",
                              "flash_dot_fwd"]
+
+
+def _saved(capsys, fn, *args):
+    """jax's own account of what a backward of ``fn`` keeps: (array,
+    why), without the source line."""
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(fn, *args)
+    lines = capsys.readouterr().out.splitlines()
+    return sorted((array, why.split(" from /")[0])
+                  for array, why in (ln.split(" ", 1) for ln in lines))
+
+
+def test_the_residuals_carry_their_names(interp, capsys):
+    """What only the forward call can produce is named on the values the
+    backward reads: the output in q's dtype and the rows' float32
+    log-sum-exp, not the kernel's [B, Nq, 128] statistics tile; a policy
+    that saves the names keeps exactly those two beside the arguments."""
+    assert (katt.FLASH_DOT_OUT, katt.FLASH_DOT_LSE) == (
+        "flash_dot_out", "flash_dot_lse")
+    q, k, v, _ = _qkv(1, (2,), 64, 16, jnp.bfloat16)
+    attend = lambda q, k, v: katt.flash_dot_attention(q, k, v, causal=True)
+    named = [r for r in _saved(capsys, attend, q, k, v)
+             if r[1].startswith("named")]
+    assert named == [("bf16[2,64,16]", "named 'flash_dot_out'"),
+                     ("f32[2,64]", "named 'flash_dot_lse'")]
+    kept = _saved(capsys, AROUND["checkpoint keeping the names"](attend),
+                  q, k, v)
+    assert [r for r in kept if "argument" not in r[1]] == named
+    assert len(kept) == 5   # q, k, v and the two
+    assert len(_saved(capsys, AROUND["checkpoint"](attend), q, k, v)) == 3
 
 
 # --- the lorentz form, to the bit ----------------------------------------------

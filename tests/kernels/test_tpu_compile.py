@@ -358,25 +358,44 @@ def test_train_step_lp_at_arxiv_width(one_chip, arxiv_split):
     assert took < 300, f"compile took {took:.0f}s"
 
 
-def test_looplm_train_step_at_the_published_widths(one_chip):
+# (the configuration's lane, temporaries as read + 5 %, arguments + outputs
+# − aliased + temporaries under).  The float32 twin is what the benchmark
+# drives after the window (``check_twin``): it keeps f32[32,16,4096,128],
+# 1.07 GB, where the stated lane keeps 0.54
+LOOPLM_LANES = {
+    "the cell: bf16": ({}, 8.1e9, 16.3e9),
+    "its check twin: f32 at highest": (
+        {"precision": "f32", "matmul_precision": "highest"}, 8.85e9, 16.6e9),
+}
+
+
+@pytest.mark.parametrize("lane", LOOPLM_LANES)
+def test_looplm_train_step_at_the_published_widths(one_chip, lane):
     """``looplm.train_step`` as ``cli.train looplm --yaml
     configs/looplm_ouro_2p6b.yaml num_hidden_layers=8`` builds it (the
     benchmark's ouro_2p6b.pretrain4k): Ouro-2.6B's widths, 8 layers, 4
     passes, one 4,096-token sequence.  It must compile for one chip with
     its three flash kernels and hold under the chip's 16.9 GB
-    ``bytes_limit``: 7.35 GB of parameters and moments, and 6.84 GB of
-    temporaries (the gradient, 32 saved layer inputs, the four passes'
-    normed streams, a block of logits) — 8.34 with the head under the
-    end-of-pass cond, 10.9 with a scan over the passes round a scan over
-    the layers.  The loop over the layer applications holds no array of
-    the head's shape, forward or backward: what a cond in the loop's body
-    closes over is added whole to a carry at each of the 32 applications
+    ``bytes_limit``: 7.35 GB of parameters and moments, and 7.71 GB of
+    temporaries by the compiler's upper count (the gradient, 32 saved
+    layer inputs, the 32 kept flash outputs and row statistics, the four
+    passes' normed streams, a block of logits; 6.84 before the flash
+    results were kept) — 8.34 with the head under the end-of-pass cond,
+    10.9 with a scan over the passes round a scan over the layers.  The
+    forward flash call is in the program ONCE, in the forward loop: the
+    backward reads the kept output and never runs it again.  The loop
+    over the layer applications holds no array of the head's shape,
+    forward or backward: what a cond in the loop's body closes over is
+    added whole to a carry at each of the 32 applications
     (``models/looplm.py`` ``forward``)."""
+    import dataclasses
+
     import yaml
 
     from hyperspace_tpu.cli import train as T
     from hyperspace_tpu.models import looplm
 
+    stated, temp_under, held_under = LOOPLM_LANES[lane]
     with open(os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__)))), "configs",
             "looplm_ouro_2p6b.yaml")) as f:
@@ -389,6 +408,8 @@ def test_looplm_train_step_at_the_published_widths(one_chip):
     cfg, _ = T._looplm_config(run, overrides)
     assert (cfg.hidden_size, cfg.head_dim, cfg.sequence_length,
             cfg.precision) == (2048, 128, 4096, "bf16")
+    cfg = dataclasses.replace(cfg, **stated)
+    kept_dtype = "bf16" if cfg.precision == "bf16" else "f32"
     opt = looplm.make_optimizer(cfg)
     state = jax.eval_shape(lambda: looplm.init_state(cfg, 0)[1])
     args = _shapes((state, jax.ShapeDtypeStruct((1 << 24,), I32)), one_chip)
@@ -396,17 +417,25 @@ def test_looplm_train_step_at_the_published_widths(one_chip):
     compiled = looplm.train_step.lower(cfg, opt, *args).compile()
     took = time.perf_counter() - t0
     text = compiled.as_text()
-    for name in ("flash_dot_fwd", "flash_dot_dq", "flash_dot_dkv"):
-        assert name in text, name
+    calls = {name: len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text))
+             for name in ("flash_dot_fwd", "flash_dot_dq", "flash_dot_dkv")}
+    assert calls == {"flash_dot_fwd": 1, "flash_dot_dq": 1,
+                     "flash_dot_dkv": 1}
+    # the forward loop's stacks: the inputs, the flash outputs, their rows'
+    # log-sum-exp; never the kernel's [.., 128] statistics tile
+    for kept in ("f32[32,4096,2048]", f"{kept_dtype}[32,16,4096,128]",
+                 "f32[32,16,4096]"):
+        assert kept + "{" in text, kept
+    assert "f32[32,16,4096,128]" not in text or kept_dtype == "f32"
     mem = compiled.memory_analysis()
     assert 7.3e9 < mem.alias_size_in_bytes < 7.4e9   # the state, donated
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
-    # what the program reads (6,838,532,096) plus 5 %
-    assert mem.temp_size_in_bytes < 7.18e9, mem.temp_size_in_bytes
+    # what the program reads (7,710,826,496; the twin 8,429,889,024) + 5 %
+    assert mem.temp_size_in_bytes < temp_under, mem.temp_size_in_bytes
     in_the_loop = [ln.strip()[:200] for ln in text.splitlines() if re.match(
         r"\s*(?:ROOT )?%\S+ = f32\[2048,49152\]", ln) and re.search(
         r'op_name="[^"]*ut_step', ln)]
     assert not in_the_loop, in_the_loop
-    assert held < 16.3e9, f"{held / 1e9:.2f} GB of a 16.9 GB bytes_limit"
+    assert held < held_under, f"{held / 1e9:.2f} GB of a 16.9 GB bytes_limit"
     assert took < 300, f"compile took {took:.0f}s"

@@ -1,9 +1,11 @@
 """LoopLM (models/looplm.py) against its plain reference
 (benchmark/reference/looplm.py) on seeded weights at a tiny size: loss,
 per-pass cross-entropies, exit probabilities, every gradient leaf, three
-AdamW steps; what the loop means for the gradient; the exit
-distribution; the trainer through ``cli.train``."""
+AdamW steps; what the loop means for the gradient; what ``remat="layer"``
+keeps of an application and what it runs again; the exit distribution;
+the trainer through ``cli.train``."""
 
+import dataclasses
 import json
 import re
 
@@ -15,6 +17,7 @@ import pytest
 from benchmark.drivers import train_lm as drv
 from benchmark.reference import looplm as ref
 from hyperspace_tpu.models import looplm as M
+from hyperspace_tpu.telemetry import registry
 
 MODEL_KEYS = ("hidden_size", "intermediate_size", "vocab_size",
               "num_hidden_layers", "num_attention_heads", "head_dim",
@@ -168,6 +171,177 @@ def test_recomputation_changes_no_gradient():
     for a, b in zip(jax.tree_util.tree_leaves(g_on),
                     jax.tree_util.tree_leaves(g_off)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# --- what remat="layer" keeps: the kernel in interpret mode ------------------
+
+KEPT_GAUGE = "looplm/remat_kept_bytes"
+LANES = {"bf16": np.dtype(jnp.bfloat16), "f32": np.dtype("float32")}
+
+
+@pytest.fixture
+def interp(monkeypatch):
+    monkeypatch.setenv("HYPERSPACE_KERNELS", "interpret")
+
+
+def _grad_jaxpr(precision, **cfg_kw):
+    cfg, _, _, weights, stream = _setup(precision=precision, **cfg_kw)
+    tokens = M.batch_at(stream, jnp.int32(0), cfg)
+    tree = drv.to_program_tree(weights, cfg.num_hidden_layers)
+    return cfg, jax.make_jaxpr(jax.grad(
+        lambda p: M.loss_fn(cfg, p, tokens)[0]))(tree).jaxpr
+
+
+def _walk(jaxpr, times=1):
+    """(equation, how often it runs a step) under ``jaxpr``: a scan's
+    body counts ``length`` times."""
+    for eqn in jaxpr.eqns:
+        yield eqn, times
+        inside = times * eqn.params.get("length", 1) \
+            if eqn.primitive.name == "scan" else times
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk(inner, inside)
+
+
+def _forward_stacks(jaxpr):
+    """(length, what the forward loop stacks for the backward, one slice
+    an application): the first scan's outputs beyond its carry."""
+    loop = next(e for e, _ in _walk(jaxpr) if e.primitive.name == "scan")
+    return loop.params["length"], loop.outvars[loop.params["num_carry"]:]
+
+
+def _kernel_calls(jaxpr):
+    calls = {}
+    for eqn, times in _walk(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            calls[name] = calls.get(name, 0) + times
+    return calls
+
+
+# (lane, S, operation by operation?, the other form, each leaf within).
+# bf16 lane: the loops' bodies compiled, as the program runs them.  float32
+# lane against the parent's form: operation by operation
+# (``jax.disable_jit``) at a shorter sequence, because XLA's CPU compiler
+# fuses the interpreter's kernel into the loop's body, and rounds it,
+# differently in a body that holds it once and one that holds it twice
+# (1e-7 of the largest value); a Mosaic kernel on the chip is one program
+# wherever it is called.  float32 lane against no checkpoint at all:
+# autodiff sums a value's cotangents in another order there, with or
+# without this policy, kernel or twin, so that pair is held to rounding
+SAME_ARITHMETIC = {
+    "bf16, remat=none": ("bf16", 64, False, "none", 0.0),
+    "bf16, the parent's checkpoint": ("bf16", 64, False, "parent", 0.0),
+    "f32, the parent's checkpoint": ("f32", 16, True, "parent", 0.0),
+    "f32, remat=none: sums in another order": ("f32", 64, False, "none",
+                                               1e-5),
+}
+
+
+@pytest.mark.parametrize("precision,seq,whole_ops,other,within",
+                         SAME_ARITHMETIC.values(), ids=SAME_ARITHMETIC.keys())
+def test_kept_flash_results_change_no_bit(interp, monkeypatch, precision,
+                                          seq, whole_ops, other, within):
+    """Keeping the flash call's output and row statistics is the same
+    arithmetic: loss and every gradient leaf equal, bit for bit, those of
+    no recomputation at all and of a checkpoint that keeps the input
+    alone (the parent's form: no policy, the kernel run twice)."""
+    cfg, _, _, weights, stream = _setup(precision=precision,
+                                        sequence_length=seq)
+    tokens = M.batch_at(stream, jnp.int32(0), cfg)
+    tree = drv.to_program_tree(weights, cfg.num_hidden_layers)
+
+    def loss_and_grads(cfg):
+        with jax.disable_jit(whole_ops):
+            return jax.value_and_grad(
+                lambda p: M.loss_fn(cfg, p, tokens)[0])(tree)
+
+    loss, grads = loss_and_grads(cfg)
+    if other == "none":
+        cfg = dataclasses.replace(cfg, remat="none")
+    else:
+        monkeypatch.setattr(M, "_keep_flash_results", lambda: None)
+    want_loss, want = loss_and_grads(cfg)
+    assert np.isfinite(float(loss))
+    assert float(loss) == pytest.approx(float(want_loss), rel=within, abs=0)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(want)):
+        assert np.abs(np.asarray(a)).max() > 0, jax.tree_util.keystr(path)
+        if within:
+            assert _rel(a, b) < within, jax.tree_util.keystr(path)
+        else:
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b),
+                err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("precision", ["bf16", "f32"])
+def test_backward_never_runs_the_flash_forward_call_again(
+        interp, monkeypatch, precision):
+    """A step holds T·L forward flash calls, T·L ``dq`` and T·L ``dkv``;
+    the checkpoint that keeps the input alone holds 2·T·L forward calls."""
+    cfg, jaxpr = _grad_jaxpr(precision)
+    each = cfg.total_ut_steps * cfg.num_hidden_layers
+    assert _kernel_calls(jaxpr) == {
+        "flash_dot_fwd": each, "flash_dot_dq": each, "flash_dot_dkv": each}
+    monkeypatch.setattr(M, "_keep_flash_results", lambda: None)
+    assert _kernel_calls(_grad_jaxpr(precision)[1]) == {
+        "flash_dot_fwd": 2 * each, "flash_dot_dq": each,
+        "flash_dot_dkv": each}
+
+
+@pytest.mark.parametrize("precision", ["bf16", "f32"])
+def test_an_application_keeps_its_input_and_the_flash_results(interp,
+                                                              precision):
+    """What the forward loop stacks for the backward, one slice an
+    application: the input ``[S, d]``, the flash output ``[H, S, D]`` on
+    the compute lane, the rows' log-sum-exp ``f32[H, S]`` and the
+    application's index; never the kernel's ``[H, S, 128]`` statistics
+    tile, q, k, v or anything of the feed-forward.  The gauge reads the
+    two kept arrays' bytes."""
+    cfg, jaxpr = _grad_jaxpr(precision)
+    each = cfg.total_ut_steps * cfg.num_hidden_layers
+    heads, seq, dh = (cfg.num_attention_heads, cfg.sequence_length,
+                      cfg.head_dim)
+    length, stacked = _forward_stacks(jaxpr)
+    assert length == each
+    index_dtype = jnp.arange(1).dtype   # int64 under the tests' x64
+    found = sorted((v.aval.shape[1:], v.aval.dtype) for v in stacked)
+    assert found == sorted([
+        ((), index_dtype), ((seq, cfg.hidden_size), np.dtype("float32")),
+        ((heads, seq, dh), LANES[precision]),
+        ((heads, seq), np.dtype("float32"))])
+    want = heads * seq * (dh * LANES[precision].itemsize + 4)
+    assert registry.default_registry().snapshot()[KEPT_GAUGE] == want
+
+
+def test_the_xla_twin_names_nothing_and_keeps_the_input_alone():
+    """No kernel (the CPU's dense twin): the gauge reads 0 and the loop
+    stacks the input and the index only."""
+    cfg, jaxpr = _grad_jaxpr("bf16")
+    _, stacked = _forward_stacks(jaxpr)
+    assert sorted(v.aval.ndim for v in stacked) == [1, 3]
+    assert _kernel_calls(jaxpr) == {}
+    assert registry.default_registry().snapshot()[KEPT_GAUGE] == 0
+
+
+@pytest.mark.parametrize("seq,heads,dh,precision,want", [
+    (64, 4, 16, "bf16", 9216), (64, 4, 16, "f32", 17408),
+    (40, 2, 32, "bf16", 5440)])
+def test_kept_bytes_gauge(interp, seq, heads, dh, precision, want):
+    """The gauge is what jax's own split of an application granted the
+    policy (at Ouro's widths 16 × 4096 × (128 × 2 + 4) = 17,039,360); a
+    configuration that recomputes nothing leaves it where it was."""
+    registry.set_gauge(KEPT_GAUGE, -1)
+    _grad_jaxpr(precision, sequence_length=seq, num_attention_heads=heads,
+                num_key_value_heads=heads, head_dim=dh)
+    assert registry.default_registry().snapshot()[KEPT_GAUGE] == want
+    registry.set_gauge(KEPT_GAUGE, -1)
+    _grad_jaxpr(precision, remat="none")
+    assert registry.default_registry().snapshot()[KEPT_GAUGE] == -1
 
 
 # the head runs once over the T·S rows of all four passes, ``block`` rows
