@@ -409,7 +409,6 @@ def bench_serve(repeats: int = 2) -> dict:
     from hyperspace_tpu.serve.engine import QueryEngine
     from hyperspace_tpu.telemetry import registry as telem
 
-    telem.install_jax_monitoring_hook()
     rng = np.random.default_rng(0)
     n, dim, k = 50_000, 16, 10
     table = np.asarray(PoincareBall(1.0).expmap0(
@@ -820,7 +819,6 @@ def bench_serve_http(repeats: int = 2, *, qps: float = 120.0,
     from hyperspace_tpu.serve.server import HttpFrontDoor
     from hyperspace_tpu.telemetry import registry as telem
 
-    telem.install_jax_monitoring_hook()
     rng = np.random.default_rng(0)
     n, dim, k = table_rows, 16, 10
     table = np.asarray(PoincareBall(1.0).expmap0(
@@ -1084,7 +1082,6 @@ def bench_live_index(repeats: int = 1, *, qps: float = 80.0,
     from hyperspace_tpu.serve.server import HttpFrontDoor
     from hyperspace_tpu.telemetry import registry as telem
 
-    telem.install_jax_monitoring_hook()
     rng = np.random.default_rng(7)
     n, dim, k, cap = table_rows, 16, 10, 512
     spec = ("poincare", 1.0)
@@ -1930,7 +1927,6 @@ def bench_multitenant(repeats: int = 1, *, qps: float = 100.0,
     from hyperspace_tpu.serve.server import HttpFrontDoor
     from hyperspace_tpu.telemetry import registry as telem
 
-    telem.install_jax_monitoring_hook()
     rng = np.random.default_rng(0)
     n, dim, k = table_rows, 16, 10
     names = ("hot", "mid", "cold")

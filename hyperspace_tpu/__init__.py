@@ -20,10 +20,21 @@ Layer map (SURVEY.md §1b):
 
 __version__ = "0.1.0"
 
-from hyperspace_tpu.manifolds import (  # noqa: F401
-    Euclidean,
-    Lorentz,
-    PoincareBall,
-    Product,
-    Sphere,
-)
+# first: importing the tracer opens the process's start-up timeline
+# (telemetry/trace.py)
+from hyperspace_tpu.telemetry import trace as _trace
+
+with _trace.importing("hyperspace_tpu.manifolds"):
+    from hyperspace_tpu.manifolds import (  # noqa: F401
+        Euclidean,
+        Lorentz,
+        PoincareBall,
+        Product,
+        Sphere,
+    )
+
+# jax is in by now: its trace / lower / compile events are counted, and
+# are spans of the start-up timeline, from here on
+from hyperspace_tpu.telemetry import registry as _registry  # noqa: E402
+
+_registry.install_jax_monitoring_hook()

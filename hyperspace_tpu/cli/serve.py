@@ -68,6 +68,7 @@ import sys
 import numpy as np
 
 from hyperspace_tpu.cli.train import _json_safe, apply_overrides
+from hyperspace_tpu.telemetry import trace as _trace
 
 
 @dataclasses.dataclass
@@ -493,6 +494,7 @@ def run_query(cfg: ServeConfig) -> dict:
     from hyperspace_tpu.serve.errors import ServeError
 
     _eng, batcher = _build(cfg)
+    _trace.close_startup()  # built: the one query is no part of start-up
     # request-shaped ValueErrors (k out of range, IVF probe capacity /
     # under-fill) and the typed serve errors (deadline/overload) are
     # usage errors in one-shot mode: clean exit, no traceback — the
@@ -574,11 +576,15 @@ def _serve_session(cfg: ServeConfig, batcher):
     ServeConfig as executed + device/backend identity) and a closing
     ``telemetry_summary`` scoped to this session by a registry mark —
     so ``read_jsonl`` tooling reads serve sessions exactly like train
-    runs; always closes the access log on the way out.  Yields the
-    session mark (the latency one-liners' baseline)."""
+    runs; always closes the access log on the way out.  The process's
+    first session also writes the ``startup`` event before its summary
+    (process start to server ready by span, docs/observability.md
+    "Start-up timeline").  Yields the session mark (the latency
+    one-liners' baseline)."""
     from hyperspace_tpu.telemetry import registry as telem
 
     mark = telem.default_registry().mark()
+    ends_startup = _trace.startup_open()
     logger = None
     try:
         if cfg.log:
@@ -598,6 +604,9 @@ def _serve_session(cfg: ServeConfig, batcher):
         if logger is not None:
             # summary must land even when the loop died — the session's
             # counters matter most in a post-mortem (train-loop rule)
+            startup = _trace.startup_fields() if ends_startup else {}
+            if startup:  # {}: it died before it was ready
+                logger.event("startup", **startup)
             logger.event("telemetry_summary",
                          **telem.default_registry().snapshot(
                              "ctr/", baseline=mark))
@@ -783,6 +792,10 @@ def run_serve(cfg: ServeConfig, *, stdin=None, stdout=None) -> dict:
     # distribution of THIS serve loop, not the whole process)
     session = _serve_session(cfg, batcher)
     session_mark = session.__enter__()
+    # built and prewarmed, no line read yet: the server is ready and the
+    # process's start-up timeline ends, so every request runs the closed
+    # span path (allocation-free with tracing off)
+    _trace.close_startup()
     try:
         for line in _line_source(stdin, draining):
             if draining.is_set():
@@ -881,6 +894,9 @@ def run_serve_http(cfg: ServeConfig, *, ready=None) -> dict:
     prewarm_ks = _prewarm_ks(cfg)  # parse errors before the build pays
 
     def announce(host, port):
+        # prewarmed and bound, no connection taken yet: the server is
+        # ready and the process's start-up timeline ends (run_serve)
+        _trace.close_startup()
         try:
             print(f"[serve-http] listening on {host}:{port}",
                   file=sys.stderr, flush=True)
@@ -1015,14 +1031,9 @@ def main(argv: list[str] | None = None) -> int:
         compile_cache.activate(cfg.compile_cache_dir)
     except ValueError as e:  # unusable cache dir is a usage error
         raise SystemExit(str(e)) from None
-    # the hook is unconditional here (idempotent, ~zero cost): the
-    # serve stats' `recompiles` field is a CONTRACT number (flat once
-    # warm) and must read honestly even with telemetry=0 and the
-    # cache disabled — a counter that silently reads 0 would make
-    # every cold start look warm
-    from hyperspace_tpu.telemetry import registry as _telem_registry
-
-    _telem_registry.install_jax_monitoring_hook()
+    # (the serve stats' `recompiles` field is a CONTRACT number, flat
+    # once warm, honest with telemetry=0 and the cache disabled: the
+    # package's import armed the jax.monitoring hook that counts it)
     try:
         chaos_armed = _faults.install_chaos(cfg.chaos, cfg.chaos_seed)
     except ValueError as e:  # malformed chaos= grammar is a usage error

@@ -518,7 +518,7 @@ def run_hgcn(run: RunConfig, overrides: dict):
             # host→device supervision traffic scales 1/n_processes
             # (single-process this is a plain sharded device_put)
             train_pos = mh.distribute_batch(
-                jnp.asarray(hgcn.round_up_pairs(split.train_pos, mesh)), mesh)
+                _placed(hgcn.round_up_pairs(split.train_pos, mesh)), mesh)
             # default multi-chip path: node-sharded encoder — each device
             # owns N/ndev nodes and their incoming edges (mean AND
             # attention aggregation; the receiver partition keeps the
@@ -530,7 +530,7 @@ def run_hgcn(run: RunConfig, overrides: dict):
             stepper, spc = _chunked(run, lambda st: step(st, ga, train_pos))
         else:
             ga = hgcn._device_graph(split.graph)
-            train_pos = jnp.asarray(split.train_pos)
+            train_pos = _placed(split.train_pos)
             stepper, spc = _chunked(
                 run, lambda st: hgcn.train_step_lp(model, opt, num_nodes,
                                                    st, ga, train_pos))
@@ -839,7 +839,7 @@ def run_looplm(run: RunConfig, overrides: dict):
             "num_tokens": int(tokens.size),
             "tokens_per_step": need - 1}
     opt, state = looplm.init_state(cfg, seed=run.seed)
-    stream = jnp.asarray(tokens, jnp.int32)
+    stream = _placed(tokens, jnp.int32)
     if run.scan_chunk > 1:
         run = _chunk_run(run)
     stepper, spc = _chunked(
@@ -898,6 +898,18 @@ def _train_loop(run: RunConfig, state, stepper, project=None,
                     steps_per_call=steps_per_call, health_fn=health_fn,
                     on_rollback=getattr(stepper, "on_rollback", None),
                     data=data)
+
+
+def _placed(a: np.ndarray, dtype=None):
+    """``jnp.asarray`` under a ``place`` span: a runner's own copy of
+    its supervision data (pairs, the token stream) to the device."""
+    from hyperspace_tpu.telemetry.trace import span
+
+    info = {}
+    with span("place", info):
+        out = jnp.asarray(a, dtype)
+        info["bytes"] = int(out.nbytes)
+    return out
 
 
 def _maybe_health(run: RunConfig, build):

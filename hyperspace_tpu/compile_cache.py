@@ -32,11 +32,11 @@ secs`` is set to 0 and the min-entry-size check is disabled, so even
 the sub-second executables (the serve bucket ladder is made of them)
 persist — disk is cheap next to a p99 cliff.
 
-**Telemetry**: activation installs the shared ``jax.monitoring`` hook
-(:func:`hyperspace_tpu.telemetry.registry.install_jax_monitoring_hook`),
-which counts ``jax/compile_cache_hit`` (executables deserialized from
-the cache — the backend compile never ran) and
-``jax/compile_cache_miss`` (backend compiles while the cache was
+**Telemetry**: the shared ``jax.monitoring`` hook
+(:func:`hyperspace_tpu.telemetry.registry.install_jax_monitoring_hook`,
+armed by the package's import) counts ``jax/compile_cache_hit``
+(executables deserialized from the cache — the backend compile never
+ran) and ``jax/compile_cache_miss`` (backend compiles while the cache was
 enabled — each writes a new entry).  Both ride into every JSONL record,
 ``telemetry_summary``, and bench artifact through the existing
 registry, so cache hit rates are visible for free
@@ -144,10 +144,6 @@ def activate(flag: Optional[str] = None) -> Optional[str]:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _state["dir"] = d
-    # hit/miss counters ride the shared monitoring hook (idempotent)
-    from hyperspace_tpu.telemetry import registry as telem
-
-    telem.install_jax_monitoring_hook()
     return d
 
 

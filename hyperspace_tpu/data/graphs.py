@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from hyperspace_tpu.telemetry.trace import importing, span
+
 
 @dataclasses.dataclass
 class Graph:
@@ -94,23 +96,28 @@ jax.tree_util.register_pytree_node(DeviceGraph, _dg_flatten, _dg_unflatten)
 
 def to_device(g: Graph) -> DeviceGraph:
     """Put a host :class:`Graph` on device as a :class:`DeviceGraph`."""
-    cluster = None
-    if g.cluster_split is not None:
-        from hyperspace_tpu.nn.scatter import ClusterAgg
+    info = {}
+    with span("place", info):
+        cluster = None
+        if g.cluster_split is not None:
+            from hyperspace_tpu.nn.scatter import ClusterAgg
 
-        cluster = ClusterAgg.from_host(g.cluster_split)
-    return DeviceGraph(
-        x=jnp.asarray(g.x),
-        senders=jnp.asarray(g.senders),
-        receivers=jnp.asarray(g.receivers),
-        edge_mask=jnp.asarray(g.edge_mask),
-        num_nodes=g.num_nodes,
-        rev_perm=None if g.rev_perm is None else jnp.asarray(g.rev_perm),
-        deg=None if g.deg is None else jnp.asarray(g.deg),
-        plan=None if g.csr_plan is None
-        else tuple(jnp.asarray(a) for a in g.csr_plan),
-        cluster=cluster,
-    )
+            cluster = ClusterAgg.from_host(g.cluster_split)
+        dg = DeviceGraph(
+            x=jnp.asarray(g.x),
+            senders=jnp.asarray(g.senders),
+            receivers=jnp.asarray(g.receivers),
+            edge_mask=jnp.asarray(g.edge_mask),
+            num_nodes=g.num_nodes,
+            rev_perm=None if g.rev_perm is None else jnp.asarray(g.rev_perm),
+            deg=None if g.deg is None else jnp.asarray(g.deg),
+            plan=None if g.csr_plan is None
+            else tuple(jnp.asarray(a) for a in g.csr_plan),
+            cluster=cluster,
+        )
+        info["bytes"] = sum(
+            int(a.nbytes) for a in jax.tree_util.tree_leaves(dg))
+    return dg
 
 
 @dataclasses.dataclass
@@ -444,12 +451,17 @@ def _read_csv(path: str, dtype):
     """Fast csv matrix read: pandas C engine when available (an order of
     magnitude faster at arxiv scale — node-feat.csv is ~21.7 M floats),
     np.loadtxt as the no-pandas fallback."""
-    try:
-        import pandas as pd
+    info = {"file": os.path.basename(path)}
+    with span("read_csv", info):
+        try:
+            with importing("pandas"):
+                import pandas as pd
 
-        return pd.read_csv(path, header=None, dtype=dtype).to_numpy()
-    except ImportError:
-        return np.loadtxt(path, delimiter=",", dtype=dtype)
+            a = pd.read_csv(path, header=None, dtype=dtype).to_numpy()
+        except ImportError:
+            a = np.loadtxt(path, delimiter=",", dtype=dtype)
+        info.update(bytes=os.path.getsize(path), rows=int(a.shape[0]))
+    return a
 
 
 # the OGB node-property datasets :func:`load_graph` reads from disk, each
@@ -750,14 +762,15 @@ def ensure_ogb_scale_dataset(root: str, name: str, seed: int = 0,
         # write into a temp sibling and rename whole: an interrupted
         # generation must not leave a half-written tree that the
         # edge.csv existence sentinel would treat as complete
-        tmp = root + ".tmp"
-        shutil.rmtree(tmp, ignore_errors=True)
-        edges, x, labels, _ = community_power_law_graph(
-            seed=seed, **{**OGB_SHAPES[name], **graph_kw})
-        write_ogb_csv_layout(tmp, edges, x, labels)
-        os.makedirs(os.path.dirname(root), exist_ok=True)
-        shutil.rmtree(root, ignore_errors=True)
-        os.replace(tmp, root)
+        with span("make_dataset", {"dataset": name}):
+            tmp = root + ".tmp"
+            shutil.rmtree(tmp, ignore_errors=True)
+            edges, x, labels, _ = community_power_law_graph(
+                seed=seed, **{**OGB_SHAPES[name], **graph_kw})
+            write_ogb_csv_layout(tmp, edges, x, labels)
+            os.makedirs(os.path.dirname(root), exist_ok=True)
+            shutil.rmtree(root, ignore_errors=True)
+            os.replace(tmp, root)
     return root
 
 
@@ -807,6 +820,14 @@ def load_graph(name: str, root: str | None = None, **synth_kw):
     "disk" or "synthetic"; callers record it, with the node and edge
     counts, in the run manifest and result (cli/train.py).
     """
+    info = {"dataset": name}
+    with span("load_graph", info):
+        out = _load_graph(name, root, synth_kw)
+        info["source"] = out[-1]
+    return out
+
+
+def _load_graph(name: str, root, synth_kw: dict):
     if root is not None:
         if name == "cora" and os.path.exists(os.path.join(root, "cora.content")):
             return (*load_cora(root), "disk")

@@ -151,7 +151,8 @@ class PrepCache:
         # ONE span over the whole lookup/build/store: a slow cache (a
         # multi-hundred-MB pickle.load off slow disk) must be visible
         # in the host timeline just like the build it replaces
-        with span("prep"):
+        info = {"kind": kind, "hit": False}
+        with span("prep", info):
             digest = key_hash(kind, key_parts)
             path = self._path(kind, digest)
             if os.path.exists(path):
@@ -160,6 +161,7 @@ class PrepCache:
                         payload = pickle.load(f)
                     self.hits += 1
                     telem.inc("prep_cache/hit")
+                    info["hit"] = True
                     return payload
                 except Exception:  # noqa: BLE001 — corrupt entry = miss
                     try:

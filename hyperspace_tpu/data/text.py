@@ -17,6 +17,8 @@ import os
 
 import numpy as np
 
+from hyperspace_tpu.telemetry.trace import span
+
 PAD_ID = 0
 
 
@@ -150,18 +152,23 @@ def ensure_token_stream(root: str, seed: int = 0, **stream_kw) -> str:
     sees half a file); returns its path."""
     path = os.path.join(root, "tokens.npy")
     if not os.path.exists(path):
-        os.makedirs(root, exist_ok=True)
-        tmp = f"{path}.{os.getpid()}.tmp.npy"
-        np.save(tmp, synthetic_token_stream(seed=seed, **stream_kw))
-        os.replace(tmp, path)
+        with span("make_dataset", {"dataset": "token_stream"}):
+            os.makedirs(root, exist_ok=True)
+            tmp = f"{path}.{os.getpid()}.tmp.npy"
+            np.save(tmp, synthetic_token_stream(seed=seed, **stream_kw))
+            os.replace(tmp, path)
     return path
 
 
 def load_token_stream(root: str | None = None, **synth_kw) -> tuple[np.ndarray, str]:
     """(tokens, source): ``<root>/tokens.npy`` when it is there ("disk"),
     else a stream synthesized from ``synth_kw`` ("synthetic")."""
-    if root is not None:
-        path = os.path.join(root, "tokens.npy")
-        if os.path.exists(path):
-            return np.load(path), "disk"
-    return synthetic_token_stream(**synth_kw), "synthetic"
+    info = {}
+    with span("load_stream", info):
+        path = None if root is None else os.path.join(root, "tokens.npy")
+        if path is not None and os.path.exists(path):
+            tokens, source = np.load(path), "disk"
+        else:
+            tokens, source = synthetic_token_stream(**synth_kw), "synthetic"
+        info.update(tokens=int(tokens.size), source=source)
+    return tokens, source

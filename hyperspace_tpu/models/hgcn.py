@@ -23,11 +23,16 @@ import dataclasses
 from functools import partial
 from typing import Any, NamedTuple, Sequence
 
-import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
+
+from hyperspace_tpu.telemetry.trace import importing, span
+
+with importing("flax"):
+    import flax.linen as nn
+with importing("optax"):
+    import optax
 
 from hyperspace_tpu import precision as precision_lib
 from hyperspace_tpu.data import graphs as graph_data
@@ -237,22 +242,30 @@ def _device_graph(g: graph_data.Graph) -> graph_data.DeviceGraph:
 # ---- link prediction ----
 
 
+def _param_count(params) -> int:
+    return sum(int(a.size) for a in jax.tree_util.tree_leaves(params))
+
+
 def init_lp(cfg: HGCNConfig, g: graph_data.Graph, seed: int = 0):
-    model = HGCNLinkPred(cfg)
-    key = jax.random.PRNGKey(seed)
-    k_init, key = jax.random.split(key)
-    # flax draws a parameter from its path and its shape, and the only
-    # shape the graph gives is the feature width: initialise over a
-    # two-node graph of that width.  The eager forward of ``model.init``
-    # over ``g`` itself would put the whole graph on one device, which a
-    # graph made for a mesh does not fit
-    dg = _device_graph(graph_data.prepare(
-        np.array([[0, 1]]), 2, np.zeros((2, g.x.shape[1]), np.float32),
-        cache=False))
-    dummy_pairs = jnp.zeros((2, 2), jnp.int32)
-    params = model.init({"params": k_init}, dg, dummy_pairs)["params"]
-    opt = make_optimizer(cfg)
-    state = TrainState(params, opt.init(params), key, jnp.zeros((), jnp.int32))
+    info = {"model": "hgcn_lp"}
+    with span("init", info):
+        model = HGCNLinkPred(cfg)
+        key = jax.random.PRNGKey(seed)
+        k_init, key = jax.random.split(key)
+        # flax draws a parameter from its path and its shape, and the only
+        # shape the graph gives is the feature width: initialise over a
+        # two-node graph of that width.  The eager forward of ``model.init``
+        # over ``g`` itself would put the whole graph on one device, which
+        # a graph made for a mesh does not fit
+        dg = _device_graph(graph_data.prepare(
+            np.array([[0, 1]]), 2, np.zeros((2, g.x.shape[1]), np.float32),
+            cache=False))
+        dummy_pairs = jnp.zeros((2, 2), jnp.int32)
+        params = model.init({"params": k_init}, dg, dummy_pairs)["params"]
+        opt = make_optimizer(cfg)
+        state = TrainState(params, opt.init(params), key,
+                           jnp.zeros((), jnp.int32))
+        info["params"] = _param_count(params)
     return model, opt, state
 
 
@@ -508,13 +521,17 @@ def train_lp(
 
 
 def init_nc(cfg: HGCNConfig, g: graph_data.Graph, seed: int = 0):
-    model = HGCNNodeClf(cfg)
-    key = jax.random.PRNGKey(seed)
-    k_init, key = jax.random.split(key)
-    dg = _device_graph(g)
-    params = model.init({"params": k_init}, dg)["params"]
-    opt = make_optimizer(cfg)
-    state = TrainState(params, opt.init(params), key, jnp.zeros((), jnp.int32))
+    info = {"model": "hgcn_nc"}
+    with span("init", info):
+        model = HGCNNodeClf(cfg)
+        key = jax.random.PRNGKey(seed)
+        k_init, key = jax.random.split(key)
+        dg = _device_graph(g)
+        params = model.init({"params": k_init}, dg)["params"]
+        opt = make_optimizer(cfg)
+        state = TrainState(params, opt.init(params), key,
+                           jnp.zeros((), jnp.int32))
+        info["params"] = _param_count(params)
     return model, opt, state
 
 

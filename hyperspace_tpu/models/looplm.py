@@ -68,7 +68,11 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-import optax
+
+from hyperspace_tpu.telemetry.trace import importing, span
+
+with importing("optax"):
+    import optax
 
 from hyperspace_tpu import precision as precision_mod
 from hyperspace_tpu.kernels.attention import (FLASH_DOT_LSE, FLASH_DOT_OUT,
@@ -186,11 +190,17 @@ def make_optimizer(cfg: LoopLMConfig):
 def init_state(cfg: LoopLMConfig, seed: int = 0, params=None):
     """(optimizer, state); ``params`` puts a given tree (the benchmark's
     weights) in place of the seed's own, so that no second copy is made."""
-    params = init_params(cfg, seed) if params is None else params
-    opt = make_optimizer(cfg)
-    stats = jnp.zeros((STATS_HEAD + 2 * cfg.total_ut_steps,), jnp.float32)
-    return opt, TrainState(params, opt.init(params),
+    info = {"model": "looplm"}
+    with span("init", info):
+        params = init_params(cfg, seed) if params is None else params
+        opt = make_optimizer(cfg)
+        stats = jnp.zeros((STATS_HEAD + 2 * cfg.total_ut_steps,),
+                          jnp.float32)
+        state = TrainState(params, opt.init(params),
                            jnp.zeros((), jnp.int32), stats)
+        info["params"] = sum(
+            int(a.size) for a in jax.tree_util.tree_leaves(params))
+    return opt, state
 
 
 # --- the forward pass ---------------------------------------------------------

@@ -26,6 +26,18 @@ Catalog + reading guide: docs/observability.md.
 
 import contextlib
 
+# the tracer first: its import opens the process's start-up timeline,
+# so that jax's import, if this package is what brings it in, has a span
+from hyperspace_tpu.telemetry.trace import (  # noqa: F401
+    Tracer,
+    default_tracer,
+    importing,
+    span,
+)
+
+with importing("jax"):
+    import jax  # noqa: F401
+
 from hyperspace_tpu.telemetry.health import (  # noqa: F401
     HealthMonitor,
     health_stats,
@@ -37,25 +49,25 @@ from hyperspace_tpu.telemetry.health import (  # noqa: F401
 def cli_session(telemetry: bool, trace_out, *, stream=None):
     """The CLI entry points' shared telemetry bracket (train and serve).
 
-    Enables span recording + the jax recompile hook up front (BEFORE the
-    workload, so host prep lands in the trace), and in a ``finally``
-    dumps the Chrome trace — a crashed run must still produce its trace,
-    and an OSError from the dump must never mask the exception this
-    block may be unwinding — then disables recording.  ``stream`` is
-    where the dump notices print (train: stdout, serve: stderr — serve's
-    stdout is a strict response stream)."""
-    if telemetry or trace_out:
-        from hyperspace_tpu.telemetry import registry as _registry
-        from hyperspace_tpu.telemetry import trace as _trace
+    Enables span recording up front (BEFORE the workload, so host prep
+    lands in the trace; jax's compile events are counted from the
+    package's import on), and in a ``finally`` ends the process's
+    start-up timeline if the workload has not (a run that got to no
+    loop, or crashed on its way there: its set-up spans still lead the
+    dump) and dumps the Chrome trace — a crashed run must still produce
+    its trace, and an OSError from the dump must never mask the
+    exception this block may be unwinding — then disables recording.
+    ``stream`` is where the dump notices print (train: stdout, serve:
+    stderr — serve's stdout is a strict response stream)."""
+    from hyperspace_tpu.telemetry import trace as _trace
 
+    if telemetry or trace_out:
         _trace.enable(keep_events=bool(trace_out))
-        _registry.install_jax_monitoring_hook()
     try:
         yield
     finally:
+        _trace.close_startup()
         if trace_out:
-            from hyperspace_tpu.telemetry.trace import default_tracer
-
             try:
                 n = default_tracer().dump_chrome_trace(trace_out)
                 print(f"[telemetry] {n} trace events -> {trace_out}",
@@ -64,8 +76,6 @@ def cli_session(telemetry: bool, trace_out, *, stream=None):
                 print(f"[telemetry] trace dump failed: {e!r}",
                       file=stream, flush=True)
         if telemetry or trace_out:
-            from hyperspace_tpu.telemetry import trace as _trace
-
             _trace.disable()
 from hyperspace_tpu.telemetry.exposition import (  # noqa: F401
     MetricsFileWriter,
@@ -82,9 +92,4 @@ from hyperspace_tpu.telemetry.registry import (  # noqa: F401
     default_registry,
     install_jax_monitoring_hook,
     observe,
-)
-from hyperspace_tpu.telemetry.trace import (  # noqa: F401
-    Tracer,
-    default_tracer,
-    span,
 )

@@ -32,15 +32,26 @@ hook also counts ``jax/compile_cache_hit`` (executables deserialized
 from disk — the backend compile never ran) and
 ``jax/compile_cache_miss`` (backend compiles while the cache was
 enabled; each writes a new entry), so cache hit rates ride into every
-JSONL record and bench artifact for free.
+JSONL record and bench artifact for free.  jax's trace and lowering
+times are summed beside them (``jax/trace_s``, ``jax/lower_s``), and
+each of the three events is also a span (``jit_trace``, ``jit_lower``,
+``compile``) by function name: in the start-up timeline while that is
+open, in the tracer while that is on (telemetry/trace.py).
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from typing import Optional
 
 _BACKEND_COMPILE_SUBSTR = "backend_compile"
+# jax's two stages before the backend compile: event -> (span, counter)
+_JIT_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": ("jit_trace", "jax/trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("jit_lower", "jax/lower_s")}
+_CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
@@ -233,21 +244,52 @@ def install_jax_monitoring_hook() -> None:
     on this image) and in the hit counter, not in a lower recompile
     count.  In-process warm executables fire nothing, so the flat-once-
     warm contracts are unchanged.
+
+    The same events are spans where spans are being recorded
+    (``trace.tracing()``): ``jit_trace``, ``jit_lower`` and ``compile``,
+    each from its duration back from now, with jax's ``fun_name``; a
+    ``compile`` also says what the persistent cache did for it
+    (``cache``: ``hit``, ``miss``: asked and compiled, ``off``: not
+    asked).
     """
     global _hook_installed
     if _hook_installed:
         return
     import jax.monitoring as _mon
 
-    def _on_duration(event: str, duration: float, **_kw) -> None:
+    from hyperspace_tpu.telemetry import trace
+
+    # what the persistent cache said of the compile this thread is in:
+    # jax fires the cache's events inside the compile they belong to, on
+    # its thread, before the compile's own duration event
+    pending = threading.local()
+
+    def _span(name: str, duration: float, kw: dict, **more) -> None:
+        if trace.tracing():
+            now = time.perf_counter()
+            trace.record_span(name, now - duration, now,
+                              {"fun_name": kw.get("fun_name"), **more})
+
+    def _on_duration(event: str, duration: float, **kw) -> None:
         if _BACKEND_COMPILE_SUBSTR in event:
             reg = default_registry()
             reg.inc("jax/recompiles")
             reg.inc("jax/compile_s", float(duration))
+            cache, pending.cache = getattr(pending, "cache", "off"), "off"
+            _span("compile", duration, kw, cache=cache)
+        elif event in _JIT_STAGES:
+            name, counter = _JIT_STAGES[event]
+            # telemetry-catalog: jax/trace_s
+            # telemetry-catalog: jax/lower_s
+            default_registry().inc(counter, float(duration))
+            _span(name, duration, kw)
 
     def _on_event(event: str, **_kw) -> None:
-        if event == _CACHE_HIT_EVENT:
+        if event == _CACHE_REQUEST_EVENT:
+            pending.cache = "miss"  # asked; a hit says so next
+        elif event == _CACHE_HIT_EVENT:
             default_registry().inc("jax/compile_cache_hit")
+            pending.cache = "hit"
         elif event == _CACHE_MISS_EVENT:
             default_registry().inc("jax/compile_cache_miss")
 

@@ -22,7 +22,11 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-import orbax.checkpoint as ocp
+
+from hyperspace_tpu.telemetry.trace import importing
+
+with importing("orbax.checkpoint"):
+    import orbax.checkpoint as ocp
 
 
 class CheckpointManager:

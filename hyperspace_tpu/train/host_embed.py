@@ -53,6 +53,7 @@ from hyperspace_tpu.data.prefetch import HostPrefetcher
 from hyperspace_tpu.models import poincare_embed as pe
 from hyperspace_tpu.parallel.host_table import DeviceHotCache, HostEmbedTable
 from hyperspace_tpu.telemetry import registry as _telem
+from hyperspace_tpu.telemetry import trace as _trace
 from hyperspace_tpu.telemetry.trace import span as _span
 from hyperspace_tpu.train.telemetry import StepPhases
 
@@ -208,6 +209,7 @@ class HostPlannedTrainer:
         if not sizes:
             return np.zeros((0,), np.float32)
         losses = []
+        startup_open = _trace.startup_open()
         with HostPrefetcher(
                 lambda i: self._make_chunk(i, sizes[i]),
                 depth=self.prefetch_depth) as pf:
@@ -217,6 +219,11 @@ class HostPlannedTrainer:
                 with self.phases.phase("data_wait"):
                     item = pf.next()
                 losses.append(self._run_chunk(item))
+                if startup_open:
+                    # this trainer has no run_loop: the process's
+                    # start-up timeline ends at its first chunk's return
+                    _trace.close_startup()
+                    startup_open = False
         return np.concatenate(losses)
 
     def to_state(self) -> pe.TrainState:

@@ -203,7 +203,6 @@ def _telemetry_setup(run):
             # library use: the tracer was off, so anything it holds is a
             # PRIOR run's aggregates/events — this run starts clean
             tracer.reset()
-        registry.install_jax_monitoring_hook()
         reg = registry.default_registry() if telemetry_on else None
     return tracer, reg, fresh
 
@@ -315,21 +314,23 @@ def run_loop(run, state, stepper, project=None, steps_per_call=1,
     """
     from hyperspace_tpu.resilience import faults
     from hyperspace_tpu.telemetry import registry as telem
+    from hyperspace_tpu.telemetry import trace
     from hyperspace_tpu.telemetry.trace import span, tracing
 
     tracer, reg, fresh_tracer = _telemetry_setup(run)
+    # the process's start-up timeline ends where its first loop's first
+    # dispatch returns (telemetry/trace.py): read once a call; a step
+    # checks the local `startup_open`, which that dispatch clears
+    ends_startup = startup_open = trace.startup_open()
     # profile_steps=N: for the first N steps, block on each chunk's
     # result inside the dispatch window (the phase reads execution, not
     # async enqueue) and observe it as the device_step phase — the
-    # train-plane mirror of the serve stage histograms; compile events
-    # are armed too, so the profiled window attributes compile time.
-    # N steps only: a permanent block would re-serialize host and
-    # device, the exact overlap the chunked loop exists to buy.
+    # train-plane mirror of the serve stage histograms (compile events
+    # are counted from the package's import on, so the profiled window
+    # attributes compile time).  N steps only: a permanent block would
+    # re-serialize host and device, the exact overlap the chunked loop
+    # exists to buy.
     profile_steps = int(getattr(run, "profile_steps", 0) or 0)
-    if profile_steps > 0:
-        from hyperspace_tpu.train.telemetry import install_hooks
-
-        install_hooks()
     monitor, health_every = _health_monitor(run, health_fn)
     mwriter = None
     metrics_out = (getattr(run, "metrics_out", None)
@@ -437,6 +438,9 @@ def run_loop(run, state, stepper, project=None, steps_per_call=1,
                             # not a host fetch — no value crosses to the host)
                             jax.block_until_ready(loss)
                     disp_ms = (time.perf_counter() - t_disp) * 1e3
+                    if startup_open:
+                        trace.close_startup()
+                        startup_open = False
                     telem.observe("train/dispatch_ms", disp_ms)
                     if prof:
                         telem.observe("train/phase/device_step_ms", disp_ms)
@@ -558,12 +562,19 @@ def run_loop(run, state, stepper, project=None, steps_per_call=1,
             # the final state must land even when it misses the save
             # cadence — otherwise resume silently replays a partial chunk
             ck.save(done, state, force=True)
+        if ends_startup:
+            trace.close_startup()  # a run that dispatched nothing
         if reg is not None:
             if ck is not None:
                 ck.wait()  # async saves landed → ckpt/bytes gauge is real
             summary = reg.snapshot("ctr/", baseline=counter_base)
             if tracer is not None:
                 summary.update(tracer.total_fields())
+            if ends_startup:
+                # process start to this loop's first dispatch, by span
+                startup = trace.startup_fields()
+                log.event("startup", **startup)
+                summary.update(startup)
             # each local device's allocator reading while the training
             # state and its data are still alive (live arrays; None
             # where the backend keeps no statistics, as the CPU's) —

@@ -35,7 +35,7 @@ Host-table cache effectiveness (hit/miss/evict counters and the
 ``host_table/cache_hit_rate`` gauge) ticks inside
 ``parallel/host_table.py`` itself; compile events come from
 ``telemetry.registry.install_jax_monitoring_hook`` (``jax/recompiles``,
-``jax/compile_s``) — :func:`install_hooks` arms it idempotently.
+``jax/compile_s``), which the package's import arms.
 """
 
 from __future__ import annotations
@@ -50,12 +50,6 @@ from hyperspace_tpu.telemetry.trace import span
 # chunk-phase order: consecutive phases of one chunk never overlap, so
 # their bounds are monotone in this order (tested)
 PHASES = ("data_wait", "host_gather", "device_step", "write_back")
-
-
-def install_hooks() -> None:
-    """Arm the compile-event counters (idempotent): ``jax/recompiles``
-    and ``jax/compile_s`` tick for every fresh XLA compile."""
-    telem.install_jax_monitoring_hook()
 
 
 class StepPhases:

@@ -38,6 +38,16 @@ os.environ.setdefault("HYPERSPACE_AUTOTUNE_TABLE", "0")
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+# The process's start-up timeline (telemetry/trace.py) is open from the
+# package's import until the process's first run_loop dispatch: left
+# open, whichever test first ran a loop would write the one `startup`
+# event and every span() before it would record.  Closed here, every
+# test sees the closed path; tests/telemetry/test_startup.py opens
+# timelines of its own.
+from hyperspace_tpu.telemetry import trace as _trace  # noqa: E402
+
+_trace.close_startup()
+
 
 @pytest.fixture
 def rng():

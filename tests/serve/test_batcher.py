@@ -181,7 +181,6 @@ def test_within_bucket_sizes_share_one_compile(rng):
     """THE serving contract: after one warmup per (bucket, k), requests
     of any size inside that bucket trigger zero XLA recompiles (asserted
     via the PR-2 ``jax/recompiles`` monitoring counter)."""
-    telem.install_jax_monitoring_hook()
     eng = _engine(rng, n=80)
     b = RequestBatcher(eng, min_bucket=8, max_bucket=32, cache_size=0)
     reg = telem.default_registry()

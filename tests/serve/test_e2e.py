@@ -71,7 +71,6 @@ def test_no_recompile_within_bucket_after_export(tmp_path):
     art_dir = str(tmp_path / "artifact")
     export_from_checkpoint(ckpt, art_dir, workload="poincare",
                            model_config={"c": cfg.c})
-    telem.install_jax_monitoring_hook()
     eng = QueryEngine.from_artifact(load_artifact(art_dir))
     batcher = RequestBatcher(eng, min_bucket=8, max_bucket=64, cache_size=0)
     reg = telem.default_registry()

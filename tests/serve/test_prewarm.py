@@ -26,11 +26,6 @@ def _table(n=300, dim=6, seed=0):
         jnp.asarray(rng.standard_normal((n, dim)) * 0.3, jnp.float32)))
 
 
-@pytest.fixture(autouse=True)
-def _hook():
-    telem.install_jax_monitoring_hook()
-
-
 def _recompiles():
     return telem.default_registry().get("jax/recompiles")
 

@@ -241,7 +241,6 @@ def test_keep_alive_connection_serves_sequentially(engine):
     """HTTP/1.1 keep-alive: several requests down one connection each
     get one response; recompiles stay FLAT across same-bucket requests
     (the compile-once-per-bucket contract through the socket path)."""
-    telem.install_jax_monitoring_hook()
     reg = telem.default_registry()
 
     async def go(door, bat):
