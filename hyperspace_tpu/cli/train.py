@@ -46,7 +46,9 @@ class RunConfig:
     resume: bool = False
     data_root: str | None = None  # on-disk dataset directory
     multihost: bool = False  # jax.distributed.initialize + host mesh axis
-    tp: int = 2  # tensor-parallel degree for HGCN's auto mesh (1 = pure dp)
+    # `model` extent of HGCN's auto mesh (1 = pure dp); the node-sharded
+    # step cuts a node shard for every device whatever the split
+    tp: int = 2
     # >1: run this many steps per dispatch as one lax.scan program
     # (train/loop.make_chunked_stepper; ALL workloads) — removes the
     # per-step launch latency that pins small-step workloads at the
@@ -609,8 +611,8 @@ def _eval_params(params, mesh):
     """Parameters for the full-graph evaluation.  That program runs on
     ONE device — its Pallas kernels sit outside any ``shard_map``, and
     the partitioner refuses to place a Mosaic kernel by itself — so
-    parameters a mesh run left sharded over its devices are gathered
-    onto the device the evaluation graph goes to."""
+    parameters a mesh run left on its devices are gathered onto the
+    device the evaluation graph goes to."""
     if mesh is None:
         return params
     return jax.device_put(params, jax.local_devices()[0])

@@ -369,7 +369,10 @@ def _concat_hazard(mesh) -> bool:
     batch-sharding ("host"/"data") set — the mesh shape under which an
     earlier jax's GSPMD miscompiled a constrained ``concatenate``
     (``_lp_step_impl``'s split_pairs rationale; fixed in the installed
-    jax, the split form stays until measured)."""
+    jax, the split form stays until measured).  The hazard needed a
+    constraint over a proper subset of the axes; the node-sharded step
+    constrains its pairs over all of them, so there it no longer
+    applies."""
     return any(int(mesh.shape[a]) > 1 for a in mesh.axis_names
                if a not in ("host", "data"))
 
@@ -399,17 +402,19 @@ def make_node_sharded_step_lp(
 ):
     """LP train step whose encoder work divides across ``mesh``.
 
-    The graph is node-sharded over the data-like axes
-    (`parallel/node_shard`): the [N, F] activations, every matmul row,
-    and each shard's slice of the edge aggregation live on one device;
-    the only collective in the encoder is an [N, F] exchange per layer
-    per direction riding ICI.  Per-device FLOPs and HBM bytes scale
-    ~1/ndev (asserted by tests/parallel/test_node_sharded.py's
+    The graph is node-sharded over every axis of the mesh, one shard a
+    device (`parallel/node_shard`): the [N, F] activations, every matmul
+    row, and each shard's slice of the edge aggregation live on one
+    device; the only collective in the encoder is an [N, F] exchange per
+    layer per direction riding ICI.  Per-device FLOPs and HBM bytes
+    scale ~1/ndev (asserted by tests/parallel/test_node_sharded.py's
     compiled-cost check).  The supervision batch (positives + sampled
-    negatives) is sharded over the same axes, so XLA inserts the
-    gradient all-reduce; 2-D kernels are column-sharded over the
-    ``model`` axis when present (`parallel/tp.tp_param_shardings`) and
-    optimizer moments are co-located with their parameter shards.
+    negatives) arrives sharded over the batch axes (``host``/``data``)
+    and is resharded inside the step over the same axes as the nodes,
+    so XLA inserts the gradient all-reduce over every device.
+    Parameters and optimizer moments are replicated: HGCN's widest
+    kernel is one MXU tile, and splitting it over a ``model`` axis
+    would only repeat the graph work on that axis's devices.
 
     Mean aggregation uses the involution backward (no cross-shard
     scatter); attention works too — the receiver partition keeps its
@@ -420,12 +425,17 @@ def make_node_sharded_step_lp(
     """
     from hyperspace_tpu.parallel.mesh import batch_sharding, replicated
     from hyperspace_tpu.parallel.node_shard import graph_shardings, shard_graph
-    from hyperspace_tpu.parallel.tp import state_shardings
+    from hyperspace_tpu.parallel.tp import replicated_like
 
     nsg = shard_graph(split.graph, mesh, halo=halo)
-    state_sh = state_shardings(state, state.params, mesh)
+    state_sh = replicated_like(state, mesh)
     bsh = batch_sharding(mesh, ndim=2)
-    constrain = lambda x: jax.lax.with_sharding_constraint(x, bsh)
+    # pairs over every device, as the nodes: a row count that is a
+    # multiple of the batch axes but not of the device count shards
+    # unevenly, which the constraint allows
+    psh = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(nsg.axes, None))
+    constrain = lambda x: jax.lax.with_sharding_constraint(x, psh)
 
     step = jax.jit(
         partial(_lp_step_impl, model, opt, num_nodes, constrain=constrain,
@@ -449,9 +459,10 @@ def make_node_sharded_step_nc(
     g: graph_data.Graph,
     halo="auto",
 ):
-    """NC twin of `make_node_sharded_step_lp`: node-sharded encoder, with
-    labels/train-mask padded to the sharded node count and the per-node
-    cross-entropy terms sharded over the same axes.  Returns
+    """NC twin of `make_node_sharded_step_lp`: node-sharded encoder over
+    every axis of the mesh, with labels/train-mask padded to the sharded
+    node count, the per-node cross-entropy terms sharded over the same
+    axes and the state replicated.  Returns
     ``(step, placed_state, placed_graph, labels, train_mask)``.
     """
     from hyperspace_tpu.parallel.mesh import replicated
@@ -460,13 +471,13 @@ def make_node_sharded_step_nc(
         pad_node_array,
         shard_graph,
     )
-    from hyperspace_tpu.parallel.tp import state_shardings
+    from hyperspace_tpu.parallel.tp import replicated_like
 
     nsg = shard_graph(g, mesh, halo=halo)
     n_pad = nsg.x.shape[0]
     labels = jnp.asarray(pad_node_array(g.labels, n_pad, 0))
     train_mask = jnp.asarray(pad_node_array(g.train_mask, n_pad, False))
-    state_sh = state_shardings(state, state.params, mesh)
+    state_sh = replicated_like(state, mesh)
     nsh = jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec(nsg.axes))
     constrain = lambda x: jax.lax.with_sharding_constraint(x, nsh)

@@ -11,7 +11,8 @@ trainer's graph partitioning (SURVEY.md §2 N8, §7 hard-part #3):
   slice); each shard gets its own receiver-local edge list, per-edge mean
   weights, and block-CSR plan, all padded to common static shapes.
 - **Device-side aggregation** (:func:`node_sharded_aggregate`): a
-  ``shard_map`` over the data-like mesh axes.  Each device all-gathers
+  ``shard_map`` over every axis of the mesh, so each device holds a
+  shard of its own and none repeats another's.  Each device all-gathers
   the [N, F] activations over ICI (the one collective; at bf16 this is
   ~N·F·2 bytes, ≪ the E·F gather it feeds), then runs *its shard's*
   gather + block-CSR segment-sum — E/ndev edges and N/ndev output rows
@@ -92,7 +93,7 @@ class NodeShardedGraph(NamedTuple):
     num_nodes: int  # static: real node count (< N_pad)
     n_shard: int    # static: nodes per shard (N_pad = n_shard · ndev)
     mesh: Any       # static: jax.sharding.Mesh
-    axes: tuple     # static: data-like mesh axis names the nodes shard over
+    axes: tuple     # static: mesh axis names the nodes shard over
     send_idx: Any = None     # [ndev, ndev, H] (a2a) | [ndev, ΣH_d] (ppermute)
     halo: bool = False       # static: exchange halo rows, not all-gather
     halo_kind: str = "a2a"   # static: "a2a" | "ppermute"
@@ -116,11 +117,6 @@ def _nsg_unflatten(aux, leaves):
 
 
 jax.tree_util.register_pytree_node(NodeShardedGraph, _nsg_flatten, _nsg_unflatten)
-
-
-def data_axes(mesh: Mesh) -> tuple:
-    """The data-like axes of ``mesh`` (nodes shard over these)."""
-    return tuple(a for a in ("host", "data") if a in mesh.axis_names)
 
 
 class HostPartition(NamedTuple):
@@ -344,12 +340,12 @@ SCHEDULE_CODES = {"all-gather": 0, "a2a": 1, "ppermute": 2}
 
 def _record_partition(counts, e_s, n_shard, need, use_halo, halo_kind,
                       send_idx, halo_sizes) -> None:
-    """The partition's shape as gauges (docs/observability.md): the rows
-    a shard needs from the others (``need[k][j]``), the rows the chosen
-    schedule moves to it, how evenly the edges fell and how much of the
-    edge arrays is padding.  Rows are counted per layer and pass: the
-    forward exchanges ``h``, the backward the same rows of its
-    cotangent."""
+    """The partition's shape as gauges (docs/observability.md): how many
+    shards it cut, the rows a shard needs from the others
+    (``need[k][j]``), the rows the chosen schedule moves to it, how
+    evenly the edges fell and how much of the edge arrays is padding.
+    Rows are counted per layer and pass: the forward exchanges ``h``,
+    the backward the same rows of its cotangent."""
     ndev = len(need)
     need = [sum(len(rows) for rows in of_k) for of_k in need]
     if not use_halo:
@@ -359,6 +355,7 @@ def _record_partition(counts, e_s, n_shard, need, use_halo, halo_kind,
     else:
         moved = int(sum(halo_sizes))
     for name, value in (
+            ("shards", ndev),
             ("halo_rows_need_max", max(need)),
             ("halo_rows_need_sum", sum(need)),
             # every schedule pads each shard's delivery to the same size
@@ -383,15 +380,14 @@ def graph_shardings(g: NodeShardedGraph) -> NodeShardedGraph:
                             g.halo_sizes)
 
 
-def to_device_sharded(hp: HostPartition, mesh: Mesh,
-                      axes: Optional[tuple] = None) -> NodeShardedGraph:
-    """Place a :class:`HostPartition` on ``mesh`` as a NodeShardedGraph."""
-    axes = data_axes(mesh) if axes is None else axes
-    ndev = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
-    if hp.senders.shape[0] != ndev:
+def to_device_sharded(hp: HostPartition, mesh: Mesh) -> NodeShardedGraph:
+    """Place a :class:`HostPartition` on ``mesh`` as a NodeShardedGraph,
+    one shard a device over every axis of the mesh."""
+    axes = tuple(mesh.axis_names)
+    if hp.senders.shape[0] != mesh.size:
         raise ValueError(
-            f"partition has {hp.senders.shape[0]} shards but mesh axes "
-            f"{axes} have extent {ndev}")
+            f"partition has {hp.senders.shape[0]} shards but the mesh "
+            f"{dict(mesh.shape)} has {mesh.size} devices")
     sh = NamedSharding(mesh, P(axes, None))
     put = lambda a: jax.device_put(jnp.asarray(a), sh)
     return NodeShardedGraph(
@@ -406,14 +402,12 @@ def to_device_sharded(hp: HostPartition, mesh: Mesh,
 
 
 def shard_graph(g: graph_data.Graph, mesh: Mesh,
-                axes: Optional[tuple] = None,
                 halo: Any = "auto") -> NodeShardedGraph:
-    """partition_graph + to_device_sharded in one call."""
-    axes = data_axes(mesh) if axes is None else axes
-    ndev = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
+    """partition_graph + to_device_sharded in one call: one shard a
+    device of ``mesh``, whatever its axes are named."""
     with span("partition"):
-        return to_device_sharded(partition_graph(g, ndev, halo=halo), mesh,
-                                 axes)
+        return to_device_sharded(partition_graph(g, mesh.size, halo=halo),
+                                 mesh)
 
 
 # --- the sharded aggregation --------------------------------------------------

@@ -10,8 +10,10 @@ replicated.  Activations between layers are left to GSPMD, which keeps the
 feature axis sharded through elementwise chains and re-gathers only where a
 contraction needs it.
 
-Used by `models/hgcn.py::make_node_sharded_step_*` (dp×tp HGCN training) and by
-`__graft_entry__.dryrun_multichip`.
+Used by the minibatch mesh steps (`models/hvae.py`, `models/hybonet.py`,
+`models/hgcn_sampled.py` `make_sharded_step`).  HGCN's node-sharded steps
+replicate their state instead (`replicated_like`): their kernels are one
+MXU tile wide, and every device holds a node shard of its own.
 """
 
 from __future__ import annotations

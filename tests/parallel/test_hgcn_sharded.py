@@ -1,5 +1,7 @@
-"""The HGCN mesh step's state: how `parallel/tp.py` shards it, and that
-it round-trips through a checkpoint with its shardings (8 virtual CPU
+"""How `parallel/tp.py`'s rule shards a state (HGCN's parameters as the
+example; the minibatch mesh steps use it, the node-sharded HGCN step
+replicates its state), and that the node-sharded step's state
+round-trips through a checkpoint with its shardings (8 virtual CPU
 devices).  That the step computes the single-device trajectory is
 tests/parallel/test_node_sharded.py's."""
 

@@ -101,7 +101,8 @@ def _mesh_or_skip(axes):
     return make_mesh(axes, devices=jax.devices()[:n])
 
 
-# auto_mesh's pick for a four-chip host (tp=2, cli.train's default)
+# auto_mesh's pick for a four-chip host (tp=2, cli.train's default); the
+# node-sharded steps cut four node shards on it, one a device
 HOST4 = {"data": 2, "model": 2}
 
 
@@ -293,6 +294,102 @@ def test_node_sharded_nc_matches_single_device():
 
     np.testing.assert_allclose(float(loss_sharded), float(loss_single),
                                rtol=1e-4, atol=1e-5)
+
+
+# --- the layout: one node shard a device, over every mesh axis ----------------
+
+
+# the four-chip host's mesh, and one whose batch axis alone has 4 devices
+EVERY_DEVICE = [pytest.param(HOST4, id="data2-model2"),
+                pytest.param({"data": 4, "model": 2}, id="data4-model2")]
+
+
+def _shards_gauge():
+    from hyperspace_tpu.telemetry import registry
+
+    return registry.snapshot()["node_shard/shards"]
+
+
+@pytest.mark.parametrize("axes", EVERY_DEVICE)
+def test_node_sharded_lp_cuts_a_shard_for_every_device(axes):
+    """The ``model`` axis holds node shards of its own: the partition
+    has as many shards as the mesh has devices, and says so."""
+    mesh = _mesh_or_skip(axes)
+    cfg, split, _ = _setup(num_nodes=192)
+    model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
+    _, _, nsg = hgcn.make_node_sharded_step_lp(
+        model, opt, split.graph.num_nodes, mesh, state, split)
+    assert nsg.axes == tuple(mesh.axis_names)
+    assert nsg.senders.shape[0] == mesh.size == _shards_gauge()
+    assert nsg.x.shape[0] == nsg.n_shard * mesh.size
+
+
+@pytest.mark.parametrize("axes", EVERY_DEVICE)
+def test_node_sharded_lp_state_is_replicated(axes):
+    """Parameters and Adam's moments sit whole on every device; no
+    kernel is column-sharded over ``model``."""
+    mesh = _mesh_or_skip(axes)
+    cfg, split, _ = _setup(num_nodes=192)
+    model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
+    _, placed, _ = hgcn.make_node_sharded_step_lp(
+        model, opt, split.graph.num_nodes, mesh, state, split)
+    leaves = jax.tree_util.tree_leaves(placed)
+    assert leaves
+    for leaf in leaves:
+        assert leaf.sharding.is_fully_replicated
+        assert len(leaf.devices()) == mesh.size
+
+
+@pytest.mark.parametrize("axes", EVERY_DEVICE)
+def test_node_sharded_lp_takes_a_batch_uneven_over_the_devices(axes):
+    """The positives arrive sharded over ``data``, a multiple of its
+    extent but not of the device count, and the step spreads them over
+    every device unevenly: one step matches the one-device step on the
+    same batch."""
+    from hyperspace_tpu.parallel import multihost as mh
+    from hyperspace_tpu.parallel.mesh import data_extent
+
+    mesh = _mesh_or_skip(axes)
+    cfg, split, _ = _setup(num_nodes=192)
+    n, d = split.graph.num_nodes, data_extent(mesh)
+    rows = len(split.train_pos) // mesh.size * mesh.size - d
+    assert rows % d == 0 and rows % mesh.size
+    pos = jnp.asarray(split.train_pos[:rows])
+
+    model, opt, state = hgcn.init_lp(cfg, split.graph, seed=0)
+    state, loss_single = hgcn.train_step_lp(
+        model, opt, n, state, G.to_device(split.graph), pos)
+
+    model2, opt2, state2 = hgcn.init_lp(cfg, split.graph, seed=0)
+    step, state2, nsg = hgcn.make_node_sharded_step_lp(
+        model2, opt2, n, mesh, state2, split)
+    state2, loss_sharded = step(state2, nsg, mh.distribute_batch(pos, mesh))
+
+    np.testing.assert_allclose(float(loss_sharded), float(loss_single),
+                               rtol=1e-4, atol=1e-5)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5),
+        state.params, state2.params)
+
+
+@pytest.mark.parametrize("axes", EVERY_DEVICE)
+def test_node_sharded_nc_cuts_a_shard_for_every_device(axes):
+    mesh = _mesh_or_skip(axes)
+    _, _, (edges, x, labels, ncls) = _setup(num_nodes=192)
+    tr, va, te = G.node_split_masks(192, seed=0)
+    g = G.prepare(edges, 192, x, labels=labels, num_classes=ncls,
+                  train_mask=tr, val_mask=va, test_mask=te)
+    cfg = hgcn.HGCNConfig(feat_dim=12, hidden_dims=(16, 8), num_classes=ncls)
+    model, opt, state = hgcn.init_nc(cfg, g, seed=0)
+    step, placed, nsg, lab_p, msk_p = hgcn.make_node_sharded_step_nc(
+        model, opt, mesh, state, g)
+    assert nsg.axes == tuple(mesh.axis_names)
+    assert nsg.senders.shape[0] == mesh.size == _shards_gauge()
+    assert all(leaf.sharding.is_fully_replicated
+               for leaf in jax.tree_util.tree_leaves(placed))
+    _, loss = step(placed, nsg, lab_p, msk_p)
+    assert np.isfinite(float(loss))
 
 
 def test_node_sharded_attention_matches_single_device():
