@@ -11,6 +11,7 @@ optimizer — no hidden globals.
     python -m hyperspace_tpu.cli.train hvae steps=200
     python -m hyperspace_tpu.cli.train product multihost=true
     python -m hyperspace_tpu.cli.train looplm --yaml configs/looplm_ouro_2p6b.yaml
+    python -m hyperspace_tpu.cli.train moe_lm --yaml configs/moe_lm_laguna_s21.yaml
 
 Each run writes JSONL metrics (``--log``), optional orbax checkpoints
 (``--ckpt-dir``), and prints one final JSON line of results.
@@ -793,10 +794,25 @@ def _looplm_config(run: RunConfig, overrides: dict):
     return cfg, stream_kw
 
 
+def _at_log_boundaries(stepper, spc: int, every: int, fn):
+    """``stepper`` that calls ``fn(state)`` whenever a call crosses a log
+    boundary (the cadence ``run_loop`` logs at; one small host fetch
+    there)."""
+    done = [0]
+
+    def stepped(state):
+        state, loss = stepper(state)
+        prev, done[0] = done[0], done[0] + spc
+        if done[0] // every > prev // every:
+            fn(state)
+        return state, loss
+
+    return stepped
+
+
 def _looplm_gauges(cfg, stepper, spc: int, every: int):
     """``stepper`` with the registry's looplm/* gauges set from the
-    state's stats vector whenever a call crosses a log boundary (the
-    cadence ``run_loop`` logs at; one small host fetch there)."""
+    state's stats vector at each log boundary."""
     from hyperspace_tpu.models import looplm
     from hyperspace_tpu.telemetry import registry as telem
 
@@ -804,51 +820,59 @@ def _looplm_gauges(cfg, stepper, spc: int, every: int):
     telem.set_gauge("looplm/ut_steps", t)
     telem.set_gauge("looplm/tokens_per_step",
                     cfg.sequence_length * cfg.sequences_per_step)
-    done = [0]
 
-    def stepped(state):
-        state, loss = stepper(state)
-        prev, done[0] = done[0], done[0] + spc
-        if done[0] // every > prev // every:
-            st = looplm.read_stats(cfg, state.stats)  # hyperlint: disable=host-sync-in-hot-path — once a log boundary, beside the loop's own loss fetch
-            for i in range(t):
-                telem.set_gauge(f"looplm/exit_prob_t{i + 1}",  # telemetry-catalog: looplm/exit_prob_t<t>
-                                st["exit_prob"][i])
-                telem.set_gauge(f"looplm/ce_t{i + 1}",  # telemetry-catalog: looplm/ce_t<t>
-                                st["ce"][i])
-            telem.set_gauge("looplm/expected_exit_step", sum(
-                (i + 1) * p for i, p in enumerate(st["exit_prob"])))
-        return state, loss
+    def gauges(state):
+        st = looplm.read_stats(cfg, state.stats)  # hyperlint: disable=host-sync-in-hot-path — once a log boundary, beside the loop's own loss fetch
+        for i in range(t):
+            telem.set_gauge(f"looplm/exit_prob_t{i + 1}",  # telemetry-catalog: looplm/exit_prob_t<t>
+                            st["exit_prob"][i])
+            telem.set_gauge(f"looplm/ce_t{i + 1}",  # telemetry-catalog: looplm/ce_t<t>
+                            st["ce"][i])
+        telem.set_gauge("looplm/expected_exit_step", sum(
+            (i + 1) * p for i, p in enumerate(st["exit_prob"])))
 
-    return stepped
+    return _at_log_boundaries(stepper, spc, every, gauges)
 
 
-def run_looplm(run: RunConfig, overrides: dict):
-    _reject_accum(run, "looplm")
+def _run_token_lm(run: RunConfig, overrides: dict, name: str, model,
+                  make_config, add_gauges):
+    """A token language model (``looplm``, ``moe_lm``) trained from a
+    published config's keys plus the job's: the packed token stream, the
+    model's ``init_state`` and jitted ``train_step`` under ``run_loop``,
+    gauges from ``add_gauges``.  Returns (cfg, state, last loss, data
+    record)."""
+    _reject_accum(run, name)
     from hyperspace_tpu.data import text as T
-    from hyperspace_tpu.models import looplm
 
-    cfg, stream_kw = _looplm_config(run, overrides)
+    cfg, stream_kw = make_config(run, overrides)
     tokens, source = T.load_token_stream(
         run.data_root, vocab_size=cfg.vocab_size, **stream_kw)
     need = cfg.sequence_length * cfg.sequences_per_step + 1
     if tokens.size < need or int(tokens.max()) >= cfg.vocab_size:
         raise SystemExit(
-            f"looplm: the token stream ({tokens.size} tokens, largest id "
+            f"{name}: the token stream ({tokens.size} tokens, largest id "
             f"{int(tokens.max())}) does not fit a step of {need} tokens "
             f"over a vocabulary of {cfg.vocab_size}")
     data = {"dataset": "token_stream", "source": source,
             "num_tokens": int(tokens.size),
             "tokens_per_step": need - 1}
-    opt, state = looplm.init_state(cfg, seed=run.seed)
+    opt, state = model.init_state(cfg, seed=run.seed)
     stream = _placed(tokens, jnp.int32)
     if run.scan_chunk > 1:
         run = _chunk_run(run)
     stepper, spc = _chunked(
-        run, lambda st: looplm.train_step(cfg, opt, st, stream))
-    stepper = _looplm_gauges(cfg, stepper, spc, run.eval_every or 50)
+        run, lambda st: model.train_step(cfg, opt, st, stream))
+    stepper = add_gauges(cfg, stepper, spc, run.eval_every or 50)
     state, loss = _train_loop(run, state, stepper, steps_per_call=spc,
                               data=data)
+    return cfg, state, loss, data
+
+
+def run_looplm(run: RunConfig, overrides: dict):
+    from hyperspace_tpu.models import looplm
+
+    cfg, state, loss, data = _run_token_lm(
+        run, overrides, "looplm", looplm, _looplm_config, _looplm_gauges)
     stats = looplm.read_stats(cfg, state.stats)
     return {"workload": "looplm", **data, "steps": int(state.step),
             "loss": float(loss), "ce": stats["ce"],
@@ -856,8 +880,124 @@ def run_looplm(run: RunConfig, overrides: dict):
             "grad_norm": stats["grad_norm"]}
 
 
+# keys of a published Laguna ``config.json`` that this trainer holds fixed
+# (as the CLI's yaml loader prints them), and keys a training step does
+# not act on: the model-wide head count (``num_attention_heads_per_layer``
+# gives each layer's), and the sparse step and dense layers, which
+# ``mlp_layer_types`` states layer by layer (checked against it below)
+_MOE_LM_FIXED = {"model_type": "laguna", "attention_bias": "False",
+                 "tie_word_embeddings": "False", "gating": "per-head",
+                 "moe_apply_router_weight_on_input": "False",
+                 "moe_router_logit_softcapping": "0", "hidden_act": "silu",
+                 "decoder_sparse_step": "1"}
+
+
+def _moe_lm_rope(params: dict) -> dict:
+    """The published ``rope_parameters`` as MoELMConfig's fields."""
+    full, sliding = params["full_attention"], params["sliding_attention"]
+    if full.get("rope_type") not in ("yarn", "default") or sliding.get(
+            "rope_type") != "default":
+        raise SystemExit(f"moe_lm: rope_parameters {params}: YaRN or "
+                         "default rotary on full layers, default on "
+                         "sliding layers")
+    yarn = ((float(full["factor"]), int(full["original_max_position_"
+                                             "embeddings"]),
+             float(full["beta_fast"]), float(full["beta_slow"]),
+             float(full["attention_factor"]))
+            if full["rope_type"] == "yarn" else ())
+    return {"rope_theta_full": str(full["rope_theta"]),
+            "partial_rotary_full": str(full.get("partial_rotary_factor", 1)),
+            "rope_yarn_full": json.dumps(yarn),
+            "rope_theta_sliding": str(sliding["rope_theta"]),
+            "partial_rotary_sliding": str(
+                sliding.get("partial_rotary_factor", 1))}
+
+
+def _literal(text: str):
+    """A value the yaml loader wrote back as text: JSON, or a Python
+    literal (a mapping prints as one)."""
+    import ast
+
+    try:
+        return json.loads(text)
+    except ValueError:
+        return ast.literal_eval(text)
+
+
+def _moe_lm_config(run: RunConfig, overrides: dict):
+    """(MoELMConfig, stream arguments) from a published config's keys plus
+    the job's."""
+    from hyperspace_tpu.models import moe_lm
+
+    overrides = dict(overrides)
+    for key, want in _MOE_LM_FIXED.items():
+        got = str(overrides.pop(key, want))
+        if got != want:
+            raise SystemExit(f"moe_lm: {key}={got!r} is not supported "
+                             f"(this trainer runs {key}={want})")
+    overrides.pop("num_attention_heads", None)
+    dense_at = overrides.pop("mlp_only_layers", None)
+    gating = _literal(overrides.pop("gating_types", "[]"))
+    if "rope_parameters" in overrides:
+        overrides.update(_moe_lm_rope(_literal(
+            overrides.pop("rope_parameters"))))
+    max_pos = int(overrides.pop("max_position_embeddings", 0))
+    stream_kw = {"num_tokens": int(overrides.pop("stream_tokens", 1 << 16))}
+    try:
+        cfg = apply_overrides(moe_lm.MoELMConfig(),
+                              _precision_default(run, overrides))
+    except ValueError as e:  # MoELMConfig's own checks
+        raise SystemExit(f"moe_lm: {e}") from None
+    n = cfg.num_hidden_layers
+    if set(gating[:n]) - {"per_head"}:
+        raise SystemExit(f"moe_lm: gating_types {sorted(set(gating))}: "
+                         "only per_head gates are supported")
+    dense = [i for i in range(n) if cfg.mlp_layer_types[i] == "dense"]
+    if dense_at is not None and [
+            i for i in _literal(dense_at) if i < n] != dense:
+        raise SystemExit(f"moe_lm: mlp_only_layers {dense_at} disagrees "
+                         f"with mlp_layer_types {cfg.mlp_layer_types[:n]}")
+    if max_pos and cfg.sequence_length > max_pos:
+        raise SystemExit(f"moe_lm: sequence_length={cfg.sequence_length} "
+                         f"exceeds max_position_embeddings={max_pos}")
+    return cfg, stream_kw
+
+
+def _moe_lm_gauges(cfg, stepper, spc: int, every: int):
+    """``stepper`` with the registry's moe_lm/* gauges set from the
+    state's stats vector at each log boundary."""
+    from hyperspace_tpu.models import moe_lm
+    from hyperspace_tpu.telemetry import registry as telem
+
+    tokens = cfg.sequence_length * cfg.sequences_per_step
+    telem.set_gauge("moe_lm/tokens_per_step", tokens)
+
+    def gauges(state):
+        if not cfg.sparse_layers:
+            return
+        rows = moe_lm.read_stats(cfg, state.stats)["held_rows"]  # hyperlint: disable=host-sync-in-hot-path — once a log boundary, beside the loop's own loss fetch
+        telem.set_gauge("moe_lm/max_held_rows", max(rows))
+        telem.set_gauge("moe_lm/mean_held_rows", sum(rows) / len(rows))
+        telem.set_gauge("moe_lm/held_share", sum(rows) / (
+            len(rows) * tokens * cfg.num_experts_per_tok))
+
+    return _at_log_boundaries(stepper, spc, every, gauges)
+
+
+def run_moe_lm(run: RunConfig, overrides: dict):
+    from hyperspace_tpu.models import moe_lm
+
+    cfg, state, loss, data = _run_token_lm(
+        run, overrides, "moe_lm", moe_lm, _moe_lm_config, _moe_lm_gauges)
+    stats = moe_lm.read_stats(cfg, state.stats)
+    return {"workload": "moe_lm", **data, "steps": int(state.step),
+            "loss": float(loss), "grad_norm": stats["grad_norm"],
+            "held_rows": stats["held_rows"]}
+
+
 WORKLOADS = {
     "looplm": run_looplm,
+    "moe_lm": run_moe_lm,
     "poincare": run_poincare,
     "hgcn": run_hgcn,
     "hybonet": run_hybonet,

@@ -44,6 +44,11 @@ dense mask operand at all.  Its calls are named ``flash_dot_fwd``,
 forward call can produce carry ``jax.ad_checkpoint`` names
 (:data:`FLASH_DOT_OUT`, :data:`FLASH_DOT_LSE`), so a ``jax.checkpoint``
 round a caller can keep them by name and not run the call a second time.
+The dot form also takes K/V with fewer heads than q (grouped-query
+attention: a K/V index map, and a dk/dv kernel that sums a K/V head's
+gradient over its group) and a sliding ``window`` (a lower bound on the
+same block ranges); windowed calls are named ``flash_window_fwd``,
+``flash_window_dq``, ``flash_window_dkv`` (:func:`flash_dot_attention`).
 """
 
 from __future__ import annotations
@@ -90,11 +95,16 @@ class _Form(NamedTuple):
     """The static score form of one launch: ``lorentz`` (module doc) or
     ``dot`` (score q·k × ``scale``, no epilogue, no c/β/τ), and whether
     the causal structure (key position ≤ query position) is built into
-    the kernel's block ranges."""
+    the kernel's block ranges.  The dot form may add a sliding
+    ``window`` (a query sees the ``window`` positions up to and including
+    its own: a lower bound on the same block ranges) and a ``group`` of
+    query heads that read one K/V head (grouped-query attention)."""
 
     kind: str = "lorentz"
     causal: bool = False
     scale: float = 1.0
+    window: int = 0
+    group: int = 1
 
     @property
     def n_smem(self) -> int:
@@ -156,6 +166,8 @@ def _score_tile(form, sm, q, k, iq, ik, bq, bk, mask_ref):
         row = jax.lax.broadcasted_iota(
             jnp.int32, sigma.shape, dimension=0) + iq * bq
         valid = jnp.logical_and(valid, col <= row)
+        if form.window:
+            valid = jnp.logical_and(valid, col > row - form.window)
     return sigma, valid, coef, k_side
 
 
@@ -176,7 +188,41 @@ def _when_needed(form, iq, ik, bq, bk, tile):
         tile()
 
 
-def _attn_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
+# --- the sliding window: block ranges with a lower bound ---------------------
+# A windowed launch's inner grid axis does not run over every block of the
+# other side: it counts from the first block the window reaches, up to the
+# most any block needs (``_window_extent``), and the index maps clamp to
+# the last block needed, so the pipeline fetches nothing new past it.
+
+
+def _window_kv_range(form, iq, bq, bk, nkb, mx=jnp.maximum, mn=jnp.minimum):
+    """(first, last) K/V block that queries of block ``iq`` see; ``mx``
+    and ``mn`` the builtins for the static count below."""
+    return (mx(iq * bq - (form.window - 1), 0) // bk,
+            mn((iq * bq + bq - 1) // bk, nkb - 1))
+
+
+def _window_q_range(form, ik, bq, bk, nqb, mn=jnp.minimum):
+    """(first, last) query block that sees keys of block ``ik``."""
+    return ((ik * bk) // bq,
+            mn((ik * bk + bk - 1 + form.window - 1) // bq, nqb - 1))
+
+
+def _window_extent(rng, n_outer, n_inner):
+    """The most blocks of the inner side any outer block needs, from
+    ``rng(i)`` on Python integers: the inner grid axis's static size."""
+    return min(n_inner, max(hi - lo + 1 for lo, hi in (
+        rng(i) for i in range(n_outer))))
+
+
+def _run_block(lo, hi, i, tile):
+    """Run ``tile(block)`` for inner step ``i`` of a windowed range."""
+    block = lo + i
+    pl.when(block <= hi)(lambda: tile(block))
+
+
+def _attn_body(*refs, form: _Form, bq: int, bk: int, masked: bool,
+               nkb_all: int = 0):
     sm, refs = refs[:form.n_smem], refs[form.n_smem:]
     q_ref, k_ref, v_ref = refs[:3]
     mask_ref = refs[3] if masked else None
@@ -191,9 +237,9 @@ def _attn_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _tile():
+    def _tile(kb=ik):
         q, k, v = _operands(form, (q_ref, k_ref, v_ref))
-        logits, valid, _, _ = _score_tile(form, sm, q, k, iq, ik, bq, bk,
+        logits, valid, _, _ = _score_tile(form, sm, q, k, iq, kb, bq, bk,
                                           mask_ref)
         logits = jnp.where(valid, logits, _NEG)
 
@@ -208,7 +254,11 @@ def _attn_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
         acc_scr[:] = acc_new
 
-    _when_needed(form, iq, ik, bq, bk, _tile)
+    if form.window:
+        lo, hi = _window_kv_range(form, iq, bq, bk, nkb_all)
+        _run_block(lo, hi, ik, _tile)
+    else:
+        _when_needed(form, iq, ik, bq, bk, _tile)
 
     @pl.when(ik == nk_blocks - 1)
     def _epilogue():
@@ -263,21 +313,48 @@ def _block_caps(form):
     return (256, 512) if form.kind == "lorentz" else (512, 512)
 
 
-def _kv_index(form, bq, bk):
+def _kv_index(form, bq, bk, nkb=0):
     """Block index of K/V for grid point (iq, ik), KV innermost: a block
     above the diagonal is never computed on, so it names the last one
-    needed and the pipeline fetches nothing new."""
+    needed and the pipeline fetches nothing new.  Windowed: ``ik`` counts
+    from the first block the window reaches."""
+    if form.window:
+        def kv(iq, ik):
+            lo, hi = _window_kv_range(form, iq, bq, bk, nkb)
+            return jnp.minimum(lo + ik, hi)
+        return kv
     if not form.causal:
         return lambda iq, ik: ik
     return lambda iq, ik: jnp.minimum(ik, (iq * bq + (bq - 1)) // bk)
 
 
-def _q_index(form, bq, bk):
+def _q_index(form, bq, bk, nqb=0):
     """Block index of the Q-side operands for grid point (ik, iq), Q
-    innermost (the dk/dv kernel): blocks before the diagonal are skipped."""
+    innermost (the dk/dv kernel): blocks before the diagonal are skipped.
+    Windowed: ``iq`` counts from the first block that sees block ``ik``."""
+    if form.window:
+        def qi(ik, iq):
+            lo, hi = _window_q_range(form, ik, bq, bk, nqb)
+            return jnp.minimum(lo + iq, hi)
+        return qi
     if not form.causal:
         return lambda ik, iq: iq
     return lambda ik, iq: jnp.maximum(iq, (ik * bk) // bq)
+
+
+def _kv_head(form):
+    """The K/V head a query head (the leading grid index) reads."""
+    if form.group == 1:
+        return lambda ib: ib
+    return lambda ib: ib // form.group
+
+
+def _kv_steps(form, bq, bk, nqb, nkb):
+    """(inner K/V grid extent, body keywords) of the KV-inner kernels."""
+    if not form.window:
+        return nkb, {}
+    return _window_extent(lambda i: _window_kv_range(
+        form, i, bq, bk, nkb, max, min), nqb, nkb), {"nkb_all": nkb}
 
 
 def _launch(q, k, v, form, scalars, maskf, mode_):
@@ -301,14 +378,16 @@ def _launch(q, k, v, form, scalars, maskf, mode_):
     kp = pad3(k, bk)
     vp = pad3(v, bk)
     nq_p, nk_p = qp.shape[1], kp.shape[1]
-    grid = (b, nq_p // bq, nk_p // bk)
+    n_kv, extra = _kv_steps(form, bq, bk, nq_p // bq, nk_p // bk)
+    grid = (b, nq_p // bq, n_kv)
 
-    kv = _kv_index(form, bq, bk)
+    kv = _kv_index(form, bq, bk, nk_p // bk)
+    kvh = _kv_head(form)
     in_specs, args = _smem_operands(form, b, nk, *scalars)
     in_specs += [
         pl.BlockSpec((1, bq, dp), lambda ib, iq, ik: (ib, iq, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, kv(iq, ik), 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, kv(iq, ik), 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (kvh(ib), kv(iq, ik), 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (kvh(ib), kv(iq, ik), 0), memory_space=pltpu.VMEM),
     ]
     args += [qp, kp, vp]
     masked = maskf is not None
@@ -321,7 +400,8 @@ def _launch(q, k, v, form, scalars, maskf, mode_):
     row_spec = pl.BlockSpec((1, bq, 128), lambda ib, iq, ik: (ib, iq, 0),
                             memory_space=pltpu.VMEM)
     out, res = pl.pallas_call(
-        functools.partial(_attn_body, form=form, bq=bq, bk=bk, masked=masked),
+        functools.partial(_attn_body, form=form, bq=bq, bk=bk, masked=masked,
+                          **extra),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -349,8 +429,12 @@ def _launch(q, k, v, form, scalars, maskf, mode_):
 def _call_name(form, which: str):
     """The dot form's calls carry names of their own (XLA makes a call's
     name the instruction's, which a device trace shows): flash_dot_fwd,
-    flash_dot_dq, flash_dot_dkv; the lorentz form's stay unnamed."""
-    return None if form.kind == "lorentz" else f"flash_dot_{which}"
+    flash_dot_dq, flash_dot_dkv, and flash_window_fwd, flash_window_dq,
+    flash_window_dkv where a sliding window bounds them; the lorentz
+    form's stay unnamed."""
+    if form.kind == "lorentz":
+        return None
+    return f"flash_{'window' if form.window else 'dot'}_{which}"
 
 
 def _scalar_per_batch(x, lead, dtype):
@@ -362,7 +446,8 @@ def _scalar_per_batch(x, lead, dtype):
 # --- recomputing flash backward (module doc) ----------------------------------
 
 
-def _dq_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
+def _dq_body(*refs, form: _Form, bq: int, bk: int, masked: bool,
+             nkb_all: int = 0):
     sm, refs = refs[:form.n_smem], refs[form.n_smem:]
     q_ref, k_ref, v_ref, dsp_ref, ld_ref = refs[:5]
     mask_ref = refs[5] if masked else None
@@ -381,12 +466,12 @@ def _dq_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
         if lorentz:
             part_scr[:] = jnp.zeros_like(part_scr)
 
-    def _tile():
+    def _tile(kb=ik):
         q, k, v, dsp = _operands(form, (q_ref, k_ref, v_ref, dsp_ref))
         lse = ld_ref[0][:, :1]   # packed per-row scalars: lane 0 = lse,
         di = ld_ref[0][:, 1:2]   # lane 1 = di (one stream, not two)
 
-        sigma, valid, coef, k_side = _score_tile(form, sm, q, k, iq, ik,
+        sigma, valid, coef, k_side = _score_tile(form, sm, q, k, iq, kb,
                                                  bq, bk, mask_ref)
         p = jnp.where(valid, jnp.exp(sigma - lse), 0.0)
         dv_dot = _mm(dsp, v, _NT)                     # ⟨dsp_i, v_j⟩, MXU
@@ -400,7 +485,11 @@ def _dq_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
             # dc term vanishes too.
             part_scr[:] += jnp.sum(jnp.where(valid, dsig * sigma, 0.0))
 
-    _when_needed(form, iq, ik, bq, bk, _tile)
+    if form.window:
+        lo, hi = _window_kv_range(form, iq, bq, bk, nkb_all)
+        _run_block(lo, hi, ik, _tile)
+    else:
+        _when_needed(form, iq, ik, bq, bk, _tile)
 
     @pl.when(ik == nk_blocks - 1)
     def _write():
@@ -409,21 +498,25 @@ def _dq_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
             dst_ref[0, 0] = part_scr[:]
 
 
-def _dkv_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
+def _dkv_body(*refs, form: _Form, bq: int, bk: int, masked: bool,
+              n_inner: int = 0, nqb_all: int = 0):
     sm, refs = refs[:form.n_smem], refs[form.n_smem:]
     q_ref, k_ref, v_ref, dsp_ref, ld_ref = refs[:5]
     mask_ref = refs[5] if masked else None
     dk_ref, dv_ref, dk_scr, dv_scr = refs[5 + masked:]
-    iq = pl.program_id(2)
+    j = pl.program_id(2)
     nq_blocks = pl.num_programs(2)
     ik = pl.program_id(1)          # KV block index is the OUTER grid dim
+    # the inner axis may run over a group's query heads, each over its
+    # query blocks: one K/V head then sums the gradient of its whole group
+    iq = j % n_inner if n_inner else j
 
-    @pl.when(iq == 0)
+    @pl.when(j == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _tile():
+    def _tile(iq=iq):
         q, k, v, dsp = _operands(form, (q_ref, k_ref, v_ref, dsp_ref))
         lse = ld_ref[0][:, :1]   # packed: lane 0 = lse, lane 1 = di
         di = ld_ref[0][:, 1:2]
@@ -437,9 +530,13 @@ def _dkv_body(*refs, form: _Form, bq: int, bk: int, masked: bool):
         q_side = _q_side(form, q)
         dk_scr[:] += coef * _mm(dsig.astype(q_side.dtype), q_side, _TN)
 
-    _when_needed(form, iq, ik, bq, bk, _tile)
+    if form.window:
+        lo, hi = _window_q_range(form, ik, bq, bk, nqb_all)
+        _run_block(lo, hi, iq, _tile)
+    else:
+        _when_needed(form, iq, ik, bq, bk, _tile)
 
-    @pl.when(iq == nq_blocks - 1)
+    @pl.when(j == nq_blocks - 1)
     def _write():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -494,11 +591,14 @@ def _bwd_launch(q, k, v, form, scalars, maskf, dsp, lse, di, mode_):
         mp = S.pad_axis(S.pad_axis(maskf.astype(jnp.float32), -1, bk), -2, bq)
 
     # dq kernel: grid (B, Qb, KVb), KV inner
-    kv = _kv_index(form, bq, bk)
+    nqb, nkb = nq_p // bq, nk_p // bk
+    n_kv, extra = _kv_steps(form, bq, bk, nqb, nkb)
+    kv = _kv_index(form, bq, bk, nkb)
+    kvh = _kv_head(form)
     in_specs = smem_specs + [
         pl.BlockSpec((1, bq, dp), lambda ib, iq, ik: (ib, iq, 0)),
-        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, kv(iq, ik), 0)),
-        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (ib, kv(iq, ik), 0)),
+        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (kvh(ib), kv(iq, ik), 0)),
+        pl.BlockSpec((1, bk, dp), lambda ib, iq, ik: (kvh(ib), kv(iq, ik), 0)),
         pl.BlockSpec((1, bq, dp), lambda ib, iq, ik: (ib, iq, 0)),
         pl.BlockSpec((1, bq, 128), lambda ib, iq, ik: (ib, iq, 0)),
     ]
@@ -508,7 +608,6 @@ def _bwd_launch(q, k, v, form, scalars, maskf, dsp, lse, di, mode_):
                                      lambda ib, iq, ik: (ib, iq, ik)))
         args.append(mp)
 
-    nqb, nkb = nq_p // bq, nk_p // bk
     out_specs = [pl.BlockSpec((1, bq, dp), lambda ib, iq, ik: (ib, iq, 0))]
     out_shape = [jax.ShapeDtypeStruct((b, nq_p, dp), grad_dtype)]
     scratch = [pltpu.VMEM((bq, dp), jnp.float32)]
@@ -518,8 +617,9 @@ def _bwd_launch(q, k, v, form, scalars, maskf, dsp, lse, di, mode_):
         out_shape.append(jax.ShapeDtypeStruct((b, nqb, 8, 128), jnp.float32))
         scratch.append(pltpu.VMEM((8, 128), jnp.float32))
     dq, *dst = pl.pallas_call(
-        functools.partial(_dq_body, form=form, bq=bq, bk=bk, masked=masked),
-        grid=(b, nqb, nkb),
+        functools.partial(_dq_body, form=form, bq=bq, bk=bk, masked=masked,
+                          **extra),
+        grid=(b, nqb, n_kv),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -530,15 +630,26 @@ def _bwd_launch(q, k, v, form, scalars, maskf, dsp, lse, di, mode_):
         name=_call_name(form, "dq"),
     )(*args)
 
-    # dkv kernel: grid (B, KVb, Qb), Q inner
-    qi = _q_index(form, bq, bk)
+    # dkv kernel: grid (B, KVb, Qb), Q inner; grouped: grid (B_kv, KVb,
+    # group x Qb), each K/V head's inner axis over its group's query heads
+    qi = _q_index(form, bq, bk, nqb)
     smem_specs2, _ = _smem_operands(form, b, nk, *scalars)
+    b_kv = k.shape[0]
+    if form.group == 1 and not form.window:
+        n_q, extra = nqb, {}
+        qrow = lambda ib, ik, iq: (ib, qi(ik, iq), 0)
+    else:
+        n_q = nqb if not form.window else _window_extent(
+            lambda i: _window_q_range(form, i, bq, bk, nqb, min), nkb, nqb)
+        extra = {"n_inner": n_q, "nqb_all": nqb}
+        g = form.group
+        qrow = lambda ib, ik, j: (ib * g + j // n_q, qi(ik, j % n_q), 0)
     in_specs2 = smem_specs2 + [
-        pl.BlockSpec((1, bq, dp), lambda ib, ik, iq: (ib, qi(ik, iq), 0)),
+        pl.BlockSpec((1, bq, dp), qrow),
         pl.BlockSpec((1, bk, dp), lambda ib, ik, iq: (ib, ik, 0)),
         pl.BlockSpec((1, bk, dp), lambda ib, ik, iq: (ib, ik, 0)),
-        pl.BlockSpec((1, bq, dp), lambda ib, ik, iq: (ib, qi(ik, iq), 0)),
-        pl.BlockSpec((1, bq, 128), lambda ib, ik, iq: (ib, qi(ik, iq), 0)),
+        pl.BlockSpec((1, bq, dp), qrow),
+        pl.BlockSpec((1, bq, 128), qrow),
     ]
     args2 = base_args + [qp, kp, vp, dspp, ld_b]
     if masked:
@@ -547,16 +658,17 @@ def _bwd_launch(q, k, v, form, scalars, maskf, dsp, lse, di, mode_):
         args2.append(mp)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_body, form=form, bq=bq, bk=bk, masked=masked),
-        grid=(b, nkb, nqb),
+        functools.partial(_dkv_body, form=form, bq=bq, bk=bk, masked=masked,
+                          **extra),
+        grid=(b_kv, nkb, form.group * n_q),
         in_specs=in_specs2,
         out_specs=[
             pl.BlockSpec((1, bk, dp), lambda ib, ik, iq: (ib, ik, 0)),
             pl.BlockSpec((1, bk, dp), lambda ib, ik, iq: (ib, ik, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, nk_p, dp), grad_dtype),
-            jax.ShapeDtypeStruct((b, nk_p, dp), grad_dtype),
+            jax.ShapeDtypeStruct((b_kv, nk_p, dp), grad_dtype),
+            jax.ShapeDtypeStruct((b_kv, nk_p, dp), grad_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, dp), jnp.float32),
@@ -666,15 +778,21 @@ def flash_attention(q, k, v, c, *, beta=0.0, tau=1.0, mask=None):
 # --- the dot-product form (models/looplm.py) ----------------------------------
 
 
-def _t_flash_dot(q, k, v, scale, causal):
-    """XLA twin of the dot form: dense softmax(q kᵀ · scale [+ causal]) v,
-    float32 scores and accumulation whatever the operands' dtype."""
+def _t_flash_dot(q, k, v, scale, causal, window=0, group=1):
+    """XLA twin of the dot form: dense softmax(q kᵀ · scale [+ causal]
+    [+ window]) v, float32 scores and accumulation whatever the operands'
+    dtype; each K/V head repeated over its ``group`` of query heads."""
+    if group > 1:
+        k, v = (jnp.repeat(a, group, axis=-3) for a in (k, v))
     hi = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None)
     logits = jnp.einsum("...qd,...kd->...qk", q, k, precision=hi,
                         preferred_element_type=jnp.float32) * scale
     if causal:
         nq, nk = q.shape[-2], k.shape[-2]
         keep = jnp.arange(nk)[None, :] <= jnp.arange(nq)[:, None]
+        if window:
+            keep &= jnp.arange(nk)[None, :] > (jnp.arange(nq)[:, None]
+                                               - window)
         logits = jnp.where(keep, logits, -jnp.inf)
     w = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("...qk,...kd->...qd", w.astype(v.dtype), v,
@@ -717,7 +835,7 @@ def _fd3_bwd(form, mode_, res, g):
 _flash_dot3.defvjp(_fd3_fwd, _fd3_bwd)
 
 
-def flash_dot_attention(q, k, v, *, causal=False):
+def flash_dot_attention(q, k, v, *, causal=False, window=None):
     """Flash attention in the scaled dot-product form: the recurrence and
     the recomputing backward of :func:`flash_attention` with the score
     q·k / √D and no epilogue.
@@ -730,18 +848,39 @@ def flash_dot_attention(q, k, v, *, causal=False):
     masked from positions, forward and both backward kernels alike.  No
     dense mask operand exists in this form.  The XLA twin serves the CPU
     (and names no residual: under a checkpoint it is recomputed whole).
+
+    Grouped-query attention: q [..., H, Nq, D] with k/v [..., H_kv, Nk, D]
+    and H a multiple of H_kv; query head h reads K/V head h // (H / H_kv)
+    through the K/V index map, and the dk/dv kernel sums each K/V head's
+    gradient over its group.  ``window`` (causal only): a query also
+    needs key position > its own − window, a lower bound on the same
+    block ranges, so a query block visits only the K/V blocks the window
+    reaches; these calls are named ``flash_window_*``.
     """
     scale = 1.0 / (q.shape[-1] ** 0.5)
     if causal and q.shape[-2] != k.shape[-2]:
         raise ValueError("causal attention needs Nq == Nk, got "
                          f"{q.shape[-2]} and {k.shape[-2]}")
+    if window and not causal:
+        raise ValueError("a sliding window bounds causal attention only")
+    group = 1
+    if k.ndim >= 3 and q.ndim == k.ndim and k.shape[-3] != q.shape[-3]:
+        if q.shape[-3] % k.shape[-3] or q.shape[:-3] != k.shape[:-3]:
+            raise ValueError(f"query heads {q.shape[:-2]} are no whole "
+                             f"groups of K/V heads {k.shape[:-2]}")
+        group = q.shape[-3] // k.shape[-3]
+    window = int(window or 0)
     mode_ = S.mode()
     if mode_ == "xla":
-        return _t_flash_dot(q, k, v, scale, causal)
+        return _t_flash_dot(q, k, v, scale, causal, window, group)
     lead = q.shape[:-2]
     flat = lambda a: a.reshape((-1,) + a.shape[-2:])
-    form = _Form("dot", bool(causal), scale)
-    out = _flash_dot3(flat(q), flat(jnp.broadcast_to(k, lead + k.shape[-2:])),
-                      flat(jnp.broadcast_to(v, lead + v.shape[-2:])),
-                      form, mode_)
+    if group == 1 and not window:
+        form = _Form("dot", bool(causal), scale)
+        out = _flash_dot3(
+            flat(q), flat(jnp.broadcast_to(k, lead + k.shape[-2:])),
+            flat(jnp.broadcast_to(v, lead + v.shape[-2:])), form, mode_)
+    else:
+        form = _Form("dot", bool(causal), scale, window, group)
+        out = _flash_dot3(flat(q), flat(k), flat(v), form, mode_)
     return out.reshape(lead + out.shape[-2:])

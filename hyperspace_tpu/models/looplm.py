@@ -75,11 +75,11 @@ with importing("optax"):
     import optax
 
 from hyperspace_tpu import precision as precision_mod
-from hyperspace_tpu.kernels.attention import (FLASH_DOT_LSE, FLASH_DOT_OUT,
-                                              flash_dot_attention)
+from hyperspace_tpu.kernels.attention import flash_dot_attention
+from hyperspace_tpu.models import lm_parts
+from hyperspace_tpu.models.lm_parts import batch_at
 from hyperspace_tpu.nn.layers import (apply_rotary, rms_norm, rotary_tables,
                                       swiglu)
-from hyperspace_tpu.telemetry import registry
 
 LAYER_MATS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 LAYER_GAINS = ("n1", "n2", "n3", "n4")
@@ -88,7 +88,7 @@ LAYER_GAINS = ("n1", "n2", "n3", "n4")
 # mean exit probabilities
 STATS_HEAD = 2
 # rows of the head's logits computed (and recomputed) at a time
-HEAD_BLOCK_ROWS = 1024
+HEAD_BLOCK_ROWS = lm_parts.HEAD_BLOCK_ROWS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -231,36 +231,6 @@ def _layer(cfg: LoopLMConfig, policy, rope, h, w):
     return h
 
 
-def _token_ce(policy, h, head, targets):
-    """Each row's cross-entropy against its target: float32 logits from
-    compute-lane operands, float32 log-sum-exp."""
-    z = policy.matmul(h, head)
-    with jax.named_scope("loss"):
-        return jax.nn.logsumexp(z, axis=-1) - jnp.take_along_axis(
-            z, targets[:, None], axis=-1)[:, 0]
-
-
-def _blocked_token_ce(policy, h, head, targets):
-    """:func:`_token_ce` a block of ``HEAD_BLOCK_ROWS`` rows at a time
-    (ONE ``lax.map``), each block recomputed in the backward: a block's
-    ``[rows, V]`` logits and their cotangent are all that lives of the
-    whole ``[rows of all passes, V]``, and the head's gradient sums in the
-    map's own carry.  Rows that do not fill the last block are padded with
-    zeros and their results dropped, so no size computes more than a block
-    at once."""
-    block = jax.checkpoint(functools.partial(_token_ce, policy))
-    rows = h.shape[0]
-    n = -(-rows // HEAD_BLOCK_ROWS)
-    if n == 1:
-        return block(h, head, targets)
-    pad = n * HEAD_BLOCK_ROWS - rows
-    return jax.lax.map(
-        lambda xs: block(xs[0], head, xs[1]),
-        (jnp.pad(h, ((0, pad), (0, 0))).reshape(n, HEAD_BLOCK_ROWS, -1),
-         jnp.pad(targets, (0, pad)).reshape(n, HEAD_BLOCK_ROWS))
-    ).reshape(-1)[:rows]
-
-
 def exit_log_probs(gate_logits):
     """gate_logits [T, S] -> log p [T, S], in log space: log p_t = log
     λ_t + Σ_{j<t} log(1 − λ_j), the last pass takes what is left."""
@@ -272,28 +242,9 @@ def exit_log_probs(gate_logits):
 
 def _keep_flash_results():
     """The policy of ``remat="layer"``: of an application, beyond its
-    input, keep what the flash forward call alone can produce — its
-    output ``[H, S, D]`` on the compute lane and the rows' float32
-    log-sum-exp ``[H, S]``, named in ``kernels/attention.py`` — so the
-    backward never runs that call a second time; q, k, v and everything
-    else are recomputed.  jax asks the policy about every equation while
-    it splits an application into what runs forward and what backward;
-    the bytes it grants are the gauge ``looplm/remat_kept_bytes``, set at
-    trace time: 0 where no kernel ran (the XLA twin names nothing and is
-    recomputed whole) and until a gradient is taken."""
-    named = jax.checkpoint_policies.save_only_these_names(FLASH_DOT_OUT,
-                                                          FLASH_DOT_LSE)
-    kept = {}
-    registry.set_gauge("looplm/remat_kept_bytes", 0)
-
-    def policy(prim, *avals, **params):
-        keep = named(prim, *avals, **params)
-        if keep:
-            kept[params["name"]] = avals[0].size * avals[0].dtype.itemsize
-            registry.set_gauge("looplm/remat_kept_bytes", sum(kept.values()))
-        return keep
-
-    return policy
+    input, the flash forward call's output and row statistics are kept
+    (``lm_parts.keep_flash_results``); gauge ``looplm/remat_kept_bytes``."""
+    return lm_parts.keep_flash_results("looplm/remat_kept_bytes")
 
 
 def forward(cfg: LoopLMConfig, params, tokens):
@@ -364,9 +315,9 @@ def forward(cfg: LoopLMConfig, params, tokens):
             jnp.arange(passes * n_layers))
     with jax.named_scope("head"):  # its ``loss`` scope lies inside
         # a row's cross-entropy does not know its pass: all T·S rows as one
-        ce = _blocked_token_ce(
+        ce = lm_parts.blocked_token_ce(
             policy, hs.reshape(passes * seq, -1), params["head"],
-            jnp.tile(targets, passes)).reshape(passes, seq)
+            jnp.tile(targets, passes), HEAD_BLOCK_ROWS).reshape(passes, seq)
     with jax.named_scope("exit_gate"):
         # a float32 reduction on the vector unit, not an MXU pass
         gate = jnp.sum(hs * params["gate_w"], axis=-1) + params["gate_b"]
@@ -388,15 +339,6 @@ def loss_fn(cfg: LoopLMConfig, params, tokens):
 
 
 # --- the step -----------------------------------------------------------------
-
-
-def batch_at(stream, step, cfg: LoopLMConfig):
-    """Step i's tokens [B, S + 1] from the packed stream: sequence b of
-    step i starts at (i·B + b)·S, wrapping; the data order is fixed."""
-    s, b = cfg.sequence_length, cfg.sequences_per_step
-    first = (step * b + jnp.arange(b, dtype=jnp.int32)) * s
-    idx = first[:, None] + jnp.arange(s + 1, dtype=jnp.int32)[None, :]
-    return stream[idx % stream.shape[0]]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "opt"),

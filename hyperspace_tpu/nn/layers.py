@@ -17,6 +17,7 @@ manifold tags.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -140,20 +141,59 @@ def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
         jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
 
 
-def rotary_tables(length: int, head_dim: int, theta: float):
-    """(cos, sin), each [length, 1, head_dim] float32, for positions
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float, beta_slow: float):
+    """YaRN's per-pair frequencies over a rotated width ``dim`` (Peng et
+    al., arXiv:2309.00071, in the transformers library's form with its
+    default truncation): pairs that turn more than ``beta_fast`` times
+    over ``original_max`` positions keep theta's frequency, pairs that
+    turn fewer than ``beta_slow`` times take it divided by ``factor``, and
+    a linear ramp blends the ones between."""
+    def turns_dim(turns):
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos = theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (1.0 / (factor * pos)) * (1.0 - keep) + (1.0 / pos) * keep
+
+
+def rotary_tables(length: int, head_dim: int, theta: float, *,
+                  rotary_dim: Optional[int] = None, yarn=None):
+    """(cos, sin), each [length, 1, rotary_dim] float32, for positions
     0 … length-1 in the rotate-half form (the angle of lane i and of lane
-    i + head_dim/2 is position · theta^(-2i/head_dim))."""
-    inv = 1.0 / (theta ** (
-        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    i + rotary_dim/2 is position · theta^(-2i/rotary_dim)).  ``rotary_dim``
+    (default the whole head) is the width a partial rotary turns, the
+    head's first lanes; ``yarn`` = (factor, original_max, beta_fast,
+    beta_slow, attention_factor) takes :func:`yarn_inv_freq`'s
+    frequencies and multiplies both tables by ``attention_factor``."""
+    dim = head_dim if rotary_dim is None else int(rotary_dim)
+    if yarn is None:
+        inv = 1.0 / (theta ** (
+            jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    else:
+        inv = yarn_inv_freq(dim, theta, *yarn[:4])
     ang = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
     ang = jnp.concatenate([ang, ang], axis=-1)[:, None, :]
-    return jnp.cos(ang), jnp.sin(ang)
+    if yarn is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * yarn[4], jnp.sin(ang) * yarn[4]
 
 
 def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x [S, H, D] rotated by its position's angles, in float32."""
+    """x [S, H, D] rotated by its position's angles, in float32.  Tables
+    narrower than the head turn its first lanes and leave the rest."""
     x = x.astype(jnp.float32)
+    width = cos.shape[-1]
+    if width < x.shape[-1]:
+        return jnp.concatenate([apply_rotary(x[..., :width], cos, sin),
+                                x[..., width:]], axis=-1)
     half = x.shape[-1] // 2
     rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
     return x * cos + rot * sin

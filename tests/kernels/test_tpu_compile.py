@@ -439,3 +439,85 @@ def test_looplm_train_step_at_the_published_widths(one_chip, lane):
     assert not in_the_loop, in_the_loop
     assert held < held_under, f"{held / 1e9:.2f} GB of a 16.9 GB bytes_limit"
     assert took < 300, f"compile took {took:.0f}s"
+
+
+def test_flash_window_and_grouped_calls_at_the_moe_models_shapes(one_chip):
+    """The dot form at Laguna-S-2.1's shapes (laguna_s21.pretrain4k's
+    calls): 72 query heads over 8 K/V heads with a 512-key window under
+    their own names, and 48 over 8, causal, under the dot form's."""
+    from hyperspace_tpu.kernels.attention import flash_dot_attention
+
+    A = _arg(one_chip)
+    kv = A((8, 4096, 128), BF16)
+    for heads, window, prefix in ((72, 512, "flash_window"),
+                                  (48, None, "flash_dot")):
+        loss = lambda q, k, v: flash_dot_attention(
+            q, k, v, causal=True, window=window).astype(F32).sum()
+        _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                           A((heads, 4096, 128), BF16), kv, kv, n_kernels=3)
+        for which in ("fwd", "dq", "dkv"):
+            assert f"{prefix}_{which}" in text, (prefix, which)
+
+
+def test_gmm_at_the_moe_models_shapes(one_chip):
+    """The grouped matmul's three calls at the cell's static bound (4,096
+    tokens x 8 slots + 8 tiles of 256 rows) and widths (3,072 -> 1,024
+    and back, 8 held experts)."""
+    from hyperspace_tpu.kernels.gmm import Groups, gmm
+
+    A = _arg(one_chip)
+    rows = 4096 * 8 + 8 * 256
+    groups = Groups(A((rows // 256,), I32), A((1,), I32), A((8,), I32))
+    for k, n in ((3072, 1024), (1024, 3072)):
+        loss = lambda x, w, g: jnp.square(gmm(x, w, g, 256)).sum()
+        _, text = _compile(jax.grad(loss, argnums=(0, 1)),
+                           A((rows, k), BF16), A((8, k, n)), groups,
+                           n_kernels=3)
+        for name in ("gmm_fwd", "gmm_dx", "gmm_dw"):
+            assert name in text, name
+
+
+def test_moe_lm_train_step_at_the_published_widths(one_chip):
+    """``moe_lm.train_step`` as ``cli.train moe_lm --yaml
+    configs/moe_lm_laguna_s21.yaml`` cut to the benchmark's
+    laguna_s21.pretrain4k builds it: Laguna-S-2.1's widths, 5 layers, 8
+    of 256 experts, an eighth of the vocabulary, one 4,096-token
+    sequence.  It must compile for one chip with its windowed and causal
+    flash calls and its grouped matmuls; the state (parameters and two
+    moments, 9.73 GB) is donated, and the temporaries are held to what
+    the compiler reads (8.60 GB by its upper count; its buffer
+    assignment packs them into 6.0 GB beside the state) + 5 %."""
+    import json
+
+    import yaml
+
+    from hyperspace_tpu.cli import train as T
+    from hyperspace_tpu.models import moe_lm
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), "configs",
+            "moe_lm_laguna_s21.yaml")) as f:
+        doc = yaml.safe_load(f)
+    pairs = [f"{k}={json.dumps(v) if isinstance(v, (list, dict)) else v}"
+             for k, v in doc.items()] + [
+        "num_hidden_layers=5", "num_experts=8", "expert_shards=32",
+        "vocab_size=12544"]
+    run, overrides = T.split_overrides(pairs, T.RunConfig())
+    cfg, _ = T._moe_lm_config(run, overrides)
+    assert (cfg.hidden_size, cfg.router_width, cfg.sequence_length,
+            cfg.precision) == (3072, 256, 4096, "bf16")
+    opt = moe_lm.make_optimizer(cfg)
+    state = jax.eval_shape(lambda: moe_lm.init_state(cfg, 0)[1])
+    args = _shapes((state, jax.ShapeDtypeStruct((1 << 22,), I32)), one_chip)
+    t0 = time.perf_counter()
+    compiled = moe_lm.train_step.lower(cfg, opt, *args).compile()
+    took = time.perf_counter() - t0
+    text = compiled.as_text()
+    for name in ("flash_window_fwd", "flash_window_dq", "flash_window_dkv",
+                 "flash_dot_fwd", "flash_dot_dq", "flash_dot_dkv",
+                 "gmm_fwd", "gmm_dx", "gmm_dw"):
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    assert 9.7e9 < mem.alias_size_in_bytes < 9.8e9   # the state, donated
+    assert mem.temp_size_in_bytes < 9.03e9, mem.temp_size_in_bytes
+    assert took < 300, f"compile took {took:.0f}s"
