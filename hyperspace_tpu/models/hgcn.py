@@ -146,7 +146,7 @@ class HGCNEncoder(nn.Module):
             else:
                 h, m = conv(h, g, deterministic=deterministic)
             c_prev = m.c
-        return h, m  # points on the final layer's manifold
+        return _graph_order(h, g), m  # points on the final layer's manifold
 
 
 class HGCNLinkPred(nn.Module):
@@ -611,3 +611,15 @@ def train_nc(
         state, loss = train_step_nc(model, opt, state, ga, labels, tr)
     res = {"loss": float(loss), **evaluate_nc(model, state.params, g, ga=ga)}
     return model, state.params, res
+
+
+def _graph_order(z, g):
+    """The encoder's rows in graph order, where the pairs, the sampled
+    negatives and the labels name nodes.  Only a node-sharded graph whose
+    shards were dealt node blocks holds them otherwise
+    (``parallel/node_shard.py``); decided at trace time."""
+    if not getattr(g, "block_interleave", 0):
+        return z
+    from hyperspace_tpu.parallel.node_shard import graph_order
+
+    return graph_order(z, g)

@@ -5,11 +5,15 @@ encoder (most of the step) replicated on every device.  This module
 shards the *node dimension* — the TPU-native analogue of the reference
 trainer's graph partitioning (SURVEY.md §2 N8, §7 hard-part #3):
 
-- **Host-side partition** (:func:`partition_graph`): nodes are split into
-  ``ndev`` contiguous blocks (the receiver-sorted edge layout from
-  ``data.graphs.prepare`` makes each block's incoming edges a contiguous
-  slice); each shard gets its own receiver-local edge list, per-edge mean
-  weights, and block-CSR plan, all padded to common static shapes.
+- **Host-side partition** (:func:`partition_graph`): each of ``ndev``
+  shards owns ``n_shard`` rows of the node table, rows
+  [k·n_shard, (k+1)·n_shard) on shard k, and the edges whose receiver
+  it owns.  Under the all-gather the shards are dealt blocks of 128
+  nodes in turn (block b to shard b mod ndev: even edge counts whatever
+  the node order); under a halo exchange they are contiguous node
+  ranges (locality keeps the halo small).  Each shard gets its own
+  receiver-local edge list, per-edge mean weights, and block-CSR plan,
+  all padded to common static shapes.
 - **Device-side aggregation** (:func:`node_sharded_aggregate`): a
   ``shard_map`` over every axis of the mesh, so each device holds a
   shard of its own and none repeats another's.  Each device all-gathers
@@ -57,9 +61,13 @@ class NodeShardedGraph(NamedTuple):
     """Device-resident node-sharded graph (pytree; statics in aux data).
 
     Per-edge arrays are [ndev, E_s] so a ``P(axes, None)`` sharding gives
-    each device exactly its shard's slice; ``senders`` hold *global* node
-    ids (they index the all-gathered activations), ``recv`` holds
-    *shard-local* receiver ids, ascending within each shard.
+    each device exactly its shard's slice; ``senders`` hold *table rows*
+    (they index the all-gathered activations; the owner of row i is
+    shard i // n_shard), ``recv`` holds *shard-local* receiver rows,
+    ascending within each shard.  Table rows are graph ids under
+    contiguous ranges; with ``block_interleave`` = b they are
+    :func:`dealt_rows` of the graph ids, and the encoder hands its
+    output back in graph order (:func:`graph_order`).
 
     When ``halo`` is set, ``senders`` instead hold *extended-local* ids
     into ``concat(h_local, halo_rows)`` and the exchange runs one of
@@ -84,8 +92,8 @@ class NodeShardedGraph(NamedTuple):
     with ``halo="ppermute"``.
     """
 
-    x: Any          # [N_pad, F] node features, node-sharded
-    senders: Any    # [ndev, E_s] int32 sender ids (global, or ext-local)
+    x: Any          # [N_pad, F] node features by table row, node-sharded
+    senders: Any    # [ndev, E_s] int32 sender rows (table, or ext-local)
     recv: Any       # [ndev, E_s] int32 local receiver ids (sorted)
     w_fwd: Any      # [ndev, E_s] f32 forward mean weights (0 on padding)
     w_bwd: Any      # [ndev, E_s] f32 reverse-edge weights (0 on padding)
@@ -99,21 +107,22 @@ class NodeShardedGraph(NamedTuple):
     halo_kind: str = "a2a"   # static: "a2a" | "ppermute"
     halo_dists: tuple = ()   # static: kept ring distances (ppermute)
     halo_sizes: tuple = ()   # static: H_d per kept distance (ppermute)
+    block_interleave: int = 0  # static: rows per dealt block; 0 = ranges
 
 
 def _nsg_flatten(g: NodeShardedGraph):
     return ((g.x, g.senders, g.recv, g.w_fwd, g.w_bwd, g.plan, g.send_idx),
             (g.num_nodes, g.n_shard, g.mesh, g.axes, g.halo, g.halo_kind,
-             g.halo_dists, g.halo_sizes))
+             g.halo_dists, g.halo_sizes, g.block_interleave))
 
 
 def _nsg_unflatten(aux, leaves):
     x, s, r, wf, wb, plan, send_idx = leaves
     (num_nodes, n_shard, mesh, axes, halo, halo_kind, halo_dists,
-     halo_sizes) = aux
+     halo_sizes, block_interleave) = aux
     return NodeShardedGraph(x, s, r, wf, wb, plan, num_nodes, n_shard,
                             mesh, axes, send_idx, halo, halo_kind,
-                            halo_dists, halo_sizes)
+                            halo_dists, halo_sizes, block_interleave)
 
 
 jax.tree_util.register_pytree_node(NodeShardedGraph, _nsg_flatten, _nsg_unflatten)
@@ -122,8 +131,8 @@ jax.tree_util.register_pytree_node(NodeShardedGraph, _nsg_flatten, _nsg_unflatte
 class HostPartition(NamedTuple):
     """Host-side (numpy) result of :func:`partition_graph`."""
 
-    x: np.ndarray        # [N_pad, F]
-    senders: np.ndarray  # [ndev, E_s] global (or extended-local if halo)
+    x: np.ndarray        # [N_pad, F] by table row
+    senders: np.ndarray  # [ndev, E_s] table rows (extended-local if halo)
     recv: np.ndarray     # [ndev, E_s] local sorted
     w_fwd: np.ndarray    # [ndev, E_s]
     w_bwd: np.ndarray    # [ndev, E_s]
@@ -135,6 +144,7 @@ class HostPartition(NamedTuple):
     halo_kind: str = "a2a"
     halo_dists: tuple = ()   # kept ring distances (ppermute)
     halo_sizes: tuple = ()   # H_d per kept distance (ppermute)
+    block_interleave: int = 0  # rows per dealt node block; 0 = ranges
 
 
 def partition_graph(g: graph_data.Graph, ndev: int,
@@ -145,8 +155,15 @@ def partition_graph(g: graph_data.Graph, ndev: int,
     Requires ``g`` built by ``data.graphs.prepare(symmetrize=True)`` (so
     the receiver-sorted layout, the masked degree, and the edge involution
     invariants hold — the backward identity needs every edge's reverse to
-    exist).  Shard k owns nodes [k·n_shard, (k+1)·n_shard) and exactly the
-    edges whose receiver falls in that range.
+    exist).  Shard k owns table rows [k·n_shard, (k+1)·n_shard) and
+    exactly the edges whose receiver it owns.  The exchange schedule is
+    decided first, from the need sets of contiguous node ranges (row =
+    graph id).  If a halo runs (forced, or ``"auto"``'s pick) the ranges
+    stay.  Under the all-gather, blocks of ``bn`` nodes are dealt to the
+    shards in turn (:func:`dealt_rows`; ``block_interleave`` = ``bn``),
+    so a node order that puts the high-degree nodes first no longer
+    loads one shard with most edges; the gauges describe the cut that
+    runs.
 
     Plan padding: every shard's edge list ends with one full all-padding
     chunk, and plan rows are padded with (last block, last chunk,
@@ -162,45 +179,15 @@ def partition_graph(g: graph_data.Graph, ndev: int,
     n_shard = (-(-per_dev // bn)) * bn      # rounded up to whole node blocks
     n_pad = n_shard * ndev
 
-    x = np.zeros((n_pad, g.x.shape[1]), np.float32)
-    x[:n] = g.x
-
     mask = np.asarray(g.edge_mask)
     s = np.asarray(g.senders)[mask]
     r = np.asarray(g.receivers)[mask]
     deg = np.maximum(np.asarray(g.deg), 1.0)
-
+    # per-edge mean weights, by graph id before any relabelling: the
+    # reverse edge (r, s) weighs 1/deg of ITS receiver, s — the backward
+    # identity's w∘π without any cross-shard lookup
+    w_r, w_s = 1.0 / deg[r], 1.0 / deg[s]
     bounds = np.searchsorted(r, np.arange(ndev + 1) * n_shard)
-    counts = np.diff(bounds)
-    # every shard ends with ≥ one full all-padding chunk so padded plan
-    # items always have an inert chunk to point at
-    e_s = (-(-max(int(counts.max()), 1) // bk)) * bk + bk
-
-    senders = np.zeros((ndev, e_s), np.int32)
-    recv = np.full((ndev, e_s), n_shard - 1, np.int32)
-    w_fwd = np.zeros((ndev, e_s), np.float32)
-    w_bwd = np.zeros((ndev, e_s), np.float32)
-    plans = []
-    for k in range(ndev):
-        lo, hi = bounds[k], bounds[k + 1]
-        m = hi - lo
-        senders[k, :m] = s[lo:hi]
-        recv[k, :m] = r[lo:hi] - k * n_shard
-        w_fwd[k, :m] = 1.0 / deg[r[lo:hi]]
-        # weight of the reverse edge (r, s): 1/deg of ITS receiver, s —
-        # the backward identity's w∘π without any cross-shard lookup
-        w_bwd[k, :m] = 1.0 / deg[s[lo:hi]]
-        plans.append(build_csr_plan(recv[k], n_shard, bn, bk))
-
-    t_max = max(p.block.shape[0] for p in plans)
-    nb, nchunks = n_shard // bn, e_s // bk
-    plan = tuple(np.full((ndev, t_max), fill, np.int32)
-                 for fill in (nb - 1, nchunks - 1, 0))
-    for k, p in enumerate(plans):
-        t = p.block.shape[0]
-        plan[0][k, :t] = p.block
-        plan[1][k, :t] = p.chunk
-        plan[2][k, :t] = p.first
 
     # halo exchange (VERDICT r3 #6 / r4 #4): per-shard sender-row need
     # sets.  Under a locality ordering most referenced rows are local or
@@ -243,14 +230,7 @@ def partition_graph(g: graph_data.Graph, ndev: int,
     send_idx = None
     halo_dists: tuple = ()
     halo_sizes: tuple = ()
-    # need[k][j]: the rows of shard j that shard k's senders name
-    need = [[np.zeros(0, np.int64)] * ndev for _ in range(ndev)]
-    for k in range(ndev if ndev > 1 else 0):
-        sk = s[bounds[k]:bounds[k + 1]]
-        owner = sk // n_shard
-        for j in np.unique(owner):
-            if int(j) != k:
-                need[k][int(j)] = np.unique(sk[owner == j])
+    need = _need_sets(s, bounds, n_shard)
     if halo is not False and ndev > 1:
         # per-distance max receive count: at distance d, shard k
         # receives need[k][(k - d) % ndev] and sends need[(k+d)%ndev][k]
@@ -308,13 +288,13 @@ def partition_graph(g: graph_data.Graph, ndev: int,
                 for d, hd in zip(halo_dists, halo_sizes):
                     off_d[d] = acc
                     acc += hd
+            ext = np.zeros(len(s), np.int32)
             for k in range(ndev):
                 lo, hi = bounds[k], bounds[k + 1]
                 sk = s[lo:hi]
                 owner = sk // n_shard
-                ext = np.zeros(hi - lo, np.int32)
                 local = owner == k
-                ext[local] = sk[local] - k * n_shard
+                ext[lo:hi][local] = sk[local] - k * n_shard
                 for j in np.unique(owner):
                     j = int(j)
                     if j == k:
@@ -324,14 +304,89 @@ def partition_graph(g: graph_data.Graph, ndev: int,
                         base = n_shard + j * h_max
                     else:
                         base = off_d[(k - j) % ndev]
-                    ext[sel] = base + np.searchsorted(need[k][j], sk[sel])
-                senders[k, :hi - lo] = ext
-                senders[k, hi - lo:] = 0       # padding edges carry w = 0
+                    ext[lo:hi][sel] = base + np.searchsorted(need[k][j],
+                                                             sk[sel])
+
+    # the cut that runs.  Under the all-gather every shard reads the
+    # whole table, so which nodes a shard owns costs the exchange
+    # nothing: deal blocks of ``bn`` nodes to the shards in turn, which
+    # spreads the high-degree nodes a locality order puts first over
+    # all of them and evens out the edges.  A halo, forced or chosen,
+    # keeps the contiguous ranges: locality is what keeps it small.
+    forced = halo is True or halo in ("a2a", "ppermute")
+    deal = bn if ndev > 1 and not use_halo and not forced else 0
+    x = np.zeros((n_pad, g.x.shape[1]), np.float32)
+    if deal:
+        x[dealt_rows(np.arange(n), n_shard, ndev, bn)] = g.x
+        s = dealt_rows(s, n_shard, ndev, bn)
+        r = dealt_rows(r, n_shard, ndev, bn)
+        # group the edges by shard; within one the rows keep the
+        # receivers' order, so the local receivers stay sorted
+        perm = np.argsort((r // n_shard).astype(np.int16), kind="stable")
+        s, r, w_r, w_s = s[perm], r[perm], w_r[perm], w_s[perm]
+        bounds = np.searchsorted(r, np.arange(ndev + 1) * n_shard)
+        need = _need_sets(s, bounds, n_shard)
+    else:
+        x[:n] = g.x
+    if use_halo:
+        s = ext
+
+    counts = np.diff(bounds)
+    # every shard ends with ≥ one full all-padding chunk so padded plan
+    # items always have an inert chunk to point at
+    e_s = (-(-max(int(counts.max()), 1) // bk)) * bk + bk
+    senders = np.zeros((ndev, e_s), np.int32)  # padding edges carry w = 0
+    recv = np.full((ndev, e_s), n_shard - 1, np.int32)
+    w_fwd = np.zeros((ndev, e_s), np.float32)
+    w_bwd = np.zeros((ndev, e_s), np.float32)
+    plans = []
+    for k in range(ndev):
+        lo, hi = bounds[k], bounds[k + 1]
+        m = hi - lo
+        senders[k, :m] = s[lo:hi]
+        recv[k, :m] = r[lo:hi] - k * n_shard
+        w_fwd[k, :m] = w_r[lo:hi]
+        w_bwd[k, :m] = w_s[lo:hi]
+        plans.append(build_csr_plan(recv[k], n_shard, bn, bk))
+
+    t_max = max(p.block.shape[0] for p in plans)
+    nb, nchunks = n_shard // bn, e_s // bk
+    plan = tuple(np.full((ndev, t_max), fill, np.int32)
+                 for fill in (nb - 1, nchunks - 1, 0))
+    for k, p in enumerate(plans):
+        t = p.block.shape[0]
+        plan[0][k, :t] = p.block
+        plan[1][k, :t] = p.chunk
+        plan[2][k, :t] = p.first
     _record_partition(counts, e_s, n_shard, need, use_halo, halo_kind,
-                      send_idx, halo_sizes)
+                      send_idx, halo_sizes, deal)
     return HostPartition(x, senders, recv, w_fwd, w_bwd, plan, n, n_shard,
                          send_idx, use_halo, halo_kind, halo_dists,
-                         halo_sizes)
+                         halo_sizes, deal)
+
+
+def _need_sets(s, bounds, n_shard):
+    """``need[k][j]``: the rows of shard j that shard k's senders
+    (``s[bounds[k]:bounds[k + 1]]``, table rows) name, each once,
+    ascending; empty for j = k."""
+    ndev = len(bounds) - 1
+    need = [[np.zeros(0, np.int64)] * ndev for _ in range(ndev)]
+    for k in range(ndev if ndev > 1 else 0):
+        rows = np.unique(s[bounds[k]:bounds[k + 1]])
+        cuts = np.searchsorted(rows, np.arange(ndev + 1) * n_shard)
+        for j in range(ndev):
+            if j != k:
+                need[k][j] = rows[cuts[j]:cuts[j + 1]]
+    return need
+
+
+def dealt_rows(ids, n_shard: int, ndev: int, bn: int = _BN):
+    """Table rows of graph ids when blocks of ``bn`` nodes are dealt to
+    ``ndev`` shards in turn: block b is shard (b mod ndev)'s
+    (b div ndev)-th block, and shard k holds rows [k·n_shard,
+    (k+1)·n_shard).  Integer arithmetic, for numpy and jax arrays."""
+    b = ids // bn
+    return (b % ndev) * n_shard + (b // ndev) * bn + ids % bn
 
 
 # the exchange schedule as the gauge ``node_shard/schedule`` codes it
@@ -339,11 +394,12 @@ SCHEDULE_CODES = {"all-gather": 0, "a2a": 1, "ppermute": 2}
 
 
 def _record_partition(counts, e_s, n_shard, need, use_halo, halo_kind,
-                      send_idx, halo_sizes) -> None:
+                      send_idx, halo_sizes, deal) -> None:
     """The partition's shape as gauges (docs/observability.md): how many
     shards it cut, the rows a shard needs from the others
     (``need[k][j]``), the rows the chosen schedule moves to it, how
-    evenly the edges fell and how much of the edge arrays is padding.
+    evenly the edges fell, how much of the edge arrays is padding and
+    whether node blocks were dealt (their rows) or ranges cut (0).
     Rows are counted per layer and pass: the forward exchanges ``h``,
     the backward the same rows of its cotangent."""
     ndev = len(need)
@@ -364,9 +420,23 @@ def _record_partition(counts, e_s, n_shard, need, use_halo, halo_kind,
             ("edges_max", int(counts.max())),
             ("edges_min", int(counts.min())),
             ("edge_pad_share", 1.0 - float(counts.sum()) / (ndev * e_s)),
+            ("block_interleave", deal),
             ("schedule", SCHEDULE_CODES[halo_kind if use_halo
                                         else "all-gather"])):
         registry.set_gauge("node_shard/" + name, value)
+
+
+def graph_order(a, g: NodeShardedGraph):
+    """A node-sharded table ``a`` ([N_pad, ...] by table row) in graph
+    order: under dealt blocks the inverse of :func:`dealt_rows`, a
+    reshape and transpose of whole blocks (no gather); ``a`` itself
+    under contiguous ranges."""
+    b = g.block_interleave
+    if not b:
+        return a
+    ndev = a.shape[0] // g.n_shard
+    return (a.reshape(ndev, g.n_shard // b, b, *a.shape[1:])
+            .swapaxes(0, 1).reshape(a.shape))
 
 
 def graph_shardings(g: NodeShardedGraph) -> NodeShardedGraph:
@@ -377,7 +447,7 @@ def graph_shardings(g: NodeShardedGraph) -> NodeShardedGraph:
                             g.num_nodes, g.n_shard, g.mesh, g.axes,
                             None if g.send_idx is None else sh,
                             g.halo, g.halo_kind, g.halo_dists,
-                            g.halo_sizes)
+                            g.halo_sizes, g.block_interleave)
 
 
 def to_device_sharded(hp: HostPartition, mesh: Mesh) -> NodeShardedGraph:
@@ -398,7 +468,8 @@ def to_device_sharded(hp: HostPartition, mesh: Mesh) -> NodeShardedGraph:
         send_idx=None if hp.send_idx is None else put(hp.send_idx),
         halo=hp.halo, halo_kind=hp.halo_kind,
         halo_dists=tuple(hp.halo_dists),
-        halo_sizes=tuple(hp.halo_sizes))
+        halo_sizes=tuple(hp.halo_sizes),
+        block_interleave=hp.block_interleave)
 
 
 def shard_graph(g: graph_data.Graph, mesh: Mesh,

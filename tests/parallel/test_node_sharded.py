@@ -7,6 +7,8 @@ per-device FLOPs and HBM bytes at dp=8 must drop to a fraction of the
 single-device step, not stay ~95% like the pair-sharded step.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,39 +31,113 @@ def _setup(num_nodes=256, seed=0):
 # --- host-side partition invariants ------------------------------------------
 
 
+def _graph_ids(hp):
+    """The graph id that each table row of ``hp`` holds (-1: a padding
+    row): the row map of dealt node blocks, or the identity."""
+    ndev = hp.senders.shape[0]
+    ids = np.arange(hp.num_nodes)
+    rows = (NS.dealt_rows(ids, hp.n_shard, ndev, hp.block_interleave)
+            if hp.block_interleave else ids)
+    out = np.full(hp.n_shard * ndev, -1)
+    out[rows] = ids
+    return out
+
+
 def test_partition_covers_every_edge_once():
-    _, split, _ = _setup()
+    # 1,000 nodes on four shards: eight blocks of 128, two a shard
+    _, split, _ = _setup(num_nodes=1000)
     g = split.graph
     ndev = 4
-    # halo=False: this test checks the GLOBAL-id layout invariants (the
+    # halo=False: this test checks the table-row layout invariants (the
     # halo layout rewrites senders to extended-local ids)
     hp = NS.partition_graph(g, ndev, halo=False)
+    assert hp.block_interleave == 128
+    graph_id = _graph_ids(hp)
+    # every node sits in one table row, its features with it
+    assert sorted(graph_id[graph_id >= 0]) == list(range(g.num_nodes))
+    np.testing.assert_array_equal(hp.x[graph_id >= 0],
+                                  np.asarray(g.x)[graph_id[graph_id >= 0]])
     # real (sender, receiver) multiset must be preserved exactly
     mask = g.edge_mask
     want = sorted(zip(g.receivers[mask].tolist(), g.senders[mask].tolist()))
     got = []
     for k in range(ndev):
         real = hp.w_fwd[k] > 0
-        got += list(zip((hp.recv[k][real] + k * hp.n_shard).tolist(),
-                        hp.senders[k][real].tolist()))
+        got += list(zip(graph_id[hp.recv[k][real] + k * hp.n_shard].tolist(),
+                        graph_id[hp.senders[k][real]].tolist()))
     assert sorted(got) == want
 
 
 def test_partition_receivers_local_sorted_and_weights():
-    _, split, _ = _setup()
+    _, split, _ = _setup(num_nodes=1000)
     g = split.graph
-    hp = NS.partition_graph(g, 4, halo=False)  # global-id layout
+    hp = NS.partition_graph(g, 4, halo=False)  # table-row layout
+    assert hp.block_interleave == 128
+    graph_id = _graph_ids(hp)
     deg = np.maximum(g.deg, 1.0)
     for k in range(4):
         r = hp.recv[k]
         assert np.all(np.diff(r) >= 0), "local receivers must stay sorted"
         assert np.all(r >= 0) and np.all(r < hp.n_shard)
         real = hp.w_fwd[k] > 0
-        glob_r = r[real] + k * hp.n_shard
+        glob_r = graph_id[r[real] + k * hp.n_shard]
+        assert np.all(glob_r >= 0)
+        # the receiver's block is dealt to this shard
+        assert np.all(glob_r // 128 % 4 == k)
         np.testing.assert_allclose(hp.w_fwd[k][real], 1.0 / deg[glob_r],
                                    rtol=1e-6)
-        np.testing.assert_allclose(hp.w_bwd[k][real],
-                                   1.0 / deg[hp.senders[k][real]], rtol=1e-6)
+        np.testing.assert_allclose(
+            hp.w_bwd[k][real], 1.0 / deg[graph_id[hp.senders[k][real]]],
+            rtol=1e-6)
+
+
+def _falling_degree_graph(n=32_768, seed=0):
+    """Node v sends 1 + 16·(1 − v/n) edges to uniform partners, so the
+    degree falls with the node id, as a BFS order leaves a citation
+    graph's: node ranges give the first shard most of the edges."""
+    rng = np.random.default_rng(seed)
+    out = 1 + np.round(16 * (1 - np.arange(n) / n)).astype(np.int64)
+    u = np.repeat(np.arange(n), out)
+    v = rng.integers(0, n, len(u))
+    edges = np.stack([u, v], 1)[u != v]
+    return G.prepare(edges, n, np.zeros((n, 4), np.float32),
+                     pad_multiple=128, cache=False)
+
+
+def _gauges():
+    from hyperspace_tpu.telemetry import registry
+
+    return {k[len("node_shard/"):]: v for k, v in registry.snapshot().items()
+            if k.startswith("node_shard/")}
+
+
+def test_dealt_blocks_even_out_a_falling_degree():
+    """Under the all-gather the shards are dealt blocks of 128 nodes in
+    turn, and their edge counts come out within 2% of each other where
+    node ranges leave the first shard about twice the last one's."""
+    g = _falling_degree_graph()
+    NS.partition_graph(g, 4, halo="a2a")       # a halo keeps the ranges
+    ranges = _gauges()
+    assert ranges["block_interleave"] == 0
+    assert ranges["edges_max"] / ranges["edges_min"] > 1.5
+    hp = NS.partition_graph(g, 4, halo="auto")
+    dealt = _gauges()
+    assert not hp.halo and hp.block_interleave == 128
+    assert dealt["block_interleave"] == 128 and dealt["schedule"] == 0
+    assert dealt["edges_max"] / dealt["edges_min"] <= 1.02
+    assert dealt["edge_pad_share"] < ranges["edge_pad_share"]
+    # the gauges describe the arrays that run
+    real = (hp.w_fwd > 0).sum(axis=1)
+    assert dealt["edges_max"] == real.max()
+    assert dealt["edges_min"] == real.min()
+
+
+@pytest.mark.parametrize("halo", ["a2a", "ppermute", True])
+def test_a_forced_halo_keeps_node_ranges(halo):
+    _, split, _ = _setup(num_nodes=1000)
+    hp = NS.partition_graph(split.graph, 4, halo=halo)
+    assert hp.halo and hp.block_interleave == 0
+    assert _gauges()["block_interleave"] == 0
 
 
 def test_padded_plan_items_are_inert(interp_kernels):
@@ -198,10 +274,37 @@ def _reference_steps(state, split, train_pos, cfg, steps):
     (HOST4, False), (HOST4, "a2a"), (HOST4, "ppermute"),
 ])
 def test_node_sharded_lp_matches_single_device_and_reference(axes, halo):
+    cfg, split, _ = _setup(num_nodes=192)
+    nsg = _lp_matches_single_device_and_reference(axes, halo, cfg, split)
+    if halo != "auto":
+        assert (nsg.halo_kind if nsg.halo else False) == halo
+
+
+@pytest.mark.parametrize("use_att", [False, True], ids=["mean", "attention"])
+def test_node_sharded_lp_on_dealt_blocks_matches_single_device_and_reference(
+        use_att):
+    """1,500 nodes (not a multiple of 512) on the four-chip host's mesh:
+    twelve blocks of 128 dealt three a shard, the last one part padding.
+    The encoder hands its rows back in graph order, where the pairs and
+    the on-device negatives name them."""
+    cfg, split, _ = _setup(num_nodes=1500)
+    cfg = dataclasses.replace(cfg, use_att=use_att)
+    nsg = _lp_matches_single_device_and_reference(
+        HOST4, "auto", cfg, split, tol=(5e-4, 5e-5) if use_att else None)
+    assert not nsg.halo and nsg.block_interleave == 128
+    assert nsg.n_shard == 384
+
+
+def _lp_matches_single_device_and_reference(axes, halo, cfg, split,
+                                            tol=None):
+    """Three node-sharded LP steps against three one-device steps and,
+    by each loss, the first gradient and the change, the reference;
+    ``tol`` = (rtol, atol) of the parameters against the one-device
+    step.  Returns the placed graph."""
     from benchmark.drivers.train_fullgraph import _adam_mu, _get
 
     mesh = _mesh_or_skip(axes)
-    cfg, split, _ = _setup(num_nodes=192)
+    rtol, atol = tol or (2e-4, 2e-5)
     n = split.graph.num_nodes
     steps = 3
     train_pos = jnp.asarray(hgcn.round_up_pairs(split.train_pos, mesh))
@@ -216,8 +319,6 @@ def test_node_sharded_lp_matches_single_device_and_reference(axes, halo):
     model2, opt2, state2 = hgcn.init_lp(cfg, split.graph, seed=0)
     step, state2, nsg = hgcn.make_node_sharded_step_lp(
         model2, opt2, n, mesh, state2, split, halo=halo)
-    if halo != "auto":
-        assert (nsg.halo_kind if nsg.halo else False) == halo
     losses, first_grad = [], None
     for i in range(steps):
         state2, loss_sharded = step(state2, nsg, train_pos)
@@ -230,7 +331,7 @@ def test_node_sharded_lp_matches_single_device_and_reference(axes, halo):
                                rtol=1e-4, atol=1e-5)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5),
+            np.asarray(a), np.asarray(b), rtol=rtol, atol=atol),
         state.params, state2.params)
     # the reference: each loss, the first gradient, the three-step change
     np.testing.assert_allclose(losses, want["losses"], rtol=1e-4)
@@ -241,6 +342,7 @@ def test_node_sharded_lp_matches_single_device_and_reference(axes, halo):
         np.testing.assert_allclose(
             np.linalg.norm(moved), want["change_norms"][name], rtol=2e-3,
             atol=1e-6)
+    return nsg
 
 
 def test_gspmd_concat_under_subset_constraint():
@@ -272,10 +374,22 @@ def test_gspmd_concat_under_subset_constraint():
 
 
 def test_node_sharded_nc_matches_single_device():
-    mesh = _mesh_or_skip({"data": 8})
-    _, _, (edges, x, labels, ncls) = _setup(num_nodes=192)
-    tr, va, te = G.node_split_masks(192, seed=0)
-    g = G.prepare(edges, 192, x, labels=labels, num_classes=ncls,
+    _nc_matches_single_device({"data": 8}, 192)
+
+
+def test_node_sharded_nc_on_dealt_blocks_matches_single_device():
+    """The NC step on 1,500 nodes dealt in blocks of 128 over the
+    four-chip host's mesh: its logits come back in graph order, where
+    the labels and the training mask are."""
+    nsg = _nc_matches_single_device(HOST4, 1500)
+    assert nsg.block_interleave == 128
+
+
+def _nc_matches_single_device(axes, num_nodes):
+    mesh = _mesh_or_skip(axes)
+    _, _, (edges, x, labels, ncls) = _setup(num_nodes=num_nodes)
+    tr, va, te = G.node_split_masks(num_nodes, seed=0)
+    g = G.prepare(edges, num_nodes, x, labels=labels, num_classes=ncls,
                   train_mask=tr, val_mask=va, test_mask=te)
     cfg = hgcn.HGCNConfig(feat_dim=12, hidden_dims=(16, 8), num_classes=ncls)
     steps = 3
@@ -294,6 +408,7 @@ def test_node_sharded_nc_matches_single_device():
 
     np.testing.assert_allclose(float(loss_sharded), float(loss_single),
                                rtol=1e-4, atol=1e-5)
+    return nsg
 
 
 # --- the layout: one node shard a device, over every mesh axis ----------------
@@ -619,6 +734,8 @@ def test_halo_auto_engages_on_low_cut_graph():
     g = G.prepare(edges, n, x, pad_multiple=128)
     hp = NS.partition_graph(g, k, halo="auto")
     assert hp.halo and hp.send_idx is not None
+    # locality keeps the halo small: the node ranges stay
+    assert hp.block_interleave == 0 and _gauges()["block_interleave"] == 0
     # and the picked schedule's estimated volume genuinely beats the
     # all-gather (the gate's own criterion)
     if hp.halo_kind == "a2a":
@@ -704,22 +821,30 @@ def test_the_shards_aggregates_add_up_to_the_unsharded_aggregate(rng):
     cotangent with ``w_bwd``), each node's row taken once from the shard
     that owns it and the padding rows dropped, is the unsharded mean
     aggregate and its transpose."""
-    _, split, _ = _setup(num_nodes=192)
+    # 1,000 nodes on two shards: eight blocks of 128 dealt four a shard
+    _, split, _ = _setup(num_nodes=1000)
     g = split.graph
     n, ndev = g.num_nodes, 2
     hp = NS.partition_graph(g, ndev, halo=False)
+    assert hp.block_interleave == 128
     n_pad = hp.n_shard * ndev
-    h = np.zeros((n_pad, 8))
-    h[:n] = rng.standard_normal((n, 8))
-    cot = np.zeros((n_pad, 8))
-    cot[:n] = rng.standard_normal((n, 8))
+    graph_id = _graph_ids(hp)
+    real_rows = graph_id >= 0
+    h = rng.standard_normal((n, 8))
+    cot = rng.standard_normal((n, 8))
 
-    def shares(table, w):
+    def shares(by_node, w):
+        # the table holds node graph_id[i] in row i; the result is read
+        # back by graph id
+        table = np.zeros((n_pad, 8))
+        table[real_rows] = by_node[graph_id[real_rows]]
         out = np.zeros((n_pad, 8))
         for k in range(ndev):
             rows = k * hp.n_shard + hp.recv[k]
             np.add.at(out, rows, w[k][:, None] * table[hp.senders[k]])
-        return out[:n]
+        by_id = np.zeros((n, 8))
+        by_id[graph_id[real_rows]] = out[real_rows]
+        return by_id
 
     mask = np.asarray(g.edge_mask)
     s, r = np.asarray(g.senders)[mask], np.asarray(g.receivers)[mask]
