@@ -12,6 +12,7 @@ optimizer — no hidden globals.
     python -m hyperspace_tpu.cli.train product multihost=true
     python -m hyperspace_tpu.cli.train looplm --yaml configs/looplm_ouro_2p6b.yaml
     python -m hyperspace_tpu.cli.train moe_lm --yaml configs/moe_lm_laguna_s21.yaml
+    python -m hyperspace_tpu.cli.train moe_lm --yaml configs/moe_lm_glm47_flash.yaml
 
 Each run writes JSONL metrics (``--log``), optional orbax checkpoints
 (``--ckpt-dir``), and prints one final JSON line of results.
@@ -885,11 +886,63 @@ def run_looplm(run: RunConfig, overrides: dict):
 # not act on: the model-wide head count (``num_attention_heads_per_layer``
 # gives each layer's), and the sparse step and dense layers, which
 # ``mlp_layer_types`` states layer by layer (checked against it below)
-_MOE_LM_FIXED = {"model_type": "laguna", "attention_bias": "False",
+_MOE_LM_FIXED = {"attention_bias": "False",
                  "tie_word_embeddings": "False", "gating": "per-head",
                  "moe_apply_router_weight_on_input": "False",
                  "moe_router_logit_softcapping": "0", "hidden_act": "silu",
                  "decoder_sparse_step": "1"}
+# the same of a published GLM-4.7-Flash (``glm4_moe_lite``) config.json:
+# one routing group (n_group > 1 would choose groups first), sigmoid
+# scores with a selection bias (noaux_tc), rotary over the whole rope
+# slice with no scaling
+_GLM_FIXED = {"attention_bias": "False", "tie_word_embeddings": "False",
+              "hidden_act": "silu", "topk_method": "noaux_tc",
+              "n_group": "1", "topk_group": "1",
+              "partial_rotary_factor": "1", "rope_scaling": "None"}
+# glm4_moe_lite's keys under MoELMConfig's names
+_GLM_NAMES = {"n_routed_experts": "num_experts",
+              "routed_scaling_factor": "moe_routed_scaling_factor",
+              "rope_theta": "rope_theta_latent"}
+
+
+def _glm_keys(overrides: dict) -> dict:
+    """A published ``glm4_moe_lite`` config's keys as MoELMConfig's:
+    every layer latent, the first ``first_k_dense_replace`` dense, one
+    shared expert of ``n_shared_experts`` x ``moe_intermediate_size``
+    (a sum of SwiGLUs is one SwiGLU over their joined width), sigmoid
+    scoring.  A key this trainer would not act on as published is
+    refused."""
+    from hyperspace_tpu.models import moe_lm
+
+    for key, want in _GLM_FIXED.items():
+        got = str(overrides.pop(key, want))
+        if got != want:
+            raise SystemExit(f"moe_lm: glm4_moe_lite {key}={got!r} is not "
+                             f"supported (this trainer runs {key}={want})")
+    heads = int(overrides.pop("num_attention_heads", 1))
+    kv = int(overrides.pop("num_key_value_heads", heads))
+    if kv != heads:
+        raise SystemExit(f"moe_lm: glm4_moe_lite num_key_value_heads={kv}: "
+                         "latent attention decompresses K and V for every "
+                         f"one of the {heads} heads")
+    shared = int(overrides.pop("n_shared_experts", 1))
+    if shared < 1:
+        raise SystemExit("moe_lm: glm4_moe_lite n_shared_experts=0: every "
+                         "sparse layer here has a shared expert")
+    if "moe_intermediate_size" in overrides:
+        overrides["shared_expert_intermediate_size"] = str(
+            shared * int(overrides["moe_intermediate_size"]))
+    n = int(overrides.get("num_hidden_layers",
+                          moe_lm.MoELMConfig.num_hidden_layers))
+    dense = int(overrides.pop("first_k_dense_replace", 0))
+    out = {_GLM_NAMES.get(k, k): v for k, v in overrides.items()}
+    out.update(
+        layer_types=json.dumps(["latent_attention"] * n),
+        mlp_layer_types=json.dumps(["dense"] * min(dense, n)
+                                   + ["sparse"] * max(n - dense, 0)),
+        num_attention_heads_per_layer=json.dumps([heads] * n),
+        scoring_func="sigmoid")
+    return out
 
 
 def _moe_lm_rope(params: dict) -> dict:
@@ -930,6 +983,13 @@ def _moe_lm_config(run: RunConfig, overrides: dict):
     from hyperspace_tpu.models import moe_lm
 
     overrides = dict(overrides)
+    model_type = str(overrides.pop("model_type", "laguna"))
+    if model_type == "glm4_moe_lite":
+        overrides = _glm_keys(overrides)
+    elif model_type != "laguna":
+        raise SystemExit(f"moe_lm: model_type={model_type!r} is not "
+                         "supported (this trainer runs laguna and "
+                         "glm4_moe_lite)")
     for key, want in _MOE_LM_FIXED.items():
         got = str(overrides.pop(key, want))
         if got != want:
@@ -966,11 +1026,25 @@ def _moe_lm_config(run: RunConfig, overrides: dict):
 def _moe_lm_gauges(cfg, stepper, spc: int, every: int):
     """``stepper`` with the registry's moe_lm/* gauges set from the
     state's stats vector at each log boundary."""
+    from hyperspace_tpu import precision as precision_mod
     from hyperspace_tpu.models import moe_lm
     from hyperspace_tpu.telemetry import registry as telem
 
     tokens = cfg.sequence_length * cfg.sequences_per_step
     telem.set_gauge("moe_lm/tokens_per_step", tokens)
+    if cfg.mtp_kind:
+        telem.set_gauge("moe_lm/mtp_positions_per_step",
+                        (cfg.sequence_length - 1) * cfg.sequences_per_step)
+    kinds = [cfg.kind_of(i) for i in range(cfg.num_hidden_layers)]
+    latent = [k for k in kinds + [cfg.mtp_kind]
+              if k and k.startswith("latent")]
+    if latent:
+        # the per-head K and V one forward pass decompresses for the
+        # flash calls, on the compute lane
+        telem.set_gauge("moe_lm/mla_kv_decompressed_bytes", (
+            len(latent) * tokens * cfg.heads_of(latent[0]) * 2
+            * cfg.v_head_dim * jnp.dtype(precision_mod.get_policy(
+                cfg.precision).compute).itemsize))
 
     def gauges(state):
         if not cfg.sparse_layers:
@@ -992,7 +1066,8 @@ def run_moe_lm(run: RunConfig, overrides: dict):
     stats = moe_lm.read_stats(cfg, state.stats)
     return {"workload": "moe_lm", **data, "steps": int(state.step),
             "loss": float(loss), "grad_norm": stats["grad_norm"],
-            "held_rows": stats["held_rows"]}
+            "held_rows": stats["held_rows"],
+            **{k: stats[k] for k in ("main_loss", "mtp_loss") if k in stats}}
 
 
 WORKLOADS = {

@@ -45,6 +45,9 @@ from hyperspace_tpu.kernels import _support as S
 
 # bytes of one weight block a grid step may hold (double-buffered twice)
 _W_BLOCK_BYTES = 2 * 1024 * 1024
+# scoped VMEM a gmm_fwd / gmm_dx grid step may ask for: Mosaic's default
+# limit on the v5e, 16 MiB, less 1 MiB for what _vmem_bytes leaves out
+_SCOPED_VMEM = 15 * 1024 * 1024
 
 
 class Groups(NamedTuple):
@@ -58,13 +61,27 @@ class Groups(NamedTuple):
     sizes: jax.Array
 
 
-def _lane_tile(n: int, k: int = 1, itemsize: int = 2) -> int:
-    """The widest of 512/256/128 columns that divides ``n`` and keeps a
-    [k, tile] block under the budget; ``n`` whole where none does."""
-    for t in (512, 256, 128):
-        if n % t == 0 and k * t * itemsize <= _W_BLOCK_BYTES:
-            return t
-    return n
+def _lane_tile(n: int, k: int = 1, itemsize: int = 2,
+               fits=lambda t: True) -> int:
+    """The widest of 512/256/128 columns that divides ``n``, keeps a
+    [k, tile] block under the budget and ``fits``; else the narrowest
+    that divides ``n``; ``n`` whole where none does.  A tile splits the
+    output's columns only, never a sum."""
+    tiles = [t for t in (512, 256, 128) if n % t == 0]
+    return next((t for t in tiles
+                 if k * t * itemsize <= _W_BLOCK_BYTES and fits(t)),
+                tiles[-1] if tiles else n)
+
+
+def _vmem_bytes(tm: int, k: int, tn: int, x_size: int, w_size: int,
+                out_size: int) -> int:
+    """Scoped VMEM of a gmm_fwd / gmm_dx grid step, as the compiler lays
+    it out for a v5e (read off it, for both cells' widths): the x, w and
+    out blocks double-buffered, and, past 128 columns, the product's own
+    scratch, 16 bytes an element of the x block for float32 operands
+    (six MXU passes) and 2 for narrower ones."""
+    blocks = 2 * (tm * k * x_size + k * tn * w_size + tm * tn * out_size)
+    return blocks + (0 if tn <= 128 else tm * k * (16 if x_size >= 4 else 2))
 
 
 def _precision(dtype):
@@ -100,7 +117,9 @@ def _gmm_call(x, w, groups: Groups, tm: int, transpose: bool, mode_: str,
     ``out_dtype``."""
     r, k = x.shape
     n = w.shape[1] if transpose else w.shape[2]
-    tn = _lane_tile(n, k, w.dtype.itemsize)
+    tn = _lane_tile(n, k, w.dtype.itemsize, lambda t: _vmem_bytes(
+        tm, k, t, x.dtype.itemsize, w.dtype.itemsize,
+        jnp.dtype(out_dtype).itemsize) <= _SCOPED_VMEM)
     row = lambda j, m, tiles, used: (jnp.minimum(m, _last_used(used)), 0)
     if transpose:
         w_spec = pl.BlockSpec((1, tn, k), lambda j, m, tiles, used: (

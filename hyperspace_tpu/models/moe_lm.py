@@ -1,6 +1,10 @@
 """MoE LM — a decoder with sliding-window and full grouped-query attention,
 per-head output gates, and sparse expert feed-forwards beside a shared
-expert (Laguna; huggingface.co/poolside/Laguna-S-2.1 config.json).
+expert (Laguna; huggingface.co/poolside/Laguna-S-2.1 config.json); or
+with latent attention, sigmoid routing and a multi-token-prediction
+module (GLM-4.7-Flash, ``glm4_moe_lite``;
+huggingface.co/zai-org/GLM-4.7-Flash config.json, in DeepSeek-V2/V3's
+published forms, arXiv:2405.04434 §2.1, arXiv:2412.19437 §2.1–2.2).
 
 The equations (pre-norm; h [S, d] is the float32 residual stream; layer
 ℓ's kind is ``layer_types[ℓ]`` and ``mlp_layer_types[ℓ]``):
@@ -15,11 +19,29 @@ The equations (pre-norm; h [S, d] is the float32 residual stream; layer
     o_g = softmax(q_g kᵀ / √D + mask) v;  o_g ← σ(a W_gate)_g · o_g
         (``gating`` per-head: one gate a head, read off the same normed a)
     h ← h + concat(o) W_o
+    latent layers (MLA; no K/V heads of their own, no gate):
+        c_q = RMSNorm(a W_qa);  q = c_q W_qb, each head [q_nope | q_rope]
+        [c_kv | k_r] = a W_kva;  each head [k_nope | v] = RMSNorm(c_kv) W_kvb
+        rotary (θ ``rope_theta_latent``) on q_rope and on k_r, ONE key
+        shared by every head;  k = [k_nope | k_r]
+        o = softmax(q kᵀ / √(nope + rope) + causal) v;  h ← h + concat(o) W_o
+        (K and V are decompressed per head for one flash call, the
+        training path DeepSeek-V3 takes)
     b = RMSNorm(h)
     dense layers:  h ← h + SwiGLU(b)                (``intermediate_size``)
     sparse layers: h ← h + Σ_{e ∈ top-k, e held} w_e SwiGLU_e(b)
                           + SwiGLU_shared(b)       (``nn/moe.py``)
     the head: RMSNorm, an untied head, cross-entropy on the next token
+    multi-token prediction (``num_nextn_predict_layers`` 1): at position i
+        h'_i = W_eh [RMSNorm(h_i); RMSNorm(E[t_{i+1}])]  (h the stack's
+        output before the final norm, E the shared embedding), one more
+        layer of the last layer's kind, the shared final norm and head,
+        cross-entropy on t_{i+2} over the S − 1 positions that have it;
+        the objective is L_main + ``mtp_loss_weight`` · L_mtp
+
+The router (``nn/moe.py``): ``scoring_func`` softmax (Laguna) or sigmoid
+(``topk_method`` noaux_tc: the choice on the scores plus a selection
+bias, the weights the scores alone).
 
 The expert share: the router is ``num_experts × expert_shards`` wide (all
 the deployment's experts), and this program holds ``num_experts`` of
@@ -55,13 +77,18 @@ How a step is laid out:
   products; norms, rotary, softmax, the router (its logits at full
   float32 precision), the top-k weights, loss and optimizer in float32.
 
-Named scopes: ``embed``, ``attn``, ``mlp`` (and ``nn/moe.py``'s
-``moe/route``, ``moe/dispatch``, ``moe/gmm``, ``moe/combine`` inside it,
-``moe/shared`` beside them), ``final_norm``, ``head``, ``loss``,
-``optimizer``.  Gauges (set where the CLI logs): ``moe_lm/max_held_rows``
-and ``moe_lm/mean_held_rows`` (rows routed to held experts, over the
-sparse layers), ``moe_lm/held_share`` (of all the step's routed slots),
-``moe_lm/remat_kept_bytes``.
+Named scopes: ``embed``, ``attn`` (``attn/mla_q``: q's down-projection,
+norm, up-projection and rotary; ``attn/mla_kv``: the joint
+down-projection, norm, up-projection and k_r's rotary and broadcast),
+``mlp`` (and ``nn/moe.py``'s ``moe/route``, ``moe/dispatch``,
+``moe/gmm``, ``moe/combine`` inside it, ``moe/shared`` beside them),
+``final_norm``, ``head``, ``loss``, ``mtp`` (the module whole: its
+norms, W_eh, its layer, its head and loss), ``optimizer``.  Gauges (set
+where the CLI logs): ``moe_lm/max_held_rows`` and
+``moe_lm/mean_held_rows`` (rows routed to held experts, over the sparse
+layers, the MTP layer's among them), ``moe_lm/held_share`` (of all the
+step's routed slots), ``moe_lm/remat_kept_bytes``,
+``moe_lm/mtp_positions_per_step``, ``moe_lm/mla_kv_decompressed_bytes``.
 """
 
 from __future__ import annotations
@@ -86,13 +113,15 @@ from hyperspace_tpu.nn.layers import (apply_rotary, rms_norm, rotary_tables,
                                       swiglu)
 
 ATTN_MATS = ("wq", "wk", "wv", "wo", "w_head_gate")
+LAYER_TYPES = ("full_attention", "sliding_attention", "latent_attention")
 DENSE_MATS = ("w_gate", "w_up", "w_down")
 SPARSE_MATS = ("router", "e_gate", "e_up", "e_down", "s_gate", "s_up",
                "s_down")
-GAINS = ("n_attn", "n_mlp")
+GAINS = ("n_attn", "n_mlp", "n_qa", "n_kva", "enorm", "hnorm")
 # stats carried in the state, one vector a step: the loss, the gradient's
 # global norm before the clip, then the rows routed to held experts in
-# each sparse layer, then the held experts that got any row in each
+# each sparse layer (the MTP layer's last), then the held experts that got
+# any row in each; with an MTP module, then the main and the MTP losses
 STATS_HEAD = 2
 
 
@@ -133,6 +162,17 @@ class MoELMConfig:
     shared_expert_intermediate_size: int = 32
     norm_topk_prob: bool = True
     moe_routed_scaling_factor: float = 2.5
+    scoring_func: str = "softmax"   # | "sigmoid" (topk_method noaux_tc)
+    # latent attention (layer_types "latent_attention")
+    q_lora_rank: int = 24
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 12
+    qk_rope_head_dim: int = 4
+    v_head_dim: int = 16
+    rope_theta_latent: float = 1e6
+    # multi-token prediction: 0 or 1 module after the stack
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
     # the job
     sequence_length: int = 64
     sequences_per_step: int = 1
@@ -154,8 +194,7 @@ class MoELMConfig:
             if len(getattr(self, key)) < n:
                 raise ValueError(f"{key} names fewer than "
                                  f"num_hidden_layers={n} layers")
-        if set(self.layer_types[:n]) - {"full_attention",
-                                        "sliding_attention"}:
+        if set(self.layer_types[:n]) - set(LAYER_TYPES):
             raise ValueError(f"layer_types {sorted(set(self.layer_types))}")
         if set(self.mlp_layer_types[:n]) - {"dense", "sparse"}:
             raise ValueError(
@@ -165,9 +204,24 @@ class MoELMConfig:
                      for i in self.layers_of(kind)}
             if len(heads) != 1:
                 raise ValueError(f"layers of kind {kind} differ in heads")
-            if heads.pop() % self.num_key_value_heads:
+            if not kind.startswith("latent") and (
+                    heads.pop() % self.num_key_value_heads):
                 raise ValueError("query heads are no whole groups of "
                                  "num_key_value_heads")
+        if any(k.startswith("latent") for k in self.kinds()) and (
+                self.qk_nope_head_dim + self.qk_rope_head_dim
+                != self.v_head_dim or self.qk_rope_head_dim % 2):
+            raise ValueError(
+                "latent attention runs one flash call: qk_nope_head_dim + "
+                f"qk_rope_head_dim ({self.qk_nope_head_dim} + "
+                f"{self.qk_rope_head_dim}) must equal v_head_dim "
+                f"({self.v_head_dim}), the rotated lanes even")
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring_func={self.scoring_func!r}: want "
+                             "softmax|sigmoid")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError(f"num_nextn_predict_layers="
+                             f"{self.num_nextn_predict_layers}: 0 or 1")
         if not 0 <= self.expert_share < self.expert_shards:
             raise ValueError(f"expert_share={self.expert_share} of "
                              f"{self.expert_shards} shares")
@@ -201,9 +255,17 @@ class MoELMConfig:
         return self.expert_shards > 1
 
     @property
+    def mtp_kind(self):
+        """The MTP module's layer kind (the stack's last), or None."""
+        return (self.kind_of(self.num_hidden_layers - 1)
+                if self.num_nextn_predict_layers else None)
+
+    @property
     def sparse_layers(self) -> int:
+        """Sparse layers, the MTP module's among them."""
         return sum(t == "sparse"
-                   for t in self.mlp_layer_types[:self.num_hidden_layers])
+                   for t in self.mlp_layer_types[:self.num_hidden_layers]) + (
+            bool(self.mtp_kind and self.mtp_kind.endswith("_sparse")))
 
 
 class TrainState(NamedTuple):
@@ -217,9 +279,20 @@ def kind_shapes(cfg: MoELMConfig, kind: str) -> dict:
     """{leaf: shape of one layer's} of a layer kind."""
     d, dh = cfg.hidden_size, cfg.head_dim
     heads, kv = cfg.heads_of(kind), cfg.num_key_value_heads
-    out = {"wq": (d, heads * dh), "wk": (d, kv * dh), "wv": (d, kv * dh),
-           "wo": (heads * dh, d), "w_head_gate": (d, heads),
-           "n_attn": (d,), "n_mlp": (d,)}
+    if kind.startswith("latent"):
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        out = {"w_qa": (d, cfg.q_lora_rank), "n_qa": (cfg.q_lora_rank,),
+               "w_qb": (cfg.q_lora_rank, heads * qk),
+               "w_kva": (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+               "n_kva": (cfg.kv_lora_rank,),
+               "w_kvb": (cfg.kv_lora_rank,
+                         heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+               "wo": (heads * cfg.v_head_dim, d), "n_attn": (d,),
+               "n_mlp": (d,)}
+    else:
+        out = {"wq": (d, heads * dh), "wk": (d, kv * dh),
+               "wv": (d, kv * dh), "wo": (heads * dh, d),
+               "w_head_gate": (d, heads), "n_attn": (d,), "n_mlp": (d,)}
     if kind.endswith("_dense"):
         f = cfg.intermediate_size
         out.update(w_gate=(d, f), w_up=(d, f), w_down=(f, d))
@@ -242,8 +315,9 @@ def init_params(cfg: MoELMConfig, seed: int = 0) -> dict:
     key, ke, kh = jax.random.split(key, 3)
     params = {"embed": normal(ke, (v, d)), "head": normal(kh, (d, v)),
               "final_norm": jnp.ones((d,), jnp.float32), "kinds": {}}
-    for kind in cfg.kinds():
-        n = len(cfg.layers_of(kind))
+
+    def stacked(kind, n):
+        nonlocal key
         leaves = {}
         for name, shape in kind_shapes(cfg, kind).items():
             if name in GAINS:
@@ -251,7 +325,17 @@ def init_params(cfg: MoELMConfig, seed: int = 0) -> dict:
             else:
                 key, k = jax.random.split(key)
                 leaves[name] = normal(k, (n,) + shape)
-        params["kinds"][kind] = leaves
+        return leaves
+
+    for kind in cfg.kinds():
+        params["kinds"][kind] = stacked(kind, len(cfg.layers_of(kind)))
+    if cfg.mtp_kind:
+        key, k = jax.random.split(key)
+        params["mtp"] = {"enorm": jnp.ones((d,), jnp.float32),
+                         "hnorm": jnp.ones((d,), jnp.float32),
+                         "w_eh": normal(k, (2 * d, d)),
+                         "layer": stacked(cfg.mtp_kind,
+                                          cfg.num_nextn_predict_layers)}
     return params
 
 
@@ -261,11 +345,18 @@ def make_optimizer(cfg: MoELMConfig):
     gains, told by name) and, where ``holds_share``, not on the router,
     whose gradient is zero: its update is exactly zero.  One object a
     configuration (a static argument of the jitted step)."""
+    def decays(leaves):
+        return {k: k not in GAINS and not (cfg.holds_share and k == "router")
+                for k in leaves}
+
     def decayed(params):
         mask = {"embed": True, "head": True, "final_norm": False}
-        mask["kinds"] = {kind: {k: k not in GAINS and not (
-            cfg.holds_share and k == "router") for k in leaves}
-            for kind, leaves in params["kinds"].items()}
+        mask["kinds"] = {kind: decays(leaves)
+                         for kind, leaves in params["kinds"].items()}
+        if "mtp" in params:
+            mask["mtp"] = {**decays({k: 0 for k in params["mtp"]
+                                     if k != "layer"}),
+                           "layer": decays(params["mtp"]["layer"])}
         return mask
 
     return optax.chain(
@@ -281,7 +372,8 @@ def init_state(cfg: MoELMConfig, seed: int = 0, params=None):
     with span("init", info):
         params = init_params(cfg, seed) if params is None else params
         opt = make_optimizer(cfg)
-        stats = jnp.zeros((STATS_HEAD + 2 * cfg.sparse_layers,), jnp.float32)
+        stats = jnp.zeros((STATS_HEAD + 2 * cfg.sparse_layers
+                           + 2 * bool(cfg.mtp_kind),), jnp.float32)
         state = TrainState(params, opt.init(params),
                            jnp.zeros((), jnp.int32), stats)
         info["params"] = sum(
@@ -293,7 +385,11 @@ def init_state(cfg: MoELMConfig, seed: int = 0, params=None):
 
 
 def rope_of(cfg: MoELMConfig, attn: str, length: int):
-    """(cos, sin) of a layer's attention kind."""
+    """(cos, sin) of a layer's attention kind (latent: of the rotated
+    slice alone)."""
+    if attn == "latent":
+        return rotary_tables(length, cfg.qk_rope_head_dim,
+                             cfg.rope_theta_latent)
     if attn == "full":
         return rotary_tables(
             length, cfg.head_dim, cfg.rope_theta_full,
@@ -304,7 +400,37 @@ def rope_of(cfg: MoELMConfig, attn: str, length: int):
         rotary_dim=int(cfg.head_dim * cfg.partial_rotary_sliding))
 
 
+def _latent_attention(cfg, policy, heads: int, rope, h, w):
+    """MLA (module doc): q from its compressed form, K and V decompressed
+    per head from the joint latent, the rotary key shared by the heads."""
+    s, eps, mm = h.shape[0], cfg.rms_norm_eps, policy.matmul
+    nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    with jax.named_scope("attn"):
+        a = rms_norm(h, w["n_attn"], eps)
+        with jax.named_scope("mla_q"):
+            c_q = rms_norm(mm(a, w["w_qa"]), w["n_qa"], eps)
+            q = mm(c_q, w["w_qb"]).reshape(s, heads, -1)
+            q = jnp.concatenate([q[..., :nope],
+                                 apply_rotary(q[..., nope:], *rope)], -1)
+        with jax.named_scope("mla_kv"):
+            kva = mm(a, w["w_kva"])
+            c_kv, k_r = kva[:, :cfg.kv_lora_rank], kva[:, cfg.kv_lora_rank:]
+            kv = mm(rms_norm(c_kv, w["n_kva"], eps),
+                    w["w_kvb"]).reshape(s, heads, nope + dv)
+            k_r = apply_rotary(k_r[:, None, :], *rope)
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                k_r, (s, heads, k_r.shape[-1]))], -1)
+            v = kv[..., nope:]
+        to_heads = lambda x: policy.cast_compute(x).transpose(1, 0, 2)
+        o = flash_dot_attention(to_heads(q), to_heads(k), to_heads(v),
+                                causal=True)
+        o = o.transpose(1, 0, 2).astype(jnp.float32)
+        return h + mm(o.reshape(s, heads * dv), w["wo"])
+
+
 def _attention(cfg, policy, attn: str, heads: int, rope, h, w):
+    if attn == "latent":
+        return _latent_attention(cfg, policy, heads, rope, h, w)
     s, dh, kv = h.shape[0], cfg.head_dim, cfg.num_key_value_heads
     mm = policy.matmul
     with jax.named_scope("attn"):
@@ -329,7 +455,8 @@ def _sparse_mlp(cfg, policy, b, w):
         b_r, w_r = jax.lax.stop_gradient(b_r), jax.lax.stop_gradient(w_r)
     top_w, top_e = moe.route(b_r, w_r, cfg.num_experts_per_tok,
                              cfg.moe_routed_scaling_factor,
-                             cfg.norm_topk_prob, balanced=cfg.holds_share)
+                             cfg.norm_topk_prob, balanced=cfg.holds_share,
+                             scoring=cfg.scoring_func)
     plan = moe.plan(top_w, top_e, cfg.expert_share * cfg.num_experts,
                     cfg.num_experts)
     y = moe.held_experts(b, w["e_gate"], w["e_up"], w["e_down"], plan,
@@ -371,9 +498,30 @@ def runs(cfg: MoELMConfig) -> list:
     return out
 
 
+def _mtp(cfg, policy, layer_fn, tokens, h, params):
+    """The MTP module's cross-entropy [S − 1] on the second-next token
+    (module doc).  It runs over all S positions, the last of which has no
+    target: causal attention keeps that row out of every other, so the
+    first S − 1 rows are exactly the module over S − 1 positions, at the
+    stack's shapes.  Also its layer's [1, 2] held rows."""
+    w, eps, seq = params["mtp"], cfg.rms_norm_eps, h.shape[0]
+    with jax.named_scope("mtp"):
+        e = params["embed"][tokens[1:]]
+        x = policy.matmul(jnp.concatenate(
+            [rms_norm(h, w["hnorm"], eps), rms_norm(e, w["enorm"], eps)],
+            -1), w["w_eh"])
+        x, r = layer_fn(x, jax.tree_util.tree_map(lambda a: a[0],
+                                                  w["layer"]))
+        x = rms_norm(x, params["final_norm"], eps)
+        targets = jnp.concatenate([tokens[2:], tokens[:1]])
+        ce = lm_parts.blocked_token_ce(policy, x, params["head"], targets)
+        return ce[:seq - 1], r[None]
+
+
 def forward(cfg: MoELMConfig, params, tokens):
-    """tokens [S + 1] int32 -> (ce [S], [sparse layers, 2]: each sparse
-    layer's rows routed to held experts and held experts with a row)."""
+    """tokens [S + 1] int32 -> (ce [S], the MTP module's ce [S − 1] or
+    None, [sparse layers, 2]: each sparse layer's rows routed to held
+    experts and held experts with a row)."""
     policy = precision_mod.get_policy(cfg.precision)
     inputs, targets = tokens[:-1], tokens[1:]
     seq = inputs.shape[0]
@@ -384,14 +532,18 @@ def forward(cfg: MoELMConfig, params, tokens):
             cfg.layer_types[i].split("_")[0]
             for i in range(cfg.num_hidden_layers))}
         h = params["embed"][inputs]
-    rows = []
-    for kind, at, n in runs(cfg):
+
+    def layer_fn(kind):
         fn = functools.partial(_layer, cfg, policy, kind,
                                ropes[kind.split("_")[0]])
         if cfg.remat == "layer":
             fn = jax.checkpoint(fn, policy=lm_parts.keep_flash_results(
                 "moe_lm/remat_kept_bytes"))
-        stack = params["kinds"][kind]
+        return fn
+
+    rows = []
+    for kind, at, n in runs(cfg):
+        fn, stack = layer_fn(kind), params["kinds"][kind]
         if n == 1:
             h, r = fn(h, jax.tree_util.tree_map(lambda a: a[at], stack))
             r = r[None]
@@ -400,24 +552,35 @@ def forward(cfg: MoELMConfig, params, tokens):
                 lambda a: a[at:at + n], stack))
         if kind.endswith("_sparse"):
             rows.append(r)
+    ce_mtp = None
+    if cfg.mtp_kind:
+        ce_mtp, r = _mtp(cfg, policy, layer_fn(cfg.mtp_kind), tokens, h,
+                         params)
+        if cfg.mtp_kind.endswith("_sparse"):
+            rows.append(r)
     with jax.named_scope("final_norm"):
         h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     with jax.named_scope("head"):  # its ``loss`` scope lies inside
         ce = lm_parts.blocked_token_ce(policy, h, params["head"], targets)
     held = (jnp.concatenate(rows) if rows
             else jnp.zeros((0, 2), jnp.float32))
-    return ce, held
+    return ce, ce_mtp, held
 
 
 def loss_fn(cfg: MoELMConfig, params, tokens):
-    """tokens [B, S + 1] -> (loss, [sparse layers, 2]: rows routed to
+    """tokens [B, S + 1] -> (loss, ([sparse layers, 2]: rows routed to
     held experts and held experts with a row, summed over the B
-    sequences)."""
+    sequences; [main loss, MTP loss] or None))."""
     with jax.default_matmul_precision(cfg.matmul_precision):
         out = [forward(cfg, params, row) for row in tokens]
     with jax.named_scope("loss"):
-        loss = jnp.mean(jnp.stack([ce for ce, _ in out]))
-        return loss, sum(rows for _, rows in out)
+        loss = jnp.mean(jnp.stack([ce for ce, _, _ in out]))
+        rows = sum(r for _, _, r in out)
+        if not cfg.mtp_kind:
+            return loss, (rows, None)
+        mtp = jnp.mean(jnp.stack([c for _, c, _ in out]))
+        return (loss + cfg.mtp_loss_weight * mtp,
+                (rows, jnp.stack([loss, mtp])))
 
 
 # --- the step -----------------------------------------------------------------
@@ -429,19 +592,23 @@ def train_step(cfg: MoELMConfig, opt, state: TrainState, stream):
     """One optimizer step on the step's own slice of the token stream —
     a single XLA program; the state is donated."""
     tokens = lm_parts.batch_at(stream, state.step, cfg)
-    (loss, rows), grads = jax.value_and_grad(
+    (loss, (rows, parts)), grads = jax.value_and_grad(
         functools.partial(loss_fn, cfg), has_aux=True)(state.params, tokens)
     with jax.named_scope("optimizer"):
         gnorm = optax.global_norm(grads)
         updates, opt_state = opt.update(grads, state.opt_state, state.params)
         params = optax.apply_updates(state.params, updates)
-        stats = jnp.concatenate([jnp.stack([loss, gnorm]), rows.T.ravel()])
+        stats = jnp.concatenate([jnp.stack([loss, gnorm]), rows.T.ravel()]
+                                + ([] if parts is None else [parts]))
     return TrainState(params, opt_state, state.step + 1, stats), loss
 
 
 def read_stats(cfg: MoELMConfig, stats) -> dict:
     """The state's stats vector by name (host side)."""
     vals, n = [float(v) for v in stats], cfg.sparse_layers
-    return {"loss": vals[0], "grad_norm": vals[1],
-            "held_rows": vals[STATS_HEAD:STATS_HEAD + n],
-            "held_experts": vals[STATS_HEAD + n:STATS_HEAD + 2 * n]}
+    out = {"loss": vals[0], "grad_norm": vals[1],
+           "held_rows": vals[STATS_HEAD:STATS_HEAD + n],
+           "held_experts": vals[STATS_HEAD + n:STATS_HEAD + 2 * n]}
+    if cfg.mtp_kind:
+        out["main_loss"], out["mtp_loss"] = vals[STATS_HEAD + 2 * n:][:2]
+    return out

@@ -11,12 +11,18 @@ bring it does not exist, and nothing stands in for it):
     T = top-k of p;  w_e = scale · p_e / Σ_T p   (``norm_topk``)
     y = Σ_{e ∈ T, e held} w_e · SwiGLU_e(b)
 
-``balanced`` routing chooses T on each logit less its expert's mean over
-the earlier rows (a per-expert bias that moves the choice and not the
-weights, as auxiliary-loss-free balancing's does; row 0 chooses on its
-own logits): a drift that every token's stream shares then moves no
-expert ahead of the rest, and no later row changes an earlier row's
-choice.
+``scoring="sigmoid"`` (DeepSeek-V3's ``noaux_tc``, one group) puts
+p = sigmoid(b W_r) in softmax's place: T is chosen on p plus a
+per-expert selection bias and the weights are p alone; no code here
+keeps such a bias, so T is chosen on p (a bias at its initial 0), or,
+``balanced``, on p less its running mean as below.
+
+``balanced`` routing chooses T on each logit (sigmoid: each score) less
+its expert's mean over the earlier rows (a per-expert bias that moves the
+choice and not the weights, as auxiliary-loss-free balancing's does; row
+0 chooses on its own): a drift that every token's stream shares then
+moves no expert ahead of the rest, and no later row changes an earlier
+row's choice.
 
 Dispatch (:func:`plan`): each token keeps at most ``c = min(k, count)``
 of its slots (the held ones; a token cannot pick more held experts than
@@ -66,22 +72,25 @@ def row_tile(pairs: int, count: int) -> int:
 
 
 def route(b, w_router, k: int, scale: float, norm_topk: bool,
-          balanced: bool = False):
+          balanced: bool = False, scoring: str = "softmax"):
     """(top-k weights [S, k] float32, top-k experts [S, k]) of the normed
     stream b [S, d]: the router's logits in float32 at full precision
     (a choice among experts is discrete; the whole stream's logits are
-    S × n_experts, a small matmul), softmax, top-k (``balanced``: chosen
-    on the logits less each expert's mean over the earlier rows, module
-    doc), renormalised over the k and scaled."""
+    S × n_experts, a small matmul), softmax (or sigmoid, ``scoring``),
+    top-k (``balanced``: chosen on the logits, or the sigmoid's scores,
+    less each expert's mean over the earlier rows, module doc),
+    renormalised over the k and scaled."""
     with jax.named_scope("moe/route"):
         logits = jnp.matmul(b.astype(jnp.float32), w_router,
                             precision=jax.lax.Precision.HIGHEST)
-        p = jax.nn.softmax(logits, axis=-1)
-        score = logits
+        if scoring == "sigmoid":
+            p = score = jax.nn.sigmoid(logits)
+        else:
+            p, score = jax.nn.softmax(logits, axis=-1), logits
         if balanced:
-            earlier = jnp.cumsum(logits, axis=0) - logits
+            earlier = jnp.cumsum(score, axis=0) - score
             rows = jnp.arange(logits.shape[0], dtype=jnp.float32)[:, None]
-            score = logits - earlier / jnp.maximum(rows, 1.0)
+            score = score - earlier / jnp.maximum(rows, 1.0)
         top_e = jax.lax.top_k(score, k)[1]
         top_p = jnp.take_along_axis(p, top_e, axis=-1)
         if norm_topk:
@@ -204,11 +213,12 @@ def held_experts(b, w_gate, w_up, w_down, pl_: Plan, compute):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "first", "count", "scale",
-                                             "norm_topk", "compute"))
+                                             "norm_topk", "compute",
+                                             "scoring"))
 def expert_share(b, w_router, w_gate, w_up, w_down, *, k, first, count,
-                 scale, norm_topk, compute=jnp.float32):
+                 scale, norm_topk, compute=jnp.float32, scoring="softmax"):
     """The whole layer's held share on its own (tests): route, plan and
     the held experts' weighted sum."""
-    top_w, top_e = route(b, w_router, k, scale, norm_topk)
+    top_w, top_e = route(b, w_router, k, scale, norm_topk, scoring=scoring)
     return held_experts(b, w_gate, w_up, w_down,
                         plan(top_w, top_e, first, count), compute)

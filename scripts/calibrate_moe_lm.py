@@ -1,6 +1,7 @@
-"""The readings the MoE LM cell's limits are set from
-(``benchmark/limits/laguna_s21.pretrain4k.json``), one process on the
-chip:
+"""The readings an MoE LM cell's limits are set from
+(``benchmark/limits/<cell>.json``; ``--workload``, default
+``laguna_s21.pretrain4k``, or ``glm47_flash.pretrain4k``), one process on
+the chip:
 
     python3 scripts/calibrate_moe_lm.py --seeds 3 --faults 1 \
         --out calibrate_moe.jsonl
@@ -8,9 +9,9 @@ chip:
 For each seed: the program's first steps and its twin's first step
 against the plain reference (the sound readings, ``program``); for the
 first ``--faults`` seeds also the control (the reference one step of
-precision down) and each planted fault of
-``benchmark/reference/laguna.py``, put in the program's place and in its
-twin's.  ``--kinds`` names which of these to read, in that order
+precision down) and each planted fault of the cell's reference
+(``benchmark/reference/laguna.py``, ``glm47_flash.py``: the driver's
+``ref``), put in the program's place and in its twin's.  ``--kinds`` names which of these to read, in that order
 (``--kinds control,no_window`` leaves the program out: the harness's own
 runs give its readings).  One JSON line a seed and variant, written as
 each is read.  ``--tiny`` runs the configuration's
@@ -35,8 +36,8 @@ def _load(*parts):
         return json.load(f)
 
 
-def _limits():
-    return _load("benchmark", "limits", CELL + ".json")["limits"]
+def _limits(cell):
+    return _load("benchmark", "limits", cell + ".json")["limits"]
 
 
 def judge(gaps: dict, limits: dict) -> list:
@@ -61,11 +62,9 @@ def _batches(config, steps: int, data_root):
                            cfg.sequences_per_step)
 
 
-def one_seed(config, traffic, seed, data_root, kinds):
+def one_seed(drv, config, traffic, seed, data_root, kinds):
     import jax.numpy as jnp
     import numpy as np
-
-    from benchmark.drivers import train_moe_lm as drv
 
     b1 = float(config["recipe"]["adam_b1"])
     steps = int(traffic["check_steps"])
@@ -99,6 +98,7 @@ def one_seed(config, traffic, seed, data_root, kinds):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="scripts/calibrate_moe_lm.py")
+    ap.add_argument("--workload", default=CELL)
     ap.add_argument("--seeds", type=int, default=3)
     ap.add_argument("--first-seed", type=int, default=2000000011)
     ap.add_argument("--faults", type=int, default=1,
@@ -113,7 +113,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if ROOT not in sys.path:
         sys.path.insert(0, ROOT)
-    limits = _limits()
+    limits = _limits(args.workload)
     if args.rejudge:
         tally = {}
         with open(args.rejudge) as f:
@@ -129,15 +129,18 @@ def main(argv=None) -> int:
                                       in tally.items()}}))
         return 0
 
+    import importlib
+
     from benchmark.drivers import train_fullgraph as one
-    from benchmark.reference import laguna as ref
     from hyperspace_tpu import compile_cache
 
     manifest = _load("BENCHMARK.json")
-    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    cell = next(w for w in manifest["workloads"]
+                if w["name"] == args.workload)
     entry = next(c for c in manifest["configs"] if c["name"] == cell["config"])
     config = _load(entry["file"])
     traffic = _load("benchmark", "traffic", cell["traffic"] + ".json")
+    drv = importlib.import_module("benchmark.drivers." + traffic["driver"])
     if args.tiny:
         from tests.benchmark.helpers import tiny_config
 
@@ -146,11 +149,11 @@ def main(argv=None) -> int:
     data_root = os.path.dirname(one.ensure_dataset(
         config, os.path.join(ROOT, ".cache", "benchmark")))
     kinds = ([k for k in args.kinds.split(",") if k] if args.kinds
-             else ["program", "control", *ref.FAULTS])
+             else ["program", "control", *drv.ref.FAULTS])
     out = open(args.out, "a") if args.out else None
     for i in range(args.seeds):
         seed = args.first_seed + 7919 * i
-        for kind, gaps in one_seed(config, traffic, seed, data_root,
+        for kind, gaps in one_seed(drv, config, traffic, seed, data_root,
                                    kinds if i < args.faults else
                                    [k for k in kinds if k == "program"]):
             rec = {"seed": seed, "kind": kind, "gaps": gaps,

@@ -125,3 +125,33 @@ def test_the_calls_carry_their_names(interp):
         lambda x, w: jnp.sum(gmm(x, w, groups, TM)[:8]), (0, 1)))(x, w).jaxpr,
         [])
     assert sorted(names) == ["gmm_dw", "gmm_dx", "gmm_fwd"]
+
+
+# (rows' width k, columns n, float32 result?) of the four calls of an
+# expert layer at two cells' widths: d -> f and back forward (a float32
+# result), the rows' gradient of each (a result in the rows' lane)
+_CALLS = {"laguna_s21": [(3072, 1024, True), (1024, 3072, True),
+                         (1024, 3072, False), (3072, 1024, False)],
+          "glm47_flash": [(2048, 1536, True), (1536, 2048, True),
+                          (1536, 2048, False), (2048, 1536, False)]}
+
+
+@pytest.mark.parametrize("cell, itemsize, want", [
+    ("laguna_s21", 2, [256, 512, 512, 256]),
+    ("laguna_s21", 4, [128, 512, 512, 128]),
+    ("glm47_flash", 2, [512, 512, 512, 512]),
+    ("glm47_flash", 4, [128, 256, 256, 128])])
+def test_column_tiles_keep_the_grid_step_in_scoped_vmem(cell, itemsize,
+                                                        want):
+    """A call's column tile at 256-row tiles: the widest whose weight
+    block is under 2 MB and whose grid step stays in the v5e's scoped
+    VMEM.  Laguna's calls, bf16 and float32 alike, and GLM's bf16 ones
+    keep the weight block's tile; GLM's float32 [2,048, 256] block
+    would ask 16.58 MiB of the 16 (the compiler's reading) and narrows."""
+    from hyperspace_tpu.kernels import gmm as G
+
+    got = [G._lane_tile(n, k, itemsize, lambda t, k=k, f=f: G._vmem_bytes(
+        256, k, t, itemsize, itemsize, 4 if f else itemsize)
+        <= G._SCOPED_VMEM) for k, n, f in _CALLS[cell]]
+    assert got == want
+    assert G._vmem_bytes(256, 2048, 256, 4, 4, 4) > G._SCOPED_VMEM

@@ -459,22 +459,44 @@ def test_flash_window_and_grouped_calls_at_the_moe_models_shapes(one_chip):
             assert f"{prefix}_{which}" in text, (prefix, which)
 
 
+def test_flash_dot_calls_at_256_lanes_the_glm_models_shape(one_chip):
+    """The dot form, causal, 20 heads of 256 lanes over 4,096 positions
+    (glm47_flash.pretrain4k's latent-attention call: 192 + 64 query/key
+    lanes, 256 value lanes): the three kernels, under their own names,
+    in the smaller blocks the 256-lane VMEM budget takes."""
+    from hyperspace_tpu.kernels.attention import flash_dot_attention
+
+    q = _arg(one_chip)((20, 4096, 256), BF16)
+    loss = lambda q, k, v: flash_dot_attention(
+        q, k, v, causal=True).astype(F32).sum()
+    _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q,
+                       n_kernels=3)
+    for name in ("flash_dot_fwd", "flash_dot_dq", "flash_dot_dkv"):
+        assert name in text, name
+
+
 def test_gmm_at_the_moe_models_shapes(one_chip):
-    """The grouped matmul's three calls at the cell's static bound (4,096
-    tokens x 8 slots + 8 tiles of 256 rows) and widths (3,072 -> 1,024
-    and back, 8 held experts)."""
+    """The grouped matmul's three calls at each MoE cell's static bound
+    (4,096 tokens x its slots + 8 tiles of 256 rows) and widths (Laguna
+    3,072 -> 1,024 and back, 8 slots; GLM 2,048 -> 1,536 and back, 4
+    slots; 8 held experts), in the stated bf16 lane and the float32
+    twin's (``check_twin``): each grid step must stay in the v5e's 16 MiB
+    of scoped VMEM (a [2,048, 256] float32 weight block asked 16.58)."""
     from hyperspace_tpu.kernels.gmm import Groups, gmm
 
     A = _arg(one_chip)
-    rows = 4096 * 8 + 8 * 256
-    groups = Groups(A((rows // 256,), I32), A((1,), I32), A((8,), I32))
-    for k, n in ((3072, 1024), (1024, 3072)):
-        loss = lambda x, w, g: jnp.square(gmm(x, w, g, 256)).sum()
-        _, text = _compile(jax.grad(loss, argnums=(0, 1)),
-                           A((rows, k), BF16), A((8, k, n)), groups,
-                           n_kernels=3)
-        for name in ("gmm_fwd", "gmm_dx", "gmm_dw"):
-            assert name in text, name
+    for d, f, slots in ((3072, 1024, 8), (2048, 1536, 4)):
+        rows = 4096 * slots + 8 * 256
+        groups = Groups(A((rows // 256,), I32), A((1,), I32), A((8,), I32))
+        for lane in (BF16, F32):
+            for k, n in ((d, f), (f, d)):
+                loss = lambda x, w, g: jnp.square(
+                    gmm(x, w, g, 256, lane)).sum()
+                _, text = _compile(jax.grad(loss, argnums=(0, 1)),
+                                   A((rows, k), lane), A((8, k, n)),
+                                   groups, n_kernels=3)
+                for name in ("gmm_fwd", "gmm_dx", "gmm_dw"):
+                    assert name in text, (d, lane, name)
 
 
 def test_moe_lm_train_step_at_the_published_widths(one_chip):
